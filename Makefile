@@ -6,7 +6,7 @@ PY ?= python
 .PHONY: lint lint-changed test tier1 trace-smoke slo-smoke profile-smoke \
 	debug-bundle bench-devices bench-check bench-warm bench-autotune \
 	bench-mesh bench-procs bench-serve bench-semantic bench-scale \
-	bench-continuum search-smoke soak-smoke chaos
+	bench-continuum search-smoke soak-smoke chaos chip-smoke
 
 # set SDLINT_ANNOTATE=1 in CI for GitHub ::error annotations on the diff.
 # The selftest proves every rule still fires on its own fixture corpus
@@ -29,13 +29,17 @@ tier1:
 # multi-device leg: forced-8-device parity smoke (the same test tier-1
 # runs) + the bench device-count sweep on the virtual host mesh. On a
 # real TPU host, drop the XLA_FLAGS/JAX_PLATFORMS overrides to sweep
-# the actual chips (docs/performance.md).
+# the actual chips (docs/performance.md). `make chip-smoke` is the
+# on-chip proof the index pass still starts (one process; fails on CPU).
 bench-devices:
 	env JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_sharded_ops.py -q \
 		-p no:cacheprovider
 	env XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 		JAX_PLATFORMS=cpu SD_BENCH_SWEEP=1 SD_BENCH_FILES=512 \
 		SD_BENCH_REPEATS=2 $(PY) bench.py
+
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # chaos soak: the full fault-injection matrix — the fast deterministic
 # subset (also in tier-1) plus the multi-seed slow soak
@@ -51,7 +55,7 @@ chaos:
 # platform; on the TPU rig run `python bench_e2e.py` for the full set.
 bench-warm:
 	env JAX_PLATFORMS=cpu SD_E2E_CONFIGS=warm SD_E2E_FILES=800 \
-		SD_E2E_REPEATS=2 SD_BENCH_WAIT=0 $(PY) bench_e2e.py
+		SD_E2E_REPEATS=2 $(PY) bench_e2e.py
 
 # closed-loop autotuner A/B: the SAME identifier pass static
 # (SD_AUTOTUNE=0) vs adaptive, on a clean link and on one throttled
@@ -72,7 +76,7 @@ bench-autotune:
 # only scale better (note rides the artifact).
 bench-mesh:
 	env JAX_PLATFORMS=cpu SD_E2E_CONFIGS=mesh SD_E2E_FILES=800 \
-		SD_E2E_REPEATS=2 SD_BENCH_WAIT=0 $(PY) bench_e2e.py
+		SD_E2E_REPEATS=2 $(PY) bench_e2e.py
 
 # multi-process execution plane A/B: the SAME shard-plane identify
 # window with SD_PROCS=0 (golden single-process path) vs a 2-worker
@@ -83,7 +87,7 @@ bench-mesh:
 # (1-core rigs record the honest floor, like config_mesh).
 bench-procs:
 	env JAX_PLATFORMS=cpu SD_E2E_CONFIGS=procs SD_E2E_FILES=4000 \
-		SD_E2E_REPEATS=3 SD_BENCH_WAIT=0 $(PY) bench_e2e.py
+		SD_E2E_REPEATS=3 $(PY) bench_e2e.py
 
 # stage-typed execution continuum A/B: the SAME image corpus runs its
 # post-identify stages (thumbnail + embed) through the unified
@@ -95,7 +99,7 @@ bench-procs:
 # everywhere and the efficiency floor on ≥2-core rigs.
 bench-continuum:
 	env JAX_PLATFORMS=cpu SD_E2E_CONFIGS=continuum SD_E2E_IMAGES=64 \
-		SD_E2E_REPEATS=2 SD_BENCH_WAIT=0 $(PY) bench_e2e.py
+		SD_E2E_REPEATS=2 $(PY) bench_e2e.py
 
 # semantic-plane bench: cold embed files/s (per-stage clocks, so the
 # rest of the media pass doesn't dilute it), the warm journal contract
@@ -149,8 +153,7 @@ soak-smoke:
 # perf trajectory gate: diff the two most recent BENCH_r*.json rounds
 # AND (when BENCH_E2E_prev.json exists) the previous → current
 # BENCH_E2E per-config rates incl. the warm-pass metrics; fail on a
-# >15% regression in any comparable throughput series (link-bound e2e
-# rates are excused on blocked/congested runs). Rides the incremental
+# >15% regression in any comparable throughput series. Rides the incremental
 # lint path so the repeated local bench loop doesn't pay a cold lint
 # every round; CI's `lint` target stays cold and authoritative.
 bench-check: lint-changed

@@ -45,44 +45,113 @@ async def _get_or_create_library(node, name: str):
 # --- commands -------------------------------------------------------------
 
 
-async def cmd_index(args: argparse.Namespace) -> int:
+def device_report(node) -> dict[str, Any]:
+    """What the pass actually ran on, not what was asked for: the JAX
+    device stamp, where the degradation ladder ended, and every count
+    of work that left the device path. All zeros/level 0 on a clean
+    device pass; `sdx index` prints it and chip_smoke.py asserts it."""
+    from .parallel import mesh as _mesh
+    from .telemetry import counter_value
+    from .telemetry.events import RESILIENCE_EVENTS
+
+    out: dict[str, Any] = {"device": None}
+    if node.use_device:
+        import jax
+
+        devs = jax.devices()
+        out["device"] = {
+            "platform": devs[0].platform,
+            "kind": devs[0].device_kind,
+            "count": len(devs),
+        }
+    out.update(
+        ladder_level=int(_mesh.LADDER.level),
+        cas_backend_fallbacks=int(
+            counter_value("sd_cas_backend_fallback_total")),
+        thumbnail_cpu_fallbacks=sum(
+            1 for e in RESILIENCE_EVENTS.snapshot()
+            if e["type"] == "thumbnail_cpu_fallback"
+        ),
+        thumbnail_errors=node.thumbnailer.errors,
+    )
+    return out
+
+
+def chain_reports(lib, root_id) -> list:
+    """JobReports of one spawned chain: the root job and every job
+    chained under it (a FAILED job spawns no successor, so a short
+    list is itself a finding)."""
+    from .jobs.report import JobReport
+
+    out, frontier = [], [root_id.bytes]
+    while frontier:
+        job_id = frontier.pop()
+        row = lib.db.find_one("job", id=job_id)
+        if row is not None:
+            out.append(JobReport.from_row(row))
+        frontier.extend(
+            r["id"] for r in lib.db.query(
+                "SELECT id FROM job WHERE parent_id = ?", (job_id,)
+            )
+        )
+    return out
+
+
+def _job_seconds(report) -> float | None:
+    """Wall seconds a settled job ran, from its own report."""
+    from datetime import datetime
+
+    if not (report.started_at and report.completed_at):
+        return None
+    return round((datetime.fromisoformat(report.completed_at)
+                  - datetime.fromisoformat(report.started_at)
+                  ).total_seconds(), 2)
+
+
+async def index_location(node, path: str, library: str, backend: str) -> dict:
+    """Index one location on a STARTED node and summarize what ran —
+    the body of `sdx index`, shared with chip_smoke.py so the smoke
+    drives the same calls a user's command does."""
+    from .jobs.report import JobStatus
     from .location.locations import LocationCreateArgs, scan_location
     from .node.statistics import update_statistics
 
+    lib = await _get_or_create_library(node, library)
+    existing = lib.db.find_one("location", path=os.path.abspath(path))
+    t0 = time.perf_counter()
+    loc = existing or LocationCreateArgs(path=path).create(lib)
+    root_id = await scan_location(lib, loc, node.jobs, backend=backend)
+    await node.jobs.wait_idle()
+    await node.thumbnailer.wait_library_batch(str(lib.id))
+    elapsed = time.perf_counter() - t0
+    stats = update_statistics(lib.db, node.thumbnailer.data_dir)
+    reports = chain_reports(lib, root_id)
+    return {
+        "library": lib.name,
+        "library_id": str(lib.id),
+        "location_id": loc["id"],
+        "files": lib.db.count("file_path", "is_dir = 0"),
+        "objects": stats["total_object_count"],
+        "bytes": int(stats["total_bytes_used"]),
+        "thumbnails": node.thumbnailer.generated,
+        "labeled": node.image_labeler.labeled if node.image_labeler else 0,
+        "backend": backend,
+        **device_report(node),
+        "jobs": {r.name: r.status.name for r in reports},
+        "job_seconds": {r.name: _job_seconds(r) for r in reports},
+        "jobs_failed": sum(r.status == JobStatus.FAILED for r in reports),
+        "seconds": round(elapsed, 2),
+    }
+
+
+async def cmd_index(args: argparse.Namespace) -> int:
     node = _make_node(args)
     await node.start()
     try:
-        lib = await _get_or_create_library(node, args.library)
-        existing = lib.db.find_one("location", path=os.path.abspath(args.path))
-        t0 = time.perf_counter()
-        if existing is None:
-            loc = LocationCreateArgs(path=args.path).create(lib)
-        else:
-            loc = existing
-        await scan_location(lib, loc, node.jobs, backend=args.backend)
-        await node.jobs.wait_idle()
-        await node.thumbnailer.wait_library_batch(str(lib.id))
-        elapsed = time.perf_counter() - t0
-        stats = update_statistics(lib.db, node.thumbnailer.data_dir)
-        files = lib.db.count("file_path", "is_dir = 0")
-        print(
-            json.dumps(
-                {
-                    "library": lib.name,
-                    "location_id": loc["id"],
-                    "files": files,
-                    "objects": stats["total_object_count"],
-                    "bytes": int(stats["total_bytes_used"]),
-                    "thumbnails": node.thumbnailer.generated,
-                    "labeled": node.image_labeler.labeled
-                    if node.image_labeler
-                    else 0,
-                    "backend": args.backend,
-                    "seconds": round(elapsed, 2),
-                }
-            )
-        )
-        return 0
+        summary = await index_location(
+            node, args.path, args.library, args.backend)
+        print(json.dumps(summary))
+        return 1 if summary["jobs_failed"] else 0
     finally:
         await node.shutdown()
 

@@ -43,7 +43,7 @@ logger = logging.getLogger(__name__)
 # rows dp-sharded so every chip hashes a warm 1024-row shard from ONE
 # dispatch) with feeder.pipeline_depth windows in flight; the
 # closed-loop controller widens/narrows both from observed feeder
-# wait, link probes, and occupancy. CPU backends keep the reference's
+# wait and occupancy. CPU backends keep the reference's
 # 100-row parity chunk (autotune.IDENTIFY_CPU_WINDOW, ref:mod.rs:34).
 
 
@@ -222,24 +222,30 @@ class FileIdentifierJob(StatefulJob):
             backend == "auto" and cas._device_available()
         )
         if use_device and messages:
+            dispatch_exc: Exception | None = None
             try:
                 fin = cas.cas_ids_begin(messages)  # async dispatch NOW
-            except Exception:
-                fin = None
+            except Exception as exc:  # noqa: BLE001 - surfaced by finisher
+                fin, dispatch_exc = None, exc
 
-            def finisher(fin=fin, messages=messages, backend=backend):
+            def finisher(fin=fin, messages=messages, backend=backend,
+                         dispatch_exc=dispatch_exc):
                 # JAX dispatch is async — device failures usually surface
-                # at materialization, so the fallback wraps the FINISH
-                # (explicit "tpu" stays strict; "auto" degrades to host)
+                # at materialization, so the fallback wraps the FINISH.
+                # Explicit "tpu" stays strict: the job fails with the
+                # device's own error. "auto" degrades to host, counted.
                 if fin is not None:
                     try:
                         return fin()
                     except Exception:
                         if backend != "auto":
                             raise
-                        logger.warning("device hashing failed; host fallback")
+                        logger.warning("device hashing failed; host fallback",
+                                       exc_info=True)
                 elif backend != "auto":
-                    raise RuntimeError("device dispatch failed")
+                    raise RuntimeError(
+                        "device dispatch failed") from dispatch_exc
+                _tm.CAS_BACKEND_FALLBACK.inc()
                 return cas.cas_ids(messages, "cpu")
 
         else:

@@ -449,12 +449,9 @@ class Thumbnailer:
         if self._accel is None:
             n = 1
             if self.use_device:
-                try:
-                    from ....parallel.mesh import accelerator_count
+                from ....parallel.mesh import accelerator_count
 
-                    n = accelerator_count()
-                except Exception:  # noqa: BLE001 - no usable jax
-                    n = 1
+                n = accelerator_count()
             self._accel = n
         return _autotune.policy("thumbnail").thumb_chunk_rows(self._accel)
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 import logging
 import os
 import shutil
+import threading
 
 from ....utils.version_manager import VersionManager
 
@@ -79,7 +80,10 @@ class ThumbnailStore:
     def write(self, library_id: str | None, cas_id: str, webp: bytes) -> str:
         path = self.path_for(library_id, cas_id)
         os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = path + ".tmp"
+        # one tmp per writer: a double-leased shard has two threads
+        # publishing the same cas_id, and a shared tmp name lets the
+        # first replace() pull the file from under the second
+        tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
         with open(tmp, "wb") as f:
             f.write(webp)
         os.replace(tmp, path)  # atomic publish

@@ -60,7 +60,7 @@ def file_checksums(paths: Sequence[str | os.PathLike], backend: str = "auto") ->
             sizes.append(-1)
 
     results: list[str | None] = [None] * len(paths)
-    device_ok = backend in ("tpu", "device", "auto") and _device_available()
+    device_ok = backend in ("tpu", "device", "auto")
 
     def host_hash(i: int) -> None:
         try:
@@ -121,11 +121,3 @@ def file_checksums(paths: Sequence[str | os.PathLike], backend: str = "auto") ->
 
     return [r if r is not None else "" for r in results]
 
-
-def _device_available() -> bool:
-    try:
-        import jax
-
-        return len(jax.devices()) > 0
-    except Exception:  # noqa: BLE001
-        return False

@@ -31,6 +31,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from . import blake3_pallas
 from .blake3_ref import CHUNK_END, CHUNK_START, IV, MSG_PERMUTATION, PARENT, ROOT
 
 _U = jnp.uint32
@@ -120,11 +121,14 @@ def _as_words(msgs: jax.Array, max_chunks: int) -> jax.Array:
     ).reshape(b_dim, max_chunks * 256)
 
 
-def _chunk_cvs(words: jax.Array, lengths: jax.Array, max_chunks: int) -> tuple[jax.Array, jax.Array]:
+def _chunk_cvs(
+    words: jax.Array, lengths: jax.Array, max_chunks: int, mode: str | None
+) -> tuple[jax.Array, jax.Array]:
     """All chunk chaining values.
 
     words: uint32[B, max_chunks*256] natural-order LE message words
-    (see `_as_words`); lengths: int32[B].
+    (see `_as_words`); lengths: int32[B]; mode: the chunk-stage backend
+    (`blake3_pallas.pallas_mode()`: "tpu", "interpret", or None = XLA).
     Returns (cvs: uint32[B, C, 8], n_chunks: int32[B]). Single-chunk
     files get their ROOT flag here.
     """
@@ -144,15 +148,12 @@ def _chunk_cvs(words: jax.Array, lengths: jax.Array, max_chunks: int) -> tuple[j
     is_root_chunk = nch_n == 1  # single-chunk messages root at the chunk level
     t_lo = chunk_idx.astype(_U)
 
-    mode = _pallas_mode_static.get("mode")
     if mode is not None:
         # Pallas kernel for the hot stage (ops/blake3_pallas.py): it
         # reads the natural [N, 256] layout (contiguous HBM — the
         # word-major transpose happens per-tile in VMEM) and derives
         # block_len/flags/active from the compact per-lane vectors, so
         # beyond the message words only [N]-sized arrays cross HBM
-        from . import blake3_pallas
-
         h_fin8 = blake3_pallas.chunk_cvs(
             words.reshape(n, 256),
             chunk_len.astype(_U)[None, :],
@@ -163,7 +164,7 @@ def _chunk_cvs(words: jax.Array, lengths: jax.Array, max_chunks: int) -> tuple[j
         cvs = h_fin8.T.reshape(b_dim, c_dim, 8)
         return cvs, n_chunks
 
-    # XLA fallback: word-major [blk, word, N] layout so each scan step
+    # XLA body: word-major [blk, word, N] layout so each scan step
     # reads 16 contiguous [N] rows
     wm = words.reshape(b_dim, c_dim, 16, 16).transpose(2, 3, 0, 1).reshape(16, 16, n)
 
@@ -247,25 +248,15 @@ def _tree_reduce(cvs: jax.Array, n_chunks: jax.Array) -> jax.Array:
     return out
 
 
-# `_chunk_cvs` reads the chunk-stage backend from here at TRACE time;
-# one jitted wrapper per mode keeps the jit cache from pinning a failed
-# Pallas program onto the fallback path
-_pallas_mode_static: dict = {"mode": None}
-
-
 def _traced_hash_body(mode: str | None, msgs, lengths, max_chunks: int):
-    """Chunk stage + tree reduce with the pallas-mode switch applied at
-    trace time — the ONE hash body both the single-device and the
-    shard_map per-device programs trace. (A second copy here is how the
-    two paths would silently stop being bit-identical.)"""
-    _pallas_mode_static["mode"] = mode  # runs at trace time
-    try:
-        cvs, n_chunks = _chunk_cvs(
-            _as_words(msgs, max_chunks), lengths, max_chunks
-        )
-        return _tree_reduce(cvs, n_chunks)
-    finally:
-        _pallas_mode_static["mode"] = None
+    """Chunk stage + tree reduce — the ONE hash body both the
+    single-device and the shard_map per-device programs trace. (A
+    second copy here is how the two paths would silently stop being
+    bit-identical.)"""
+    cvs, n_chunks = _chunk_cvs(
+        _as_words(msgs, max_chunks), lengths, max_chunks, mode
+    )
+    return _tree_reduce(cvs, n_chunks)
 
 
 def _make_mode_impl(mode: str | None):
@@ -276,19 +267,10 @@ def _make_mode_impl(mode: str | None):
     return impl
 
 
+# one jitted program family per chunk-stage backend
 _hash_batch_impl_modes = {
     mode: _make_mode_impl(mode) for mode in (None, "tpu", "interpret")
 }
-
-_pallas_disabled = [False]
-
-
-def _resolve_pallas_mode() -> str | None:
-    from . import blake3_pallas
-
-    if _pallas_disabled[0]:
-        return None
-    return blake3_pallas.pallas_mode()
 
 
 # --- multi-device dp dispatch ----------------------------------------------
@@ -317,7 +299,6 @@ def _sharded_impl(mode: str | None, devices, donate_input: bool = True):
     key = (mode, tuple(d.id for d in devices), donate_input)
     impl = _sharded_impls.get(key)
     if impl is None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _dp_mesh(devices)
@@ -336,8 +317,13 @@ def _sharded_impl(mode: str | None, devices, donate_input: bool = True):
             def body(m, l):
                 return _traced_hash_body(mode, m, l, max_chunks)
 
-            return shard_map(
-                body, mesh=mesh, in_specs=(P("dp"), P("dp")), out_specs=P("dp")
+            # rows never meet (no collective), so the varying-axes
+            # check buys nothing — and it rejects both bodies: the XLA
+            # scan's carry starts from unvarying constants, and the
+            # Pallas out_shape carries no vma
+            return jax.shard_map(
+                body, mesh=mesh, in_specs=(P("dp"), P("dp")),
+                out_specs=P("dp"), check_vma=False,
             )(msgs, lengths)
 
         _sharded_impls[key] = impl
@@ -360,28 +346,9 @@ def _hash_batch_sharded(
     from ..telemetry import metrics as _tm
 
     _tm.SHARD_BATCH_ROWS.observe(msgs.shape[0] // len(devices), op="blake3")
-    placed = shard_put(msgs, devices)
-    placed_lens = shard_put(lengths, devices)
-    mode = _resolve_pallas_mode()
-    if mode is not None:
-        try:
-            return _sharded_impl(mode, devices, donate_input)(
-                placed, placed_lens, max_chunks=max_chunks
-            )
-        except Exception:  # Mosaic/compile/runtime failure → XLA path
-            import logging
-
-            logging.getLogger(__name__).exception(
-                "pallas blake3 failed; falling back to XLA permanently"
-            )
-            _pallas_disabled[0] = True
-            # a runtime failure can land AFTER the placed buffer was
-            # donated (deleted) to the failed program — re-place from
-            # the caller's host array so the XLA retry runs in place
-            placed = shard_put(msgs, devices)
-            placed_lens = shard_put(lengths, devices)
-    return _sharded_impl(None, devices, donate_input)(
-        placed, placed_lens, max_chunks=max_chunks
+    return _sharded_impl(blake3_pallas.pallas_mode(), devices, donate_input)(
+        shard_put(msgs, devices), shard_put(lengths, devices),
+        max_chunks=max_chunks,
     )
 
 
@@ -393,10 +360,10 @@ def hash_batch(msgs, lengths, max_chunks: int | None = None,
     words (all the framework ever needs: cas_id is 8 bytes, validator
     checksum 32). Numpy byte arrays are reinterpreted as uint32 on the
     HOST (a zero-copy view — same transfer bytes, and the device skips
-    the byte-pack pass entirely; see PROFILE.md). The chunk stage runs
-    as a Pallas kernel on real TPUs (ops/blake3_pallas.py), XLA
-    otherwise; any Pallas failure permanently falls back to the XLA
-    path.
+    the byte-pack pass entirely). The chunk stage runs as a Pallas
+    kernel on real TPUs (ops/blake3_pallas.py), XLA otherwise
+    (`blake3_pallas.pallas_mode`); a kernel that fails to compile or
+    run is the caller's error — no second implementation stands in.
 
     `devices`: ≥2 devices shard the batch dim over a flat `dp` mesh
     (one dispatch feeds every chip; B must divide evenly — callers pad
@@ -424,7 +391,6 @@ def hash_batch(msgs, lengths, max_chunks: int | None = None,
         words_per_chunk = 256 if msgs.dtype == jnp.uint32 else CHUNK_LEN
         max_chunks = msgs.shape[1] // words_per_chunk
     lengths = jnp.asarray(lengths, jnp.int32)
-    out = None
     if devices is not None and len(devices) > 1:
         devices = list(devices)
         if msgs.shape[0] % len(devices):
@@ -435,28 +401,17 @@ def hash_batch(msgs, lengths, max_chunks: int | None = None,
         out = _hash_batch_sharded(
             msgs, lengths, max_chunks, devices, donate_input
         )
-    elif devices is not None and len(devices) == 1:
-        # pin the single-device dispatch to THIS device (the ladder's
-        # surviving chip) — committed inputs make jit execute there,
-        # instead of on a default device that may be the dead one
-        msgs = jax.device_put(msgs, devices[0])
-        lengths = jax.device_put(lengths, devices[0])
-    if out is None:
-        mode = _resolve_pallas_mode()
-        if mode is not None:
-            try:
-                out = _hash_batch_impl_modes[mode](
-                    msgs, lengths, max_chunks=max_chunks
-                )
-            except Exception:  # Mosaic/compile/runtime failure → XLA path
-                import logging
-
-                logging.getLogger(__name__).exception(
-                    "pallas blake3 failed; falling back to XLA permanently"
-                )
-                _pallas_disabled[0] = True
-    if out is None:
-        out = _hash_batch_impl_modes[None](msgs, lengths, max_chunks=max_chunks)
+    else:
+        if devices is not None and len(devices) == 1:
+            # pin the single-device dispatch to THIS device (the
+            # ladder's surviving chip) — committed inputs make jit
+            # execute there, instead of on a default device that may
+            # be the dead one
+            msgs = jax.device_put(msgs, devices[0])
+            lengths = jax.device_put(lengths, devices[0])
+        out = _hash_batch_impl_modes[blake3_pallas.pallas_mode()](
+            msgs, lengths, max_chunks=max_chunks
+        )
     if spec is not None and spec.mode == "wrong_shape":
         out = out[:, :4]
     return out

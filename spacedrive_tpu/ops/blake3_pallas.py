@@ -10,27 +10,26 @@ VMEM so the VPU's 8×128 registers vectorize across chunk lanes. Message
 schedules are host-precomputed (perm^r applied to static indices — no
 in-kernel gathers).
 
-Round 4 finding (device trace, PROFILE.md): the previous design fed the
-kernel `[16, 16, N]` word-major data, which forced XLA to materialize a
-~235 MB HBM transpose + byte-pack around a 0.8 ms kernel — ~13 ms of
-data movement per 4096×57-chunk batch. Moving the transpose INSIDE the
-kernel (VMEM, per-tile) and bitcasting on the HOST (numpy view — zero
-copy) cut the dispatch from ~13.7 ms to ~5.4 ms measured on a v5e
-(chained-marginal timing, distinct inputs); the in-VMEM transpose costs
-~3.9 ms of the 5.4 and is the remaining optimization frontier.
+An earlier design fed the kernel `[16, 16, N]` word-major data, which
+forced XLA to materialize an HBM transpose + byte-pack of the whole
+batch around the kernel. The transpose now happens INSIDE the kernel
+(VMEM, per-tile) and the byte→word bitcast on the HOST (numpy view —
+zero copy). Device timings for either design: not measured on the
+current rig (PERF.md).
 
 On real TPUs BOTH loops — the 16-block walk and the 7 rounds — are
 fully unrolled: a `fori_loop` carrying the `[8, L]` state costs a
-Mosaic layout round-trip per block and measured 5.5× slower on a v5e.
+Mosaic layout round-trip per block.
 Interpret mode (tests) keeps the block walk ROLLED instead — the
 unrolled body is a ~5k-op graph whose CPU compile takes minutes
 (see _build_kernel).
 
 Bit-exactness contract is identical to ops/blake3_jax.py (golden-tested
 against the reference vectors); `ops/blake3_jax.hash_batch` calls this
-kernel when the backend is a real TPU (`SD_BLAKE3_PALLAS=0` opts out,
-`=1` forces interpret mode elsewhere) and falls back to its XLA path on
-any Pallas failure. Guide: /opt/skills/guides/pallas_guide.md.
+kernel when the backend is a real TPU (`SD_BLAKE3_PALLAS=0` chooses the
+XLA body instead, `=1` forces interpret mode elsewhere). A kernel that
+fails to compile or run raises to the caller — nothing stands in for
+it. Guide: /opt/skills/guides/pallas_guide.md.
 """
 
 from __future__ import annotations
@@ -63,7 +62,7 @@ def _schedules() -> tuple[tuple[int, ...], ...]:
 def _build_kernel(unroll: bool = True):
     """The chunk kernel. `unroll=True` (real TPU) inlines the 16-block
     walk — a fori_loop carrying the [8, L] state costs a Mosaic layout
-    round-trip per block, measured 5.5× slower on a v5e. Interpret mode
+    round-trip per block. Interpret mode
     gets `unroll=False`: the unrolled body is a ~5k-op graph whose CPU
     compile takes MINUTES (the parity test ran hours), while the rolled
     loop compiles the body once; the block math is shared, so parity
@@ -83,13 +82,13 @@ def _build_kernel(unroll: bool = True):
         zeros = jnp.zeros((lanes,), U)
         # one in-VMEM transpose per tile: [L, 256] natural (contiguous
         # HBM reads) -> [256, L] so each message word is a lane vector.
-        # Cheaper than the XLA HBM transpose it replaces (see module
-        # docstring), and int32 idioms throughout — Mosaic has no
-        # unsigned vector max (arith.maxui).
+        # Replaces an XLA HBM transpose of the whole batch (see module
+        # docstring); int32 idioms throughout — Mosaic has no unsigned
+        # vector max (arith.maxui).
         wt = jnp.transpose(words_ref[...], (1, 0))
         # per-block block_len/flags/active derive from the compact
         # per-lane chunk_len IN-KERNEL: shipping them as [16, N] arrays
-        # cost ~4 ms/batch of HBM traffic + XLA prologue on a v5e
+        # is 3 × 16 × N words of HBM traffic + an XLA prologue
         chunk_len = chunk_len_ref[0, :].astype(jnp.int32)
         n_blocks = jnp.maximum(1, (chunk_len + BLOCK_LEN - 1) // BLOCK_LEN)
         is_root = is_root_ref[0, :] != np.uint32(0)
@@ -184,17 +183,15 @@ def pallas_mode() -> str | None:
 
     Default: real kernel on TPU backends only. SD_BLAKE3_PALLAS=1
     forces interpret mode elsewhere (tests); =0 disables entirely.
+    Only reached from a device dispatch, so a backend that cannot
+    initialise raises here instead of reading as "not a TPU".
     """
     env = os.environ.get("SD_BLAKE3_PALLAS")
     if env == "0":
         return None
-    try:
-        import jax
+    import jax
 
-        platform = jax.devices()[0].platform
-    except Exception:
-        return None
-    if platform == "tpu":
+    if jax.devices()[0].platform == "tpu":
         return "tpu"
     return "interpret" if env == "1" else None
 

@@ -47,7 +47,6 @@ def _embed_fn_sharded(devices):
     if fn is None:
         import jax
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         import numpy as _np
@@ -59,8 +58,9 @@ def _embed_fn_sharded(devices):
             def body(imgs):
                 return _embedder.forward(params, imgs)
 
-            return shard_map(
-                body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")
+            return jax.shard_map(
+                body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+                check_vma=False,
             )(images)
 
         fn = (mesh, embed_sharded)

@@ -234,11 +234,10 @@ async def request_telemetry(p2p: Any, identity: RemoteIdentity) -> dict:
     snapshot on its side — nothing secret rides it — and this side
     validates the version before trusting the shape."""
     from ..telemetry.federation import snapshot_compatible
-    from ..utils.compat import timeout
 
     stream = await p2p.new_stream(identity)
     try:
-        async with timeout(TELEMETRY_TIMEOUT):
+        async with asyncio.timeout(TELEMETRY_TIMEOUT):
             await Header(
                 HeaderType.TELEMETRY, trace=_trace.wire_current()
             ).write(stream)
@@ -280,11 +279,10 @@ async def request_trace(p2p: Any, identity: RemoteIdentity,
     telemetry/attrib.py). Raises ``PermissionError`` on a membership
     refusal, ``ValueError`` on a malformed response — both PASS through
     the caller's resilience policy without feeding the breaker."""
-    from ..utils.compat import timeout
 
     stream = await p2p.new_stream(identity)
     try:
-        async with timeout(TELEMETRY_TIMEOUT):
+        async with asyncio.timeout(TELEMETRY_TIMEOUT):
             await Header(
                 HeaderType.TELEMETRY, trace=_trace.wire_current(),
                 telemetry_op={"op": "trace_pull", "trace_id": str(trace_id)},
@@ -323,11 +321,10 @@ async def request_profile(p2p: Any, identity: RemoteIdentity) -> dict:
     membership refusal, ``ValueError`` on a malformed response — both
     PASS through the caller's resilience policy without feeding the
     breaker."""
-    from ..utils.compat import timeout
 
     stream = await p2p.new_stream(identity)
     try:
-        async with timeout(TELEMETRY_TIMEOUT):
+        async with asyncio.timeout(TELEMETRY_TIMEOUT):
             await Header(
                 HeaderType.TELEMETRY, trace=_trace.wire_current(),
                 telemetry_op={"op": "profile_pull"},

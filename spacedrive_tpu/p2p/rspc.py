@@ -1,7 +1,7 @@
 """rspc-over-P2P — drive another node's API across the mesh.
 
 Parity: ref:core/src/p2p/operations/rspc.rs:13 — a `Header::Http`-style
-stream that tunnels API requests to a remote node, used by the frontend
+stream that carries API requests to a remote node, used by the frontend
 to browse *other* devices. Here the frame is msgpack
 `{key, arg, library_id}` → `{ok, result | error, code}` over one
 authenticated stream per request; query/mutation only (subscriptions

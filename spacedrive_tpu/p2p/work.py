@@ -473,11 +473,9 @@ async def request_work(
     """One WORK exchange. Raises ``PermissionError`` on a refusal
     (membership gate), ``ValueError`` on a malformed response — both
     PASS through the policy without feeding the breaker."""
-    from ..utils.compat import timeout as _timeout
-
     stream = await p2p.new_stream(identity)
     try:
-        async with _timeout(timeout):
+        async with asyncio.timeout(timeout):
             await Header(
                 HeaderType.WORK, library_id=library_id,
                 trace=_trace.wire_current(),

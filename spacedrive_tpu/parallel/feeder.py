@@ -68,9 +68,7 @@ class WindowPipeline(Generic[T]):
         measure: Callable[[T], int] | None = None,
     ):
         # `measure(window) -> bytes` attributes each fetched window's
-        # host→device payload to sd_feeder_h2d_bytes_total — the
-        # counter BENCH_r05 was missing when the congested link had to
-        # be diagnosed from print lines
+        # host→device payload to sd_feeder_h2d_bytes_total
         self._measure = measure
         self.stats = PipelineStats()
         # unbounded deque + condition (NOT a bounded Queue): close()

@@ -6,7 +6,7 @@ NCCL-free QUIC mesh) maps onto TPU primitives as batch-parallel
 canonical axis vocabulary — `dp` (batch), `fsdp` (param shards), `tp`
 (tensor) — and the helpers every call site shares, so meshes are built
 one way everywhere (`__graft_entry__.dryrun_multichip` exercises the
-same factoring on the driver's virtual device count).
+same factoring).
 
 Multi-host: `multihost_init()` wraps `jax.distributed.initialize` —
 inside a pod/slice collectives ride ICI; across hosts, DCN. Library
@@ -87,39 +87,33 @@ _ACCEL_COUNT: list[int] | None = None
 
 
 def accelerator_count() -> int:
-    """Local non-CPU device count, 1 when only CPU (or no jax) is live.
+    """Local non-CPU device count, 1 when only CPU is live.
 
     The batch/depth scale factor for dp dispatch: the identifier's
     chunk size, the thumbnailer's device chunk, and the feeder depth
     all multiply by this so one host window feeds the whole mesh.
     Virtual host-platform devices deliberately do NOT count — they
     share the same cores, so scaling host batches by them only makes
-    batches slower."""
+    batches slower. A backend that cannot initialise (a chip held by
+    another process) raises: it must fail the start, not read as
+    "CPU only"."""
     global _ACCEL_COUNT
     if _ACCEL_COUNT is None:
-        try:
-            import jax
+        import jax
 
-            devs = jax.devices()
-            _ACCEL_COUNT = [
-                len(devs) if devs and devs[0].platform != "cpu" else 1
-            ]
-        except Exception:  # noqa: BLE001 - no usable accelerator
-            _ACCEL_COUNT = [1]
+        devs = jax.devices()
+        _ACCEL_COUNT = [len(devs) if devs[0].platform != "cpu" else 1]
     return _ACCEL_COUNT[0]
 
 
 def dispatch_devices() -> list:
-    """All local JAX devices for dp-sharded dispatch ([] when jax is
-    unusable). Unlike `accelerator_count`, virtual CPU devices DO
-    appear here — sharding is a correctness surface the test suite
-    exercises on the forced host platform."""
-    try:
-        import jax
+    """All local JAX devices for dp-sharded dispatch. Unlike
+    `accelerator_count`, virtual CPU devices DO appear here — sharding
+    is a correctness surface the test suite exercises on the forced
+    host platform. Raises when the backend cannot initialise."""
+    import jax
 
-        return list(jax.devices())
-    except Exception:  # noqa: BLE001
-        return []
+    return list(jax.devices())
 
 
 # --- graceful degradation ladder (utils/resilience + utils/faults) ---------
@@ -302,13 +296,10 @@ LADDER = DeviceLadder()
 
 def ladder_devices() -> tuple[list[Any], int]:
     """``dispatch_devices()`` filtered through the degradation ladder:
-    (devices, level) — an empty list means use the host reference
-    path. Callers MUST report the dispatch outcome back to ``LADDER``
+    (devices, level) — an empty list (``LEVEL_HOST``) means use the
+    host reference path. Callers MUST report the dispatch outcome back to ``LADDER``
     so demotion/re-arm bookkeeping stays truthful."""
-    devs = dispatch_devices()
-    if not devs:
-        return [], LEVEL_HOST
-    return LADDER.filter(devs)
+    return LADDER.filter(dispatch_devices())
 
 
 def multihost_init(

@@ -165,10 +165,6 @@ JOB_PHASE_SECONDS = REGISTRY.histogram(
 
 # --- bench (bench.py) -------------------------------------------------------
 
-BENCH_LINK_PROBE_GBPS = REGISTRY.gauge(
-    "sd_bench_link_probe_gbps",
-    "latest host→device link probe (device_put bandwidth)",
-)
 # bench reads its median/spread back out of these rings, so they must
 # hold every sample of the largest plausible SD_BENCH_REPEATS run —
 # the default 128-sample ring would silently truncate repeats > 128

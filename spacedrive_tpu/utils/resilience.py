@@ -304,9 +304,7 @@ class ResiliencePolicy:
                 if budget is None:
                     result = await fn()
                 else:
-                    from .compat import timeout
-
-                    async with timeout(budget):
+                    async with asyncio.timeout(budget):
                         result = await fn()
             except (asyncio.CancelledError, KeyboardInterrupt, SystemExit):
                 # cancellation/exit is never an attempt failure: it must

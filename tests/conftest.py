@@ -16,18 +16,9 @@ force_cpu_devices(8)
 
 # Persistent XLA compile cache for the CPU-mesh programs: the slow
 # suite's device-shape matrix costs ~1 h of single-core compiles COLD,
-# and milliseconds warm. Tests get their own cache dir so they can't
-# poison (or be poisoned by) the production TPU cache. Set via the env
-# var (not a function arg) so subprocess tests — the multihost children
-# call configure_compilation_cache() themselves — inherit the same
-# isolation, and the helper keeps owning the path derivation.
-os.environ.setdefault(
-    "SD_XLA_CACHE_DIR",
-    os.path.join(
-        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-        "spacedrive_tpu_xla_tests",
-    ),
-)
+# and milliseconds warm. Same rule as production (ops/__init__.py):
+# JAX_COMPILATION_CACHE_DIR places it, else <checkout>/.jax_cache —
+# subprocess tests inherit the variable and derive the same default.
 from spacedrive_tpu.ops import configure_compilation_cache  # noqa: E402
 
 configure_compilation_cache()

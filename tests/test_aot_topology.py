@@ -1,0 +1,63 @@
+"""AOT-compile device programs for a chip this sandbox does not have.
+
+libtpu ships the compiler, so `jax.experimental.topologies` can hand out
+a `v5e:2x2` topology descriptor on a CPU-only host and `.lower().compile()`
+runs Mosaic + XLA:TPU against it. A PR learns "Mosaic refuses this
+kernel" or "this resize bucket no longer fits" here, at no chip cost.
+Slow lane only: the two programs take roughly 17 s + 11 s to compile.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_CHILD = r"""
+import jax, numpy as np
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+from spacedrive_tpu.ops import blake3_jax, thumbnail_jax
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+assert len(topo.devices) == 4, topo.devices
+one = SingleDeviceSharding(topo.devices[0])
+
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+# the 32-row rung of the 57-chunk hot bucket, Pallas chunk stage
+hashed = blake3_jax._hash_batch_impl_modes["tpu"].lower(
+    spec((32, 57 * 256), np.uint32), spec((32,), np.int32), max_chunks=57
+).compile()
+assert hashed.out_info.shape == (32, 8), hashed.out_info
+assert hashed.memory_analysis().generated_code_size_in_bytes > 0
+
+# one resize bucket: 4 canvases of 1024² RGBA → the 1024² output canvas
+resized = thumbnail_jax._resize_fn().lower(
+    spec((4, 1024, 1024, 4), np.uint8), spec((4, 2), np.float32),
+    out_size=thumbnail_jax.OUT_CANVAS,
+).compile()
+assert resized.out_info.shape == (4, 1024, 1024, 4), resized.out_info
+# canvases + scales in, one output canvas each out — and it fits a chip
+mem = resized.memory_analysis()
+assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 << 30
+print("AOT_OK")
+"""
+
+
+@pytest.mark.slow
+def test_hash_and_resize_programs_compile_for_v5e():
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+        TPU_ACCELERATOR_TYPE="v5litepod-4", TPU_WORKER_HOSTNAMES="localhost",
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", _CHILD], env=env, cwd=REPO,
+        capture_output=True, text=True, timeout=900,
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "AOT_OK" in out.stdout

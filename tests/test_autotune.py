@@ -3,14 +3,14 @@
 Covers the ISSUE-8 contract:
 
 - cold start: no samples yet ⇒ every policy holds the static defaults;
-- oscillation damping: alternating congested/clear samples must NOT
+- oscillation damping: alternating pad-heavy/full samples must NOT
   thrash the ladder rung (or any knob) every tick;
 - DeviceLadder interaction: the autotuner may never promote the
   dispatch rung past what the demotion level allows;
 - ``SD_AUTOTUNE=0``: policy reads equal the pre-autotuner static
   constants exactly, and the device pipeline's outputs (cas_ids and
   thumbnail bytes) are bit-identical to the reference paths;
-- sizing changes never change bytes: a congested-then-promoted policy
+- sizing changes never change bytes: a demoted-then-promoted policy
   produces the same cas_ids as the static config.
 """
 
@@ -23,7 +23,6 @@ from spacedrive_tpu.parallel import autotune
 from spacedrive_tpu.parallel import mesh as _mesh
 from spacedrive_tpu.parallel.autotune import (
     BATCH_LADDER,
-    CONGESTED_GBPS,
     Controller,
     Sample,
     STARVED_WAIT_S,
@@ -45,19 +44,18 @@ def _isolated_autotune(monkeypatch):
 
 
 def starved() -> Sample:
-    return Sample(wait_mean_s=STARVED_WAIT_S * 4, wait_n=3,
-                  link_gbps=CONGESTED_GBPS * 3)
+    return Sample(wait_mean_s=STARVED_WAIT_S * 4, wait_n=3)
 
 
-def congested() -> Sample:
-    s = Sample(link_gbps=CONGESTED_GBPS / 10)
+def pad_heavy() -> Sample:
+    s = Sample()
     s.occ_mean["blake3"] = 0.3
     s.occ_n["blake3"] = 2
     return s
 
 
-def clear_sample(occ: float = 0.95) -> Sample:
-    s = Sample(link_gbps=CONGESTED_GBPS * 3)
+def full_sample(occ: float = 0.95) -> Sample:
+    s = Sample()
     s.occ_mean["blake3"] = occ
     s.occ_n["blake3"] = 2
     return s
@@ -111,55 +109,25 @@ def test_starvation_widens_window_and_deepens_pipeline():
     assert pol.window_scale <= autotune.SCALE_MAX
     assert pol.feeder_depth(1) <= autotune.FEEDER_DEPTH_CAP
     # and decays back toward static once the pipeline runs ahead
-    comfortable = Sample(wait_mean_s=0.0001, wait_n=3,
-                         link_gbps=CONGESTED_GBPS * 3)
+    comfortable = Sample(wait_mean_s=0.0001, wait_n=3)
     for _ in range(60):
         c.tick(comfortable)
     assert pol.window_scale == 1.0
     assert pol.depth_extra == 0
 
 
-def test_congested_link_demotes_rung():
+def test_rung_follows_dispatch_occupancy():
+    """Chips hauling pad rows ⇒ the rung is oversized and demotes all
+    the way down; full batches alone promote it back up (damped) — the
+    rung must not be a demote-only ratchet."""
     c = Controller(interval=999)
     pol = c.policies["identify"]
     for _ in range(6 * STEP_STREAK):
-        c.tick(congested())
+        c.tick(pad_heavy())
     assert pol.rung == 0
     assert pol.dispatch_rows_per_device() == BATCH_LADDER[0]
-    # a clear link with full batches promotes back up (damped)
     for _ in range(6 * STEP_STREAK):
-        c.tick(clear_sample())
-    assert pol.rung == len(BATCH_LADDER) - 1
-
-
-def test_low_occupancy_demotes_rung_on_clear_link():
-    """Chips hauling pad rows ⇒ the rung is oversized regardless of
-    link weather."""
-    c = Controller(interval=999)
-    pol = c.policies["identify"]
-    for _ in range(4 * STEP_STREAK):
-        c.tick(clear_sample(occ=0.2))
-    assert pol.rung < len(BATCH_LADDER) - 1
-
-
-def test_rung_promotes_on_full_batches_without_link_probe():
-    """Production nodes never set sd_bench_link_probe_gbps (only bench
-    rigs do): with the probe absent (0.0), full batches alone must be
-    able to promote the rung back up — a probe-gated promote path
-    would make the rung a demote-only ratchet outside the bench."""
-    c = Controller(interval=999)
-    pol = c.policies["identify"]
-    no_probe_low = Sample()
-    no_probe_low.occ_mean["blake3"] = 0.2
-    no_probe_low.occ_n["blake3"] = 2
-    for _ in range(4 * STEP_STREAK):
-        c.tick(no_probe_low)
-    assert pol.rung < len(BATCH_LADDER) - 1
-    no_probe_full = Sample()
-    no_probe_full.occ_mean["blake3"] = 0.95
-    no_probe_full.occ_n["blake3"] = 2
-    for _ in range(6 * STEP_STREAK):
-        c.tick(no_probe_full)
+        c.tick(full_sample())
     assert pol.rung == len(BATCH_LADDER) - 1
 
 
@@ -167,7 +135,7 @@ def test_rung_promotes_on_full_batches_without_link_probe():
 
 
 def test_alternating_signals_do_not_thrash():
-    """Alternating congested/clear samples: the streak resets on every
+    """Alternating pad-heavy/full samples: the streak resets on every
     direction flip, so the rung must hold still (and so must every
     other knob)."""
     c = Controller(interval=999)
@@ -175,20 +143,20 @@ def test_alternating_signals_do_not_thrash():
     before = pol.snapshot()
     decisions = []
     for i in range(50):
-        decisions += c.tick(congested() if i % 2 == 0 else clear_sample())
+        decisions += c.tick(pad_heavy() if i % 2 == 0 else full_sample())
     assert pol.snapshot() == before
     assert decisions == []
 
 
 def test_sustained_signal_still_steps_after_damping():
     """Damping must delay, not disable: STEP_STREAK consecutive
-    congested ticks step exactly once."""
+    pad-heavy ticks step exactly once."""
     c = Controller(interval=999)
     pol = c.policies["identify"]
     for i in range(STEP_STREAK - 1):
-        c.tick(congested())
+        c.tick(pad_heavy())
         assert pol.rung == len(BATCH_LADDER) - 1, f"stepped early at {i}"
-    c.tick(congested())
+    c.tick(pad_heavy())
     assert pol.rung == len(BATCH_LADDER) - 2
 
 
@@ -202,21 +170,21 @@ def test_never_promotes_past_device_ladder_demotion():
     _mesh.LADDER._level = _mesh.LEVEL_SUBSET
     try:
         # the clamp lands on the next tick, undamped
-        c.tick(clear_sample())
+        c.tick(full_sample())
         assert pol.rung == 1
-        # sustained clear-link pressure must NOT promote past the cap
+        # sustained full-batch pressure must NOT promote past the cap
         for _ in range(10 * STEP_STREAK):
-            c.tick(clear_sample())
+            c.tick(full_sample())
         assert pol.rung <= 1
         assert pol.dispatch_rows_per_device() <= BATCH_LADDER[1]
         # host-path demotion pins the bottom rung
         _mesh.LADDER._level = _mesh.LEVEL_HOST
-        c.tick(clear_sample())
+        c.tick(full_sample())
         assert pol.dispatch_rows_per_device() == BATCH_LADDER[0]
         # ladder re-armed: promotion is allowed again (damped)
         _mesh.LADDER._level = _mesh.LEVEL_MESH
         for _ in range(10 * STEP_STREAK):
-            c.tick(clear_sample())
+            c.tick(full_sample())
         assert pol.rung == len(BATCH_LADDER) - 1
     finally:
         _mesh.LADDER.reset()

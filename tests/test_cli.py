@@ -85,6 +85,76 @@ def test_cli_index_browse_crypto(tmp_path, capsys):
     assert secret.read_text() == "classified"
 
 
+def _index_with_faults(tmp_path, capsys, plan: str):
+    """One `sdx index --backend tpu` pass under a fault plan; returns
+    (exit code, the printed JSON summary)."""
+    from spacedrive_tpu.parallel import mesh
+    from spacedrive_tpu.utils import faults
+
+    corpus = tmp_path / "corpus"
+    corpus.mkdir()
+    for i in range(6):
+        (corpus / f"f{i}.bin").write_bytes(os.urandom(2000 + i))
+    mesh.LADDER.reset()
+    try:
+        rc = main([
+            "--data-dir", str(tmp_path / "home"), "--faults", plan,
+            "index", str(corpus), "--backend", "tpu", "--no-p2p",
+        ])
+    finally:
+        faults.clear()
+        mesh.LADDER.reset()
+    return rc, json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_cli_index_reports_what_ran_not_what_was_asked(tmp_path, capsys):
+    """`--backend tpu` with the device hash forced to fail: the ladder
+    finishes the pass on the host (exit 0, every job COMPLETED) and the
+    summary says so — device stamp, final ladder level, fallback
+    counts — instead of echoing the flag."""
+    import jax
+
+    from spacedrive_tpu.parallel import mesh
+    from spacedrive_tpu.telemetry import counter_value
+    from spacedrive_tpu.telemetry.events import RESILIENCE_EVENTS
+
+    # the counts are process-wide (a CLI process starts at zero; this
+    # test process may not)
+    before = counter_value("sd_cas_backend_fallback_total")
+    thumb_before = sum(e["type"] == "thumbnail_cpu_fallback"
+                       for e in RESILIENCE_EVENTS.snapshot())
+    rc, out = _index_with_faults(
+        tmp_path, capsys, "device.blake3:raise:times=inf")
+    assert rc == 0 and out["jobs_failed"] == 0
+    assert out["jobs"] == {
+        "indexer": "COMPLETED", "file_identifier": "COMPLETED",
+        "media_processor": "COMPLETED",
+    }
+    assert out["files"] == 6 and out["backend"] == "tpu"
+    assert set(out["job_seconds"]) == set(out["jobs"])
+    assert all(s >= 0 for s in out["job_seconds"].values())
+    assert out["device"] == {
+        "platform": "cpu", "kind": jax.devices()[0].device_kind, "count": 8,
+    }
+    assert out["ladder_level"] == mesh.LEVEL_HOST
+    assert out["cas_backend_fallbacks"] > before
+    assert out["thumbnail_cpu_fallbacks"] == thumb_before
+    assert out["thumbnail_errors"] == 0
+
+
+def test_cli_index_exits_nonzero_when_a_job_fails(tmp_path, capsys):
+    """A FAILED job in the chain is the command's failure: the feeder's
+    producer crashing past its one restart fails the identifier, no
+    successor spawns, and `sdx index` returns 1."""
+    rc, out = _index_with_faults(
+        tmp_path, capsys, "feeder.fetch:crash:times=inf")
+    assert rc == 1
+    assert out["jobs"] == {
+        "indexer": "COMPLETED", "file_identifier": "FAILED",
+    }
+    assert out["jobs_failed"] == 1
+
+
 def test_relay_command_serves_rendezvous(tmp_path):
     """`sdx relay` runs the standalone relay: sync HTTP API up AND the
     P2P rendezvous accepting authenticated registrations."""

@@ -224,10 +224,9 @@ def test_spaceblock_transfer_and_cancel(tmp_path):
         cancel = asyncio.Event()
         cancel.set()
         rx = Transfer(reqs, cancelled=cancel)
-        from spacedrive_tpu.utils.compat import timeout
 
         with pytest.raises(TransferCancelled):
-            async with timeout(5):
+            async with asyncio.timeout(5):
                 send_task = asyncio.ensure_future(
                     Transfer(reqs).send(Duplex(b2a, a2b), [io.BytesIO(data)])
                 )

@@ -170,3 +170,42 @@ def test_auto_policy_keeps_small_batches_single_device(monkeypatch):
     ]
     cas.cas_ids_begin(big)()
     assert calls == [8]
+
+
+def test_pallas_chunk_kernel_under_dp_shard_map(monkeypatch):
+    """The Pallas chunk kernel (interpret mode here) INSIDE the dp
+    shard_map equals the pure-Python reference — the combination a
+    multi-chip host runs, which used to fail at trace time and fall
+    back without a word."""
+    from spacedrive_tpu.ops import blake3_jax, blake3_pallas
+    from spacedrive_tpu.ops.blake3_ref import blake3_hex
+
+    monkeypatch.setenv("SD_BLAKE3_PALLAS", "1")
+    assert blake3_pallas.pallas_mode() == "interpret"
+    lens = [0, 5, 1024, 1025, 2048, 1500]  # 6 rows → 3 per device
+    msgs = np.zeros((len(lens), 2 * 1024), np.uint8)
+    data = RNG.integers(0, 256, 2048, dtype=np.uint8)
+    for i, n in enumerate(lens):
+        msgs[i, :n] = data[:n]
+    words = blake3_jax.hash_batch(
+        msgs, np.array(lens, np.int32), max_chunks=2, devices=_devices()[:2]
+    )
+    got = blake3_jax.words_to_hex(words, 64)
+    assert got == [blake3_hex(data[:n].tobytes()) for n in lens]
+
+
+def test_pallas_failure_is_the_callers_error(monkeypatch):
+    """A kernel that cannot run raises to the caller; no second device
+    implementation stands in for it."""
+    from spacedrive_tpu.ops import blake3_jax, blake3_pallas
+
+    def boom(*a, **k):
+        raise RuntimeError("mosaic says no")
+
+    monkeypatch.setenv("SD_BLAKE3_PALLAS", "1")
+    monkeypatch.setattr(blake3_pallas, "chunk_cvs", boom)
+    # a shape no other test compiles, so the jit cache cannot serve it
+    msgs = np.zeros((3, 4 * 1024), np.uint8)
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        blake3_jax.hash_batch(msgs, np.full((3,), 4000, np.int32),
+                              max_chunks=4)

@@ -16,22 +16,14 @@ Comparison rules:
 - every throughput-shaped series is gated: the headline ``parsed
   .value`` plus any numeric ``extras`` entry whose name marks a rate
   (``*_files_per_s``, ``*_thumbs_per_s``, ``*_per_s``, ``*throughput*``,
-  ``*_gbps``) — cas_id and thumbnail rates ride the same rule;
-- runs flagged ``blocked`` (congested host→device link) gate only
-  device-side rates: e2e numbers under a congested link measure the
-  container's network weather, not the code.
+  ``*_gbps``) — cas_id and thumbnail rates ride the same rule.
 
 BENCH_E2E leg: when ``BENCH_E2E_prev.json`` and ``BENCH_E2E.json`` both
 exist (bench_e2e.py archives the replaced artifact), the per-config
 rate series (``config1.device_files_per_s``, …,
 ``config_warm.warm_files_per_s``, ``config_mesh.mesh2_files_per_s`` +
 the warm journal hit rate and mesh scaling_efficiency) gate with the
-same threshold; a config carrying ``blocked: congested-link`` on
-either side is excused — its rates measured the tunnel, not the code.
-Journal-/host-bound configs (config_warm, config_mesh) are never
-stamped blocked: under congestion they carry ``link_context`` and only
-their link-sensitive cold-leg rates are excused — their headline rates
-move ~0 device bytes and always gate.
+same threshold.
 
 BENCH_AUTOTUNE leg: when ``BENCH_AUTOTUNE.json`` exists (``make
 bench-autotune``), the adaptive series gates ABSOLUTELY rather than
@@ -85,9 +77,6 @@ DEFAULT_THRESHOLD = 0.15
 _RATE_NAME = re.compile(
     r"(_files_per_s|_thumbs_per_s|_clips_per_s|_per_s|throughput|_gbps)$"
 )
-# e2e rates that depend on the host→device link, skipped when either
-# run was marked blocked (link congestion is weather, not code)
-_LINK_BOUND = re.compile(r"(e2e|link_probe)")
 
 
 def _series(doc: dict[str, Any]) -> dict[str, float]:
@@ -104,25 +93,17 @@ def _series(doc: dict[str, Any]) -> dict[str, float]:
     return out
 
 
-def _blocked(doc: dict[str, Any]) -> bool:
-    return bool((doc.get("parsed") or {}).get("blocked"))
-
-
 def compare(old: dict[str, Any], new: dict[str, Any],
             threshold: float = DEFAULT_THRESHOLD) -> dict[str, Any]:
     """Diff two bench documents. Returns {checked, regressions,
     skipped} where regressions is a list of {name, old, new, delta}."""
     old_s, new_s = _series(old), _series(new)
-    link_excused = _blocked(old) or _blocked(new)
     checked: list[dict[str, Any]] = []
     regressions: list[dict[str, Any]] = []
     skipped: list[str] = []
     for name in sorted(old_s):
         if name not in new_s:
             skipped.append(f"{name}: absent in newer run")
-            continue
-        if link_excused and _LINK_BOUND.search(name):
-            skipped.append(f"{name}: link-bound rate on a blocked run")
             continue
         ov, nv = old_s[name], new_s[name]
         if ov <= 0:
@@ -160,23 +141,14 @@ def _rig_cores(sec: dict[str, Any]) -> int:
         if isinstance(v, int) and not isinstance(v, bool):
             return v
     return 0
-# rates that lean on a link-bound COLD leg: excused (only these) when a
-# non-link-bound config ran under congestion (``link_context`` stamp —
-# bench_e2e.probed(link_bound=False)). The headline warm/mesh rates move
-# ~0 device bytes and always gate; stamping the whole config ``blocked``
-# here is exactly the bug that made bench-check excuse real warm-path
-# regressions.
-_LINK_SENSITIVE_KEYS = ("cold_files_per_s", "warm_speedup_vs_cold")
 
 
 def e2e_series(doc: dict[str, Any]) -> dict[str, float]:
-    """Comparable {config.metric: value} rates from a BENCH_E2E doc.
-    Blocked configs contribute nothing — their numbers measured the
-    congested link, so neither side of a diff may lean on them."""
+    """Comparable {config.metric: value} rates from a BENCH_E2E doc."""
     out: dict[str, float] = {}
     for cfg in _E2E_CONFIGS:
         sec = doc.get(cfg)
-        if not isinstance(sec, dict) or sec.get("blocked"):
+        if not isinstance(sec, dict):
             continue
         for k, v in sec.items():
             if isinstance(v, bool) or not isinstance(v, (int, float)):
@@ -202,15 +174,9 @@ def _compare_attrib(cfg: str, old_cfg: dict[str, Any],
                     skipped: list) -> None:
     """Gate one config's attribution bucket split (lower-is-better
     seconds; a bucket absorbing >threshold more time per file fails
-    like any rate regression). Configs that ran under a congested link
-    (blocked or link_context) are excused wholesale — a weather-
-    inflated link bucket reshuffles every share."""
+    like any rate regression)."""
     old_a, new_a = old_cfg.get("attrib"), new_cfg.get("attrib")
     if not isinstance(old_a, dict) or not isinstance(new_a, dict):
-        return
-    if old_cfg.get("blocked") or new_cfg.get("blocked") \
-            or old_cfg.get("link_context") or new_cfg.get("link_context"):
-        skipped.append(f"{cfg}.attrib: congested-link run on one side")
         return
     # fixed bucket keys plus whatever gap_<group>_s_per_kfile frame
     # groups the host profiler decomposed. Dynamic keys gate only when
@@ -267,21 +233,7 @@ def compare_e2e(old: dict[str, Any], new: dict[str, Any],
     for name in sorted(old_s):
         cfg, _, key = name.partition(".")
         if name not in new_s:
-            reason = (
-                "blocked (congested link) in one run"
-                if (old.get(cfg) or {}).get("blocked")
-                or (new.get(cfg) or {}).get("blocked")
-                else "absent in newer run"
-            )
-            skipped.append(f"{name}: {reason}")
-            continue
-        if key in _LINK_SENSITIVE_KEYS and (
-            (old.get(cfg) or {}).get("link_context")
-            or (new.get(cfg) or {}).get("link_context")
-        ):
-            skipped.append(
-                f"{name}: cold-leg rate with congested-link context"
-            )
+            skipped.append(f"{name}: absent in newer run")
             continue
         if key in _SCALING_KEYS:
             oc = _rig_cores(old.get(cfg) or {})
