@@ -17,6 +17,8 @@ import threading
 import uuid
 from typing import Any, Iterable, Iterator, Sequence
 
+from ..telemetry import metrics as _tm
+from ..telemetry import span
 from .schema import MIGRATIONS
 
 
@@ -61,6 +63,8 @@ class LibraryDb:
         self._conn = sqlite3.connect(self.path, check_same_thread=False)
         self._conn.row_factory = dict_row
         self._lock = threading.RLock()
+        # guarded by _lock: only the outermost committing call is timed
+        self._in_txn, self._txn_wrote = False, False
         with self._lock:
             self._conn.execute("PRAGMA journal_mode=WAL")
             self._conn.execute("PRAGMA foreign_keys=ON")
@@ -71,12 +75,15 @@ class LibraryDb:
 
     def _migrate(self) -> None:
         version = self._conn.execute("PRAGMA user_version").fetchone()["user_version"]
-        while version < len(MIGRATIONS):
-            with self._conn:
-                for stmt in MIGRATIONS[version]:
-                    self._conn.execute(stmt)
-                version += 1
-                self._conn.execute(f"PRAGMA user_version={version}")
+        if version >= len(MIGRATIONS):
+            return
+        with span("db.migrate"):  # a fresh library builds its whole schema here
+            while version < len(MIGRATIONS):
+                with self._conn:
+                    for stmt in MIGRATIONS[version]:
+                        self._conn.execute(stmt)
+                    version += 1
+                    self._conn.execute(f"PRAGMA user_version={version}")
 
     def close(self) -> None:
         with self._lock:
@@ -88,20 +95,38 @@ class LibraryDb:
     def transaction(self) -> Iterator[sqlite3.Connection]:
         """Exclusive write transaction (the sync layer's atomicity
         guarantee: domain rows + crdt_operation rows in one tx,
-        ref:core/crates/sync/src/manager.rs:70-93)."""
+        ref:core/crates/sync/src/manager.rs:70-93), and the one door
+        every commit goes through: a `db.txn` span under whatever span
+        encloses it (so the profile shows which stage waited for
+        SQLite) and, if anything was written, one observation of
+        `sd_db_txn_seconds`. One per commit, never one per row; a block
+        nested inside another's rides the outer one's span."""
         with self._lock:
-            with self._conn:
-                yield self._conn
+            if self._in_txn:
+                # sqlite3's connection block commits on exit, so the
+                # inner one commits what the outer has written so far
+                with self._conn:
+                    yield self._conn
+                    self._txn_wrote |= self._conn.in_transaction
+                return
+            self._in_txn, self._txn_wrote = True, False
+            try:
+                with span("db.txn") as txn:
+                    with self._conn:
+                        yield self._conn
+                        self._txn_wrote |= self._conn.in_transaction
+            finally:
+                self._in_txn = False
+            if self._txn_wrote:
+                _tm.DB_TXN_SECONDS.observe(txn.duration)
 
     def execute(self, sql: str, params: Sequence | dict = ()) -> sqlite3.Cursor:
-        with self._lock:
-            with self._conn:
-                return self._conn.execute(sql, params)
+        with self.transaction() as conn:
+            return conn.execute(sql, params)
 
     def executemany(self, sql: str, seq: Iterable[Sequence]) -> None:
-        with self._lock:
-            with self._conn:
-                self._conn.executemany(sql, seq)
+        with self.transaction() as conn:
+            conn.executemany(sql, seq)
 
     @staticmethod
     def _maybe_slow() -> None:

@@ -23,6 +23,7 @@ import msgpack
 
 from ..tasks import ExecStatus, Interrupter, InterruptionKind, Task
 from ..telemetry import metrics as _tm
+from ..telemetry import span
 from ..telemetry import trace as _trace
 from .report import JobReport, JobStatus
 
@@ -231,7 +232,10 @@ class JobRunnerTask(Task):
                     job.run_metadata.update(result.metadata)
                 ctx.progress(completed_task_count=job.step_number)
 
-            self.output = await job.finalize(ctx)
+            # what a job does after its last step (the indexer's size
+            # roll-up, vouches, totals) has a name: one span per job
+            async with span("job.finalize"):
+                self.output = await job.finalize(ctx)
             return ExecStatus.DONE
         except JobError:
             raise

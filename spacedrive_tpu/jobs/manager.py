@@ -16,6 +16,8 @@ from typing import Any
 
 from ..db.database import now_iso
 from ..tasks import TaskStatus, TaskSystem
+from ..telemetry import profiler as _profiler
+from ..telemetry import span
 from ..telemetry import trace as _trace
 from ..telemetry.events import JOB_EVENTS
 from ..utils.tasks import supervise
@@ -67,6 +69,12 @@ class JobManager:
         # the whole chain and every batch it coalesces runs under it
         if job.trace_ctx is None:
             job.trace_ctx = _trace.current() or _trace.new_context()
+        # the manager's own part of a job, under the job's trace: its
+        # report row, the markers, the hand-over to the task system
+        with _trace.use(job.trace_ctx), span("job.ingest"):
+            self._ingest(job, library, parent)
+
+    def _ingest(self, job: StatefulJob, library: Any, parent: JobReport | None) -> None:
         report = JobReport(
             id=job.id,
             name=job.NAME,
@@ -90,6 +98,10 @@ class JobManager:
         report.update(library.db)
         JOB_EVENTS.emit("running", job=job.NAME, id=str(job.id))
         runner = JobRunnerTask(job, ctx)
+        # SD_JAX_PROFILE: one refcounted profiler session per chain. A
+        # successor is dispatched inside this job's supervisor, so it
+        # takes its hold before this one is released below
+        profiling = _profiler.profile_start(job.NAME)
         # dispatch under the job's context so the task-system boundary
         # carries it (cold resume re-enters here with the deserialized
         # context and the resumed job continues its original trace)
@@ -104,9 +116,18 @@ class JobManager:
         )
         self._supervisor_by_job[job.id] = sup
         sup.add_done_callback(lambda _t, jid=job.id: self._supervisor_by_job.pop(jid, None))
+        if profiling:
+            sup.add_done_callback(lambda _t: _profiler.profile_stop())
 
     async def _supervise(self, job: StatefulJob, library: Any, handle, ctx: JobContext) -> None:
         result = await handle.wait()
+        # after the job's last step: final report, notification, cache
+        # invalidation and the chain's next ingest
+        with _trace.use(job.trace_ctx):
+            async with span("job.settle"):
+                await self._settle(job, library, result, ctx)
+
+    async def _settle(self, job: StatefulJob, library: Any, result, ctx: JobContext) -> None:
         # close the job's final phase so sd_job_phase_seconds accounts
         # the full wall time, not just up to the last transition
         ctx._close_phase()

@@ -232,20 +232,23 @@ class IndexerJob(StatefulJob):
                 + time.perf_counter() - t_scan, 4
             )
             return StepResult(more_steps=more)
-        if kind == "save":
-            self._save_batch(library, loc_id, step["entries"], update=False)
-        elif kind == "update":
-            self._save_batch(library, loc_id, step["entries"], update=True)
-        elif kind == "remove":
-            ops = []
-            for pub_id in step["pub_ids"]:
-                ops.extend([library.sync.shared_delete("file_path", pub_id.hex())])
-
-            def deletes(conn):
+        # one span per step of up to BATCH_SIZE rows, whatever its kind:
+        # the save transactions apart from the directory scan (`walk`)
+        with span("indexer.save"):
+            if kind == "save":
+                self._save_batch(library, loc_id, step["entries"], update=False)
+            elif kind == "update":
+                self._save_batch(library, loc_id, step["entries"], update=True)
+            elif kind == "remove":
+                ops = []
                 for pub_id in step["pub_ids"]:
-                    conn.execute("DELETE FROM file_path WHERE pub_id = ?", (pub_id,))
+                    ops.extend([library.sync.shared_delete("file_path", pub_id.hex())])
 
-            library.sync.write_ops(ops, deletes)
+                def deletes(conn):
+                    for pub_id in step["pub_ids"]:
+                        conn.execute("DELETE FROM file_path WHERE pub_id = ?", (pub_id,))
+
+                library.sync.write_ops(ops, deletes)
         self.run_metadata["db_write_time"] = round(
             self.run_metadata.get("db_write_time", 0.0) + time.perf_counter() - t0, 4
         )

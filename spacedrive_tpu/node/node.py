@@ -19,6 +19,7 @@ from ..object.media.thumbnail.actor import Thumbnailer
 from ..parallel import autotune as _autotune
 from ..object.orphan_remover import OrphanRemoverActor
 from ..tasks.system import TaskSystem
+from ..telemetry import span
 from ..telemetry.events import LoopLagMonitor
 from ..utils.events import EventBus
 from ..utils.tracing import init_logger, install_loop_excepthook
@@ -39,6 +40,11 @@ class Node:
         with_logger: bool = False,
         with_labeler: bool = True,
     ):
+        with span("node.init"):
+            self._build(data_dir, use_device, with_logger, with_labeler)
+
+    def _build(self, data_dir: str | os.PathLike, use_device: bool,
+               with_logger: bool, with_labeler: bool) -> None:
         self.data_dir = os.fspath(data_dir)
         os.makedirs(self.data_dir, exist_ok=True)
         if with_logger:
@@ -162,40 +168,41 @@ class Node:
         if self._started:
             return
         self._started = True
-        # observability: orphaned-task crashes reach the log + error
-        # ring, and the loop-lag sampler feeds the flight recorder
-        import asyncio
+        async with span("node.start"):
+            # observability: orphaned-task crashes reach the log + error
+            # ring, and the loop-lag sampler feeds the flight recorder
+            import asyncio
 
-        install_loop_excepthook(asyncio.get_running_loop())
-        self.loop_monitor.start()
-        self.history.start()
-        self.autotuner.start()
-        # host profiling: tag THIS thread as the event-loop thread so
-        # samples classify as loop vs feeder vs worker, then take a
-        # refcounted hold on the process sampler
-        self.profiler.register_loop_thread()
-        self._profiler_started = self.profiler.start()
-        # worker processes up before any job runs, so the first shard's
-        # pool batches never pay spawn latency inside a measured pass
-        self._procpool_started = self.procpool.start()
-        # resource growth surfaces: node-state inventories registered
-        # before the sampler's hold so the first tick reads them all
-        from ..telemetry import resources as _resources
+            install_loop_excepthook(asyncio.get_running_loop())
+            self.loop_monitor.start()
+            self.history.start()
+            self.autotuner.start()
+            # host profiling: tag THIS thread as the event-loop thread so
+            # samples classify as loop vs feeder vs worker, then take a
+            # refcounted hold on the process sampler
+            self.profiler.register_loop_thread()
+            self._profiler_started = self.profiler.start()
+            # worker processes up before any job runs, so the first shard's
+            # pool batches never pay spawn latency inside a measured pass
+            self._procpool_started = self.procpool.start()
+            # resource growth surfaces: node-state inventories registered
+            # before the sampler's hold so the first tick reads them all
+            from ..telemetry import resources as _resources
 
-        for name, fn in _resources.node_providers(self).items():
-            self.resources.register_provider(name, fn)
-        self._resources_started = self.resources.start()
-        # bind the thumbnailer to THIS loop up front: enqueues arrive
-        # from worker threads (non-indexed walker) and can only wake the
-        # actor thread-safely once it knows its owning loop
-        self.thumbnailer._ensure_started()
-        for lib in self.libraries.load_all():
-            await self._init_library(lib)
-        if self.config.config.p2p.enabled:
-            from ..p2p.manager import P2PManager
+            for name, fn in _resources.node_providers(self).items():
+                self.resources.register_provider(name, fn)
+            self._resources_started = self.resources.start()
+            # bind the thumbnailer to THIS loop up front: enqueues arrive
+            # from worker threads (non-indexed walker) and can only wake the
+            # actor thread-safely once it knows its owning loop
+            self.thumbnailer._ensure_started()
+            for lib in self.libraries.load_all():
+                await self._init_library(lib)
+            if self.config.config.p2p.enabled:
+                from ..p2p.manager import P2PManager
 
-            self.p2p = P2PManager(self)
-            await self.p2p.start()
+                self.p2p = P2PManager(self)
+                await self.p2p.start()
 
     async def _init_library(self, lib: Library) -> None:
         """Per-library wiring done at load (ref:library/manager/mod.rs:387-535):
@@ -294,48 +301,49 @@ class Node:
         (persisting queues), actors, p2p, then close libraries."""
         from ..jobs.manager import shutdown_jobs
 
-        if self.http is not None:
-            await self.http.shutdown()
-            self.http = None
+        async with span("node.shutdown"):
+            if self.http is not None:
+                await self.http.shutdown()
+                self.http = None
 
-        for lib in list(self.libraries.libraries.values()):
-            await shutdown_jobs(self.jobs, lib)
-            remover = getattr(lib, "orphan_remover", None)
-            if remover is not None:
-                await remover.stop()
-            cloud = getattr(lib, "cloud_sync", None)
-            if cloud is not None:
-                await cloud.shutdown()
-                await cloud.client.close()
-        await self.loop_monitor.stop()
-        await self.history.stop()
-        await self.autotuner.stop()
-        if self._profiler_started:
-            self.profiler.stop()
-            self._profiler_started = False
-        if self._procpool_started:
-            self.procpool.stop()
-            self._procpool_started = False
-        if self._resources_started:
-            self.resources.stop()
-            self._resources_started = False
-        if not self.resources.running():
-            # last hold released (or sampling disabled): drop the
-            # node-state closures so a dead node can't be read. While a
-            # sibling in-process node still holds the sampler, its own
-            # registrations (last-wins) stay live instead.
-            from ..telemetry import resources as _resources
+            for lib in list(self.libraries.libraries.values()):
+                await shutdown_jobs(self.jobs, lib)
+                remover = getattr(lib, "orphan_remover", None)
+                if remover is not None:
+                    await remover.stop()
+                cloud = getattr(lib, "cloud_sync", None)
+                if cloud is not None:
+                    await cloud.shutdown()
+                    await cloud.client.close()
+            await self.loop_monitor.stop()
+            await self.history.stop()
+            await self.autotuner.stop()
+            if self._profiler_started:
+                self.profiler.stop()
+                self._profiler_started = False
+            if self._procpool_started:
+                self.procpool.stop()
+                self._procpool_started = False
+            if self._resources_started:
+                self.resources.stop()
+                self._resources_started = False
+            if not self.resources.running():
+                # last hold released (or sampling disabled): drop the
+                # node-state closures so a dead node can't be read. While a
+                # sibling in-process node still holds the sampler, its own
+                # registrations (last-wins) stay live instead.
+                from ..telemetry import resources as _resources
 
-            for name in _resources.node_providers(self):
-                self.resources.unregister_provider(name)
-        await self.thumbnailer.shutdown()
-        if self.image_labeler is not None:
-            await self.image_labeler.shutdown()
-        await self.location_manager.shutdown()
-        await self.actors.shutdown()
-        if self.p2p is not None:
-            await self.p2p.shutdown()
-        await self.task_system.shutdown()
-        for lib in list(self.libraries.libraries.values()):
-            lib.close()
-        self._started = False
+                for name in _resources.node_providers(self):
+                    self.resources.unregister_provider(name)
+            await self.thumbnailer.shutdown()
+            if self.image_labeler is not None:
+                await self.image_labeler.shutdown()
+            await self.location_manager.shutdown()
+            await self.actors.shutdown()
+            if self.p2p is not None:
+                await self.p2p.shutdown()
+            await self.task_system.shutdown()
+            for lib in list(self.libraries.libraries.values()):
+                lib.close()
+            self._started = False

@@ -14,6 +14,7 @@ import os
 from typing import Any
 
 from ..db.database import LibraryDb, blob_u64
+from ..telemetry import span
 from .volumes import get_volumes
 
 
@@ -32,6 +33,15 @@ def _dir_size(path: str | None) -> int:
 
 def update_statistics(
     db: LibraryDb, thumbnails_dir: str | None = None
+) -> dict[str, Any]:
+    # an index pass ends with this (`cli.index_location`): a scan of
+    # every file_path row, the volumes, the thumbnail directory's size
+    with span("statistics.update"):
+        return _update_statistics(db, thumbnails_dir)
+
+
+def _update_statistics(
+    db: LibraryDb, thumbnails_dir: str | None
 ) -> dict[str, Any]:
     total_objects = db.count("object")
     # one table scan for both totals; unique bytes = one size per distinct

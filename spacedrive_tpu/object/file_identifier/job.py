@@ -33,7 +33,6 @@ from ...ops import cas
 from ...parallel import autotune as _autotune
 from ...telemetry import metrics as _tm
 from ...telemetry import span
-from ...telemetry import profiler as _profiler
 
 logger = logging.getLogger(__name__)
 
@@ -67,9 +66,12 @@ class FileIdentifierJob(StatefulJob):
     INVALIDATES = ("search.paths", "search.objects")
     IS_BATCHED = True
     _pipeline = None  # runtime-only window pipeline (never serialized)
-    _profiling = False  # holds one jax-profiler refcount while running
 
     async def init_job(self, ctx: JobContext) -> None:
+        async with span("identify.init"):
+            self._init(ctx)
+
+    def _init(self, ctx: JobContext) -> None:
         library = ctx.library
         loc_id = self.init["location_id"]
         location = library.db.find_one("location", id=loc_id)
@@ -136,10 +138,11 @@ class FileIdentifierJob(StatefulJob):
             params.append(escape_like(materialized_prefix(self.init['sub_path'])) + "%")
         limit = self._window_limit()
         # cursor pagination by id (ref:file_identifier_job.rs:126-165)
-        rows = library.db.query(
-            f"SELECT * FROM file_path WHERE {where} AND id > ? ORDER BY id LIMIT ?",
-            tuple(params) + (cursor, limit),
-        )
+        with span("identify.page"):
+            rows = library.db.query(
+                f"SELECT * FROM file_path WHERE {where} AND id > ? ORDER BY id LIMIT ?",
+                tuple(params) + (cursor, limit),
+            )
         loc_path = d["location_path"]
         loc_id = d["location_id"]
         journal = _journal.IndexJournal(library.db)
@@ -152,71 +155,79 @@ class FileIdentifierJob(StatefulJob):
         # re-record (mtime-only touch) keep its thumb/media/phash vouches
         to_record: dict[int, tuple] = {}
         jstats = {"hit": 0, "dirty": 0, "dirty_chunks": 0}
-        for row in rows:
-            full = _row_full_path(loc_path, row)
-            size = blob_u64(row["size_in_bytes_bytes"]) or 0
-            key = _journal.key_of(row)
-            if size == 0:
-                metas.append({"row": row, "cas_id": None})
-                # journal the empty file (cas sentinel "") so warm-pass
-                # walks get a `hit` instead of an eternal miss
+        # the row loop is one span per window; the sampled reads inside it
+        # are timed per file into a local and observed once per window
+        read_s = 0.0
+        with span("identify.rows"):
+            for row in rows:
+                full = _row_full_path(loc_path, row)
+                size = blob_u64(row["size_in_bytes_bytes"]) or 0
+                key = _journal.key_of(row)
+                if size == 0:
+                    metas.append({"row": row, "cas_id": None})
+                    # journal the empty file (cas sentinel "") so warm-pass
+                    # walks get a `hit` instead of an eternal miss
+                    ident = _journal.stat_identity(full)
+                    if ident is not None:
+                        to_record[row["id"]] = (key, ident, "", None, None)
+                    continue
                 ident = _journal.stat_identity(full)
+                entry = None
                 if ident is not None:
-                    to_record[row["id"]] = (key, ident, "", None, None)
-                continue
-            ident = _journal.stat_identity(full)
-            entry = None
-            if ident is not None:
-                # the walker already counted this file's verdict this
-                # pass — don't double-count the invalidation here
-                verdict, entry = journal.lookup(
-                    loc_id, key, ident, count_invalidated=False
-                )
-                if verdict == _journal.HIT and entry.cas_id:
-                    # vouched: skip the read, the hash, and the transfer
-                    resolved[row["id"]] = entry.cas_id
-                    journal.bytes_saved(cas.message_len(size),
-                                        location_id=loc_id)
-                    jstats["hit"] += 1
-                    metas.append({"row": row, "cas_id": "journal"})
-                    continue
-            try:
-                msg = cas.read_message(full, size)
-            except OSError as e:
-                metas.append(None)
-                logger.debug("identifier: unreadable %s: %s", full, e)
-                continue
-            if (
-                ident is not None
-                and entry is not None
-                and entry.chunks is not None
-                and entry.chunks.msg_len == len(msg)
-                and len(msg) > cas.CHUNK_LEN
-            ):
-                try:
-                    cas_id, cache, n_dirty, hashed = cas.dirty_range_rehash(
-                        msg, entry.chunks
+                    # the walker already counted this file's verdict this
+                    # pass — don't double-count the invalidation here
+                    verdict, entry = journal.lookup(
+                        loc_id, key, ident, count_invalidated=False
                     )
-                except ValueError:
-                    cache = None
-                else:
-                    resolved[row["id"]] = cas_id
-                    to_record[row["id"]] = (key, ident, cas_id, cache, entry)
-                    journal.bytes_saved(len(msg) - hashed,
-                                        location_id=loc_id)
-                    _tm.INDEX_BYTES_HASHED.inc(hashed)
-                    jstats["dirty"] += 1
-                    jstats["dirty_chunks"] += n_dirty
-                    metas.append({"row": row, "cas_id": "journal"})
+                    if verdict == _journal.HIT and entry.cas_id:
+                        # vouched: skip the read, the hash, and the transfer
+                        resolved[row["id"]] = entry.cas_id
+                        journal.bytes_saved(cas.message_len(size),
+                                            location_id=loc_id)
+                        jstats["hit"] += 1
+                        metas.append({"row": row, "cas_id": "journal"})
+                        continue
+                t_read = time.perf_counter()
+                try:
+                    msg = cas.read_message(full, size)
+                except OSError as e:
+                    metas.append(None)
+                    logger.debug("identifier: unreadable %s: %s", full, e)
                     continue
-            messages.append(msg)
-            msg_rows.append(row)
-            metas.append({"row": row, "cas_id": "pending"})
-            if ident is not None:
-                # cas filled in post-hash; digest-only chunk cache so the
-                # FIRST in-place modification can already diff chunks
-                to_record[row["id"]] = (key, ident, None,
-                                        cas.build_chunk_cache(msg), entry)
+                finally:
+                    read_s += time.perf_counter() - t_read
+                if (
+                    ident is not None
+                    and entry is not None
+                    and entry.chunks is not None
+                    and entry.chunks.msg_len == len(msg)
+                    and len(msg) > cas.CHUNK_LEN
+                ):
+                    try:
+                        cas_id, cache, n_dirty, hashed = cas.dirty_range_rehash(
+                            msg, entry.chunks
+                        )
+                    except ValueError:
+                        cache = None
+                    else:
+                        resolved[row["id"]] = cas_id
+                        to_record[row["id"]] = (key, ident, cas_id, cache, entry)
+                        journal.bytes_saved(len(msg) - hashed,
+                                            location_id=loc_id)
+                        _tm.INDEX_BYTES_HASHED.inc(hashed)
+                        jstats["dirty"] += 1
+                        jstats["dirty_chunks"] += n_dirty
+                        metas.append({"row": row, "cas_id": "journal"})
+                        continue
+                messages.append(msg)
+                msg_rows.append(row)
+                metas.append({"row": row, "cas_id": "pending"})
+                if ident is not None:
+                    # cas filled in post-hash; digest-only chunk cache so the
+                    # FIRST in-place modification can already diff chunks
+                    to_record[row["id"]] = (key, ident, None,
+                                            cas.build_chunk_cache(msg), entry)
+        _tm.IDENTIFIER_STAGE_SECONDS.observe(read_s, stage="read")
         backend = d["backend"]
         use_device = backend in ("tpu", "device") or (
             backend == "auto" and cas._device_available()
@@ -278,13 +289,6 @@ class FileIdentifierJob(StatefulJob):
 
         library = ctx.library
         d = self.data
-        if not self._profiling:
-            # optional device profile around the pipeline driver
-            # (SD_JAX_PROFILE=<logdir>; no-op on CPU-only CI). Armed
-            # lazily like the pipeline below, so a pause (whose cleanup
-            # released the profiler hold) re-arms on resume instead of
-            # truncating the capture at the first preemption.
-            self._profiling = _profiler.profile_start("identify")
         if self._pipeline is None:
             # The producer chains cursor windows back-to-back: window
             # N+1's disk reads and device dispatch start as soon as N's
@@ -476,9 +480,6 @@ class FileIdentifierJob(StatefulJob):
     def cleanup(self) -> None:
         """Every exit path (done/pause/cancel/fail) stops the window
         pipeline and keeps its stats."""
-        if self._profiling:
-            self._profiling = False
-            _profiler.profile_stop()
         if self._pipeline is not None:
             stats = self._pipeline.stats
             self.run_metadata["prefetch_hits"] = stats.prefetch_hits

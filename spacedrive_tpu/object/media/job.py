@@ -22,6 +22,7 @@ from ...jobs import StatefulJob
 from ...jobs.job import JobContext, JobError, StepResult
 from ...jobs.manager import register_job
 from ...location.indexer import journal as _journal
+from ...telemetry import span
 from .media_data import ImageMetadata
 
 logger = logging.getLogger(__name__)
@@ -62,6 +63,10 @@ class MediaProcessorJob(StatefulJob):
     IS_BATCHED = True
 
     async def init_job(self, ctx: JobContext) -> None:
+        async with span("media.init"):
+            await self._init(ctx)
+
+    async def _init(self, ctx: JobContext) -> None:
         library = ctx.library
         loc_id = self.init["location_id"]
         location = library.db.find_one("location", id=loc_id)
@@ -238,7 +243,8 @@ class MediaProcessorJob(StatefulJob):
     async def execute_step(self, ctx: JobContext, step: dict, step_number: int) -> StepResult:
         kind = step["kind"]
         if kind == "extract_media_data":
-            return self._extract_media_data(ctx, step)
+            with span("media.extract"):
+                return self._extract_media_data(ctx, step)
         if kind == "embed":
             import asyncio
 
@@ -303,15 +309,10 @@ class MediaProcessorJob(StatefulJob):
         ONE transaction via sync.write_ops, so the vectors replicate
         live like any other shared model. Journal vouches are written
         strictly AFTER that commit."""
-        import time
-
         import numpy as np
 
-        from ...db.database import now_iso
-        from ...models import embedder as _embedder
         from ...ops import embed_jax
         from ...telemetry import metrics as _tm
-        from ..search import index as _search_index
 
         library = ctx.library
         loc_path = self.data["location_path"]
@@ -331,10 +332,9 @@ class MediaProcessorJob(StatefulJob):
                 _tm.EMBED_FILES.inc(errors, result="error")
             return StepResult()
 
-        t0 = time.perf_counter()
-        planes = self._decode_for_embed([p for _, _, p in items])
-        _tm.EMBED_STAGE_SECONDS.observe(
-            time.perf_counter() - t0, stage="decode")
+        with span("embed.decode") as stage:
+            planes = self._decode_for_embed([p for _, _, p in items])
+        _tm.EMBED_STAGE_SECONDS.observe(stage.duration, stage="decode")
 
         batch_rows: list[tuple[dict, int]] = []
         batch_imgs: list[np.ndarray] = []
@@ -349,12 +349,29 @@ class MediaProcessorJob(StatefulJob):
         if not batch_imgs:
             return StepResult()
 
-        t0 = time.perf_counter()
-        vectors = embed_jax.embed_batch(np.stack(batch_imgs))
-        _tm.EMBED_STAGE_SECONDS.observe(
-            time.perf_counter() - t0, stage="forward")
+        with span("embed.forward") as stage:
+            vectors = embed_jax.embed_batch(np.stack(batch_imgs))
+        _tm.EMBED_STAGE_SECONDS.observe(stage.duration, stage="forward")
 
-        t0 = time.perf_counter()
+        with span("embed.write") as stage:
+            written = self._write_embeddings(
+                library, journal, loc_id, batch_rows, vectors)
+        _tm.EMBED_STAGE_SECONDS.observe(stage.duration, stage="write")
+        return StepResult(
+            metadata={
+                "embeddings_written":
+                    self.run_metadata.get("embeddings_written", 0) + written,
+            }
+        )
+
+    def _write_embeddings(self, library, journal, loc_id: int,
+                          batch_rows: list, vectors) -> int:
+        """The write stage of one embedding chunk; → rows written."""
+        from ...db.database import now_iso
+        from ...models import embedder as _embedder
+        from ...telemetry import metrics as _tm
+        from ..search import index as _search_index
+
         sync = library.sync
         stamp = now_iso()
         ops = []
@@ -399,15 +416,7 @@ class MediaProcessorJob(StatefulJob):
                 )
             _tm.EMBED_FILES.inc(len(writes), result="embedded")
             _search_index.refresh(library)
-        _tm.EMBED_STAGE_SECONDS.observe(
-            time.perf_counter() - t0, stage="write")
-        return StepResult(
-            metadata={
-                "embeddings_written":
-                    self.run_metadata.get("embeddings_written", 0)
-                    + len(writes),
-            }
-        )
+        return len(writes)
 
     def _decode_for_embed(self, paths: list[str]) -> list:
         """The embedding decode leg: pooled when the multi-process

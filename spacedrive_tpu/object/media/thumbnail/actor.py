@@ -23,6 +23,7 @@ import itertools
 import logging
 import os
 import secrets
+import time
 from typing import Any, Sequence
 
 from ....parallel import autotune as _autotune
@@ -473,12 +474,24 @@ class Thumbnailer:
         sem = asyncio.Semaphore(parallelism)
         chunk_rows = self._device_chunk()
 
-        async def _decode(entry: tuple[str, str, str]) -> Decoded | None:
+        def _timed(work: list[float], fn, *args):
+            """Run one image's `fn` on this worker thread and add the
+            seconds spent INSIDE it to the chunk's `work` sum (appends
+            are atomic): work done, where the chunk's span is wall time
+            with semaphore queueing and pipeline overlap in it."""
+            t0 = time.perf_counter()
+            try:
+                return fn(*args)
+            finally:
+                work.append(time.perf_counter() - t0)
+
+        async def _decode(entry: tuple[str, str, str],
+                          work: list[float]) -> Decoded | None:
             cas_id, path, ext = entry
             async with sem:
                 try:
                     return await asyncio.wait_for(
-                        asyncio.to_thread(decode, path, ext),
+                        asyncio.to_thread(_timed, work, decode, path, ext),
                         timeout=GENERATION_TIMEOUT_S,
                     )
                 except (ThumbError, asyncio.TimeoutError, OSError) as e:
@@ -486,10 +499,13 @@ class Thumbnailer:
                     return None
 
         async def _decode_chunk(chunk):
+            work: list[float] = []
             async with span("thumbnail.decode") as decode_span:
-                decoded = await asyncio.gather(*(_decode(e) for e in chunk))
+                decoded = await asyncio.gather(
+                    *(_decode(e, work) for e in chunk))
             _tm.THUMB_STAGE_SECONDS.observe(
                 decode_span.duration, stage="decode")
+            _tm.THUMB_WORK_SECONDS.observe(sum(work), stage="decode")
             _tm.PIPELINE_HOST_SECONDS.observe(
                 decode_span.duration, pipeline="thumbnail")
             return decoded
@@ -537,9 +553,11 @@ class Thumbnailer:
                         self.errors += 1
                         _tm.THUMB_FILES.inc(result="error")
 
+            work: list[float] = []
+
             async def _one_finish(d, r):
                 async with sem:
-                    return await asyncio.to_thread(finish, d, r)
+                    return await asyncio.to_thread(_timed, work, finish, d, r)
 
             async with span("thumbnail.encode") as encode_span:
                 await asyncio.gather(*(_one_fallback(i) for i in fallback))
@@ -564,6 +582,7 @@ class Thumbnailer:
                             len(device_idx), result="error")
             _tm.THUMB_STAGE_SECONDS.observe(
                 encode_span.duration, stage="encode")
+            _tm.THUMB_WORK_SECONDS.observe(sum(work), stage="encode")
             _tm.PIPELINE_HOST_SECONDS.observe(
                 encode_span.duration, pipeline="thumbnail")
             if _faults.hit("thumbnail.persist") is not None:

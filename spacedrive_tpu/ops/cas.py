@@ -380,6 +380,8 @@ def cas_ids_begin(
     Explicit `devices` stay strict and re-raise."""
     from . import blake3_jax
     from ..parallel import mesh as _mesh
+    from ..telemetry import metrics as _tm
+    from ..telemetry import span as _span
 
     if devices is not None:
         devs = list(devices)
@@ -391,23 +393,18 @@ def cas_ids_begin(
             # recursion cap: go straight to the host path WITHOUT
             # consulting the ladder — ladder_devices() could hand this
             # doomed call the half-open probe and strand it
-            from ..telemetry import metrics as _tm
-
             _tm.CAS_BACKEND_FALLBACK.inc()
             return lambda: cas_ids(messages, "cpu")
         devs, level = _mesh.ladder_devices()
         if level == _mesh.LEVEL_HOST:
             # demoted to (or stuck on) the host reference path — count
             # the degradation so a node quietly hashing on CPU shows up
-            from ..telemetry import metrics as _tm
-
             _tm.CAS_BACKEND_FALLBACK.inc()
             return lambda: cas_ids(messages, "cpu")
     n_dev = len(devs)
 
     def _retry_demoted(exc: Exception) -> Callable[[], list[str]]:
         from ..telemetry import events as _events
-        from ..telemetry import metrics as _tm
 
         _mesh.LADDER.record_failure(level, devs)
         _tm.CAS_BACKEND_FALLBACK.inc()
@@ -416,12 +413,19 @@ def cas_ids_begin(
         # oscillation when a test-sized reset_timeout is in effect)
         return cas_ids_begin(messages, _depth=_depth + 1)
 
+    # host seconds of this call by stage, observed once at its end: what
+    # it costs to bucket and pack a window and to hand it to the device
+    # (H2D and enqueue), apart from the wait for digests in `finish`
+    pack_s = dispatch_s = 0.0
+
     buckets: dict[int, _Bucket] = {}
-    for i, msg in enumerate(messages):
-        c = LARGE_CHUNKS if len(msg) == LARGE_MSG_LEN else _bucket_for(len(msg))
-        b = buckets.setdefault(c, _Bucket(c, [], []))
-        b.indices.append(i)
-        b.messages.append(msg)
+    with _span("cas.pack") as sp:
+        for i, msg in enumerate(messages):
+            c = LARGE_CHUNKS if len(msg) == LARGE_MSG_LEN else _bucket_for(len(msg))
+            b = buckets.setdefault(c, _Bucket(c, [], []))
+            b.indices.append(i)
+            b.messages.append(msg)
+    pack_s += sp.duration
 
     # dispatch quantum: the autotuner's current per-device rung × device
     # count (static top rung = device_batch, bit-identical to the
@@ -459,20 +463,23 @@ def cas_ids_begin(
                     else None
                 )
                 used_devices = used_devices or shard or single is not None
-                arr, lens = pack_canonical_batch(
-                    part, c, n_devices=n_dev if shard else 1
-                )
+                with _span("cas.pack") as sp:
+                    arr, lens = pack_canonical_batch(
+                        part, c, n_devices=n_dev if shard else 1
+                    )
+                pack_s += sp.duration
                 if shard:
-                    from ..telemetry import metrics as _tm
-
                     for frac in shard_occupancy(len(part), arr.shape[0], n_dev):
                         _tm.DEVICE_DISPATCH_OCCUPANCY.observe(frac, op="blake3")
-                in_flight.append(
-                    (bucket, off, blake3_jax.hash_batch(
+                with _span("cas.enqueue", nbytes=arr.nbytes) as sp:
+                    words = blake3_jax.hash_batch(
                         arr, lens, max_chunks=c,
                         devices=devs if shard else single,
-                    ))
-                )
+                    )
+                dispatch_s += sp.duration
+                in_flight.append((bucket, off, words))
+        _tm.IDENTIFIER_STAGE_SECONDS.observe(pack_s, stage="pack")
+        _tm.IDENTIFIER_STAGE_SECONDS.observe(dispatch_s, stage="dispatch")
     except Exception as exc:  # noqa: BLE001 - dispatch failure → demote
         if explicit:
             raise

@@ -116,13 +116,11 @@ class WindowPipeline(Generic[T]):
                         raise _faults.InjectedFault(
                             "injected feeder producer crash"
                         )
-                t0 = time.perf_counter()
-                with _span("feeder.fetch"):
+                with _span("feeder.fetch") as fetch_span:
                     item = self._fetch(key)
-                fetch_s = time.perf_counter() - t0
                 with self.stats._lock:
-                    self.stats.read_time += fetch_s
-                _tm.FEEDER_FETCH_SECONDS.observe(fetch_s)
+                    self.stats.read_time += fetch_span.duration
+                _tm.FEEDER_FETCH_SECONDS.observe(fetch_span.duration)
                 if item is None:
                     self._put(None)
                     return

@@ -102,6 +102,18 @@ _SEGMENT_BUCKETS = {
     "request": HOST_CPU,   # sync.request assembly
     "journal": HOST_CPU,
     "store": HOST_CPU,
+    "txn": HOST_CPU,       # db.txn under any stage: SQLite's commit
+    "migrate": HOST_CPU,   # db.migrate
+    "save": HOST_CPU,      # indexer.save
+    "page": HOST_CPU,      # identify.page / identify.rows / cas.pack run
+    "rows": HOST_CPU,      # under feeder.fetch, whose head files them
+    "pack": HOST_CPU,      # under LINK; these are for callers off the feeder
+    "extract": HOST_CPU,   # media.extract
+    "write": HOST_CPU,     # embed.write
+    "init": HOST_CPU,      # identify.init / media.init: a job before its steps
+    "finalize": HOST_CPU,  # job.finalize: the indexer's size roll-up
+    "forward": DEVICE,     # embed.forward
+    "enqueue": LINK,       # cas.enqueue: H2D and dispatch of one batch
     # queueing
     "dispatch": QUEUE_WAIT,  # the synthetic task.dispatch queue-wait span
     "queue": QUEUE_WAIT,

@@ -81,8 +81,12 @@ IDENTIFIER_BATCH_FILL = REGISTRY.histogram(
 )
 IDENTIFIER_STAGE_SECONDS = REGISTRY.histogram(
     "sd_identifier_stage_seconds",
-    "per-window time split between device hash and DB linking",
-    labels=("stage",),  # hash | db
+    "per-window time split: consumer side, the wait for digests (hash) "
+    "and DB linking (db); producer side, inside feeder.fetch, the "
+    "sampled reads of the row loop (read), bucketing and packing the "
+    "batch (pack) and handing it to the device (dispatch). Nothing sums "
+    "the labels: the two sides overlap in time",
+    labels=("stage",),  # hash | db | read | pack | dispatch
 )
 
 # --- thumbnailer (object/media/thumbnail/actor.py) --------------------------
@@ -103,6 +107,14 @@ THUMB_STAGE_SECONDS = REGISTRY.histogram(
     "per-chunk time split across the pipelined stages: host decode, "
     "device resize, host webp encode+store",
     labels=("stage",),  # decode | device | encode
+)
+
+THUMB_WORK_SECONDS = REGISTRY.histogram(
+    "sd_thumbnail_work_seconds",
+    "per-chunk sum of the seconds spent INSIDE decode() and finish() on "
+    "the worker threads: work done, where sd_thumbnail_stage_seconds is "
+    "the chunk's wall with semaphore queueing and pipeline overlap in it",
+    labels=("stage",),  # decode | encode
 )
 
 # --- semantic search (models/embedder.py, object/search/index.py) -----------
@@ -615,6 +627,15 @@ RESOURCE_INVENTORY = REGISTRY.gauge(
 EVENT_LOOP_LAG = REGISTRY.gauge(
     "sd_event_loop_lag_seconds",
     "latest sampled event-loop scheduling lag",
+)
+
+# --- library database (db/database.py) --------------------------------------
+
+DB_TXN_SECONDS = REGISTRY.histogram(
+    "sd_db_txn_seconds",
+    "one observation per committed write: a transaction() block (every "
+    "sync.write_ops), a writing execute(), an executemany(); reads "
+    "never count",
 )
 
 # --- spans (telemetry/spans.py) ---------------------------------------------

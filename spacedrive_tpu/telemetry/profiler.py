@@ -1,13 +1,19 @@
-"""Optional ``jax.profiler`` hooks around the pipeline driver.
+"""Optional ``jax.profiler`` session around a whole job chain.
 
-When ``SD_JAX_PROFILE=<logdir>`` is set, the identify pipeline wraps
-its run in ``jax.profiler.start_trace``/``stop_trace`` so device-side
-traces (XLA ops, transfers) land next to the host-side Chrome trace
-this subsystem exports. Everything here is no-op-safe: unset env, a
-missing/CPU-only jax, or a profiler that refuses to start all degrade
-to "no profile", never to a failed job. Start/stop is refcounted so
-overlapping drivers (indexer chain + a watcher rescan) share one
-profiler session instead of crashing on double-start.
+When ``SD_JAX_PROFILE=<logdir>`` is set, the job manager takes a hold
+on one profiler session when it dispatches a job and releases it when
+the job has settled and its successors are dispatched, so an index pass
+(indexer → file identifier → media processor) is ONE ``.xplane.pb``:
+the device's ops and transfers on the device planes and every
+``telemetry.span`` of the pass as ``sd.<dotted path>`` on the host
+lines of the same file, on the same clock (``spans.py`` opens a
+``TraceAnnotation`` per span). The Python function tracer is off: the
+spans name the host side, and a whole pass of function calls would be
+gigabytes. Everything here is no-op-safe: unset env, a missing/CPU-only
+jax, or a profiler that refuses to start all degrade to "no profile",
+never to a failed job. Start/stop is refcounted so overlapping drivers
+(a chain + a watcher rescan) share one session instead of crashing on
+double-start.
 """
 
 from __future__ import annotations
@@ -39,7 +45,14 @@ def profile_start(tag: str = "pipeline") -> bool:
         try:
             import jax
 
-            jax.profiler.start_trace(os.path.join(logdir, tag))
+            make_options = getattr(jax.profiler, "ProfileOptions", None)
+            if make_options is None:
+                jax.profiler.start_trace(os.path.join(logdir, tag))
+            else:
+                options = make_options()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(os.path.join(logdir, tag),
+                                         profiler_options=options)
         except Exception as e:  # noqa: BLE001 - profiling is best-effort
             logger.debug("jax profiler start failed: %s", e)
             return False

@@ -17,6 +17,16 @@ Stages are dotted paths: a span opened inside another records as
 ``parent.child`` (e.g. ``identify.hash``), keeping label cardinality
 proportional to the pipeline's actual shape.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` named
+``sd.<dotted path>``: whenever a profiler session is running (the
+benchmark's ``--trace 1``, an operator's ``SD_JAX_PROFILE``) the span
+lands on a host line of the same ``.xplane.pb`` as the device's ops, on
+the profiler's clock, so an idle gap of the chip can be laid against the
+span that was open. With no session the annotation is one small object
+and a flag read. It is bound on first use and only in a process that has
+imported ``jax`` already: this module never imports it
+(``parallel/procworker.py`` stays import-light).
+
 Every span also carries distributed-trace identity (``trace_id``/
 ``span_id``/``parent_id``, see ``telemetry.trace``): a nested span
 inherits its parent's trace; a root span adopts the ambient
@@ -30,6 +40,7 @@ from __future__ import annotations
 
 import contextvars
 import logging
+import sys
 import threading
 import time
 from collections import deque
@@ -49,6 +60,21 @@ _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
 _recent: deque[dict[str, Any]] = deque(maxlen=RECENT_SPANS)
 _recent_lock = threading.Lock()
 
+#: prefix of every span's name on the profiler's host lines
+ANNOTATION_PREFIX = "sd."
+_annotation_cls: Any = None
+
+
+def _bind_annotation() -> Any:
+    """`jax.profiler.TraceAnnotation`, once `jax` is in `sys.modules`
+    (and past its own import); None until then."""
+    global _annotation_cls
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    cls = getattr(profiler, "TraceAnnotation", None)
+    if cls is not None:
+        _annotation_cls = cls
+    return cls
+
 
 class Span:
     """One timed pipeline stage. Use via ``span(...)``:
@@ -62,7 +88,7 @@ class Span:
     __slots__ = (
         "stage", "nbytes", "path", "_t0", "_t0_wall", "_token",
         "_trace_token", "duration", "trace_id", "span_id", "parent_id",
-        "fields",
+        "fields", "_annotation", "_outer", "_ctx",
     )
 
     def __init__(self, stage: str, nbytes: int = 0):
@@ -78,6 +104,9 @@ class Span:
         self.trace_id: str = ""
         self.span_id: str = ""
         self.parent_id: str | None = None
+        self._annotation: Any = None
+        self._outer: "Span | None" = None  # the live span this one opened under
+        self._ctx: _trace.TraceContext | None = None
 
     def add_bytes(self, n: int) -> None:
         """Attribute more bytes mid-span (e.g. per-file in a loop)."""
@@ -95,36 +124,59 @@ class Span:
 
     def __enter__(self) -> "Span":
         parent = _current.get()
+        while parent is not None and parent.duration is not None:
+            # a task started inside a span keeps a copy of its context
+            # after the span has ended (the actors `Node.start` spawns);
+            # a span whose time is over is nobody's parent, and hands
+            # back to the span it opened under
+            parent = parent._outer
+        self._outer = parent
+        self.duration = None
+        ambient = _trace.current()
         if parent is not None:
             self.path = f"{parent.path}.{self.stage}"
             self.trace_id = parent.trace_id
             self.parent_id = parent.span_id
-        else:
+        elif ambient is not None:
             # no enclosing span: join the ambient trace context a
-            # boundary installed (dispatch, resume, wire) or start fresh
-            ctx = _trace.current()
-            if ctx is not None:
-                self.trace_id = ctx.trace_id
-                self.parent_id = ctx.span_id
-            else:
-                self.trace_id = _trace.new_trace_id()
+            # boundary installed (dispatch, resume, wire)
+            self.trace_id = ambient.trace_id
+            self.parent_id = ambient.span_id
+        else:
+            self.trace_id = _trace.new_trace_id()
         self.span_id = _trace.new_span_id()
         self._token = _current.set(self)
-        self._trace_token = _trace.set_current(
-            _trace.TraceContext(self.trace_id, self.span_id)
-        )
+        self._ctx = _trace.TraceContext(self.trace_id, self.span_id,
+                                        outer=ambient)
+        self._trace_token = _trace.set_current(self._ctx)
+        cls = _annotation_cls or _bind_annotation()
+        if cls is not None:
+            try:
+                self._annotation = cls(ANNOTATION_PREFIX + self.path)
+                self._annotation.__enter__()
+            except Exception:  # noqa: BLE001 - a profiler fault never fails work
+                self._annotation = None
         self._t0_wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.duration = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            try:
+                self._annotation.__exit__(None, None, None)
+            except Exception:  # noqa: BLE001
+                pass
+            self._annotation = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
         if self._trace_token is not None:
             _trace.reset_current(self._trace_token)
             self._trace_token = None
+        if self._ctx is not None:
+            self._ctx.closed = True  # copies of this context read past it
+            self._ctx = None
         metrics.SPAN_SECONDS.observe(self.duration, stage=self.path)
         if self.nbytes:
             metrics.SPAN_BYTES.inc(self.nbytes, stage=self.path)

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import os
 import threading
 from collections import deque
@@ -38,13 +39,21 @@ TRACE_RING = 4096  # completed spans retained for export
 
 
 class TraceContext:
-    """An addressable point in a trace: (trace_id, span_id)."""
+    """An addressable point in a trace: (trace_id, span_id).
 
-    __slots__ = ("trace_id", "span_id")
+    A span's own context also knows what was ambient when the span
+    opened (`outer`) and whether the span has ended (`closed`): a task
+    started inside a span keeps a copy of its context for life, and
+    `current()` reads past a point whose span is over."""
 
-    def __init__(self, trace_id: str, span_id: str):
+    __slots__ = ("trace_id", "span_id", "outer", "closed")
+
+    def __init__(self, trace_id: str, span_id: str,
+                 outer: "TraceContext | None" = None):
         self.trace_id = trace_id
         self.span_id = span_id
+        self.outer = outer
+        self.closed = False
 
     def to_wire(self) -> dict[str, str]:
         return {"trace_id": self.trace_id, "span_id": self.span_id}
@@ -64,12 +73,49 @@ class TraceContext:
         return f"<TraceContext {self.trace_id[:8]}…/{self.span_id}>"
 
 
+# Ids are a per-process random draw plus a counter: one `os.urandom`
+# per process (and per fork), never one per span. A system call on
+# every `Span.__enter__` was harmless at eight spans per window and is
+# not once spans sit where the work is. The counter is pushed through
+# an odd multiplier mod 2**64 (a bijection), so ids stay unique within
+# a process and look as scattered as the random ones did; two processes
+# collide only if their 64-bit starting points fall within each other's
+# span counts.
+_MASK64 = (1 << 64) - 1
+_SCATTER = 0x9E3779B97F4A7C15  # odd: n -> n * _SCATTER is one-to-one mod 2**64
+_id_prefix = 0  # the trailing half of every trace id of this process
+_id_base = 0  # where this process's counter starts
+_id_counter = itertools.count()
+
+
+def _seed_ids() -> None:
+    global _id_prefix, _id_base, _id_counter
+    raw = os.urandom(16)
+    _id_prefix = int.from_bytes(raw[:8], "big")
+    _id_base = int.from_bytes(raw[8:], "big")
+    _id_counter = itertools.count()
+
+
+_seed_ids()
+if hasattr(os, "register_at_fork"):
+    # a forked worker must not replay its parent's sequence
+    os.register_at_fork(after_in_child=_seed_ids)
+
+
+def _next_id() -> int:
+    # next() on itertools.count is one C call: atomic under the GIL
+    return ((_id_base + next(_id_counter)) * _SCATTER) & _MASK64
+
+
 def new_trace_id() -> str:
-    return os.urandom(16).hex()  # 128-bit, W3C-trace-context sized
+    # 128-bit, W3C-trace-context sized. The scattered counter leads and
+    # the process's draw trails: readers that shorten an id to its head
+    # (`_tid_for`, the repr) still tell one trace from the next
+    return "%016x%016x" % (_next_id(), _id_prefix)
 
 
 def new_span_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % _next_id()
 
 
 def new_context() -> TraceContext:
@@ -83,12 +129,18 @@ _ambient: contextvars.ContextVar[TraceContext | None] = contextvars.ContextVar(
 
 
 def current() -> TraceContext | None:
-    """The context new spans (and outbound messages) should join."""
-    return _ambient.get()
+    """The context new spans (and outbound messages) should join: the
+    innermost one whose span has not ended (the actors `Node.start`
+    spawns join what was ambient before `node.start`, not one trace
+    for everything the node ever starts)."""
+    ctx = _ambient.get()
+    while ctx is not None and ctx.closed:
+        ctx = ctx.outer
+    return ctx
 
 
 def wire_current() -> dict[str, str] | None:
-    ctx = _ambient.get()
+    ctx = current()
     return ctx.to_wire() if ctx is not None else None
 
 

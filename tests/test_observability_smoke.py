@@ -30,6 +30,10 @@ async def test_metrics_trace_and_debug_bundle_end_to_end(tmp_path, corpus):
     from spacedrive_tpu.location.locations import LocationCreateArgs, scan_location
     from spacedrive_tpu.node import Node
 
+    # the span ring is the process's: the first `walk` and the first
+    # `identify.hash` below must be this pass's, not what an earlier
+    # suite on this worker left behind (or what the ring half evicted)
+    telemetry.reset()
     node = Node(os.path.join(tmp_path, "node"), use_device=False,
                 with_labeler=False)
     node.config.config.p2p.enabled = False
