@@ -137,22 +137,41 @@ def forward(p: dict[str, Any], images):
 
 
 def decode_image(path: str, image_size: int = IMAGE_SIZE) -> np.ndarray | None:
-    """Decode one image to the embedder's input plane — the same
-    dispatch as the labeler (HEIF rides libheif, not PIL). Module-level
-    so the procpool `embed.decode` stage and the inline fallback run
-    the EXACT same code path; None = undecodable."""
+    """Decode one image to the embedder's input plane, at the scale the
+    plane needs: a JPEG is DCT-scaled by the largest of 1/2, 1/4, 1/8
+    that leaves both sides at least 8 source pixels per output pixel
+    (under 512 px on a side: none), and whatever PIL opens goes straight
+    to RGB and through one bicubic resize. The stored pixels, no EXIF
+    orientation. HEIF, SVG and PDF do not come from PIL and ride
+    `format_image`. Module-level so the procpool `embed.decode` stage,
+    the inline leg and query-by-image run the EXACT same code path;
+    None = undecodable or over `MAXIMUM_FILE_SIZE`."""
     from PIL import Image
 
-    from ..object.media.images import format_image
+    from ..object.media import images
+    from ..telemetry import metrics as _tm
 
+    shrink = 1.0
     try:
-        rgba = format_image(path)
-        img = Image.fromarray(rgba).convert("RGB").resize(
-            (image_size, image_size)
-        )
-        return np.asarray(img, np.float32) / 255.0
+        ext = os.path.splitext(path)[1].lstrip(".").lower()
+        if ext in (images.HEIF_EXTENSIONS | images.SVG_EXTENSIONS
+                   | images.PDF_EXTENSIONS):
+            rgb = Image.fromarray(images.format_image(path)).convert("RGB")
+        else:
+            if os.path.getsize(path) > images.MAXIMUM_FILE_SIZE:
+                return None
+            with Image.open(path) as img:
+                if img.format == "JPEG":
+                    width = img.size[0]
+                    img.draft("RGB", (8 * image_size, 8 * image_size))
+                    shrink = width / img.size[0]
+                rgb = img.convert("RGB")
+        plane = np.asarray(rgb.resize((image_size, image_size)), np.float32)
     except Exception:  # noqa: BLE001 - undecodable → caller skips
         return None
+    _tm.EMBED_DECODE.inc(scale="8" if shrink > 6 else "4" if shrink > 3
+                         else "2" if shrink > 1.5 else "1")
+    return plane / 255.0
 
 
 def vector_to_blob(vec: np.ndarray) -> bytes:

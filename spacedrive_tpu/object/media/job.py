@@ -422,7 +422,10 @@ class MediaProcessorJob(StatefulJob):
         """The embedding decode leg: pooled when the multi-process
         plane is up (stage `embed.decode` — SD022 keeps the payload
         msgpack-plain), inline fallback otherwise; both run
-        models/embedder.decode_image so the planes are bit-identical."""
+        models/embedder.decode_image (a JPEG DCT-scaled to what the
+        32×32 plane needs, everything else straight to RGB) so the
+        planes are bit-identical. `sd_embed_decode_total` counts in the
+        process that decodes: a pooled decode counts in its worker."""
         import numpy as np
 
         from ...models import embedder as _embedder

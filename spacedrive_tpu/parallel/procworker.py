@@ -278,8 +278,9 @@ def _stage_phash_gray(payload: dict) -> dict:
 def _stage_embed_decode(payload: dict) -> dict:
     """The embedding stage's decode leg: image file → the embedder's
     fixed input plane (models/embedder.decode_image — the EXACT code
-    path the inline fallback runs, so pooled and single-process decodes
-    are bit-identical). Undecodable files return None slots; the owner
+    path the inline fallback runs, DCT scale chosen from the file's
+    format and size alone, so pooled and single-process decodes are
+    bit-identical). Undecodable files return None slots; the owner
     skips them without paying a second guaranteed-to-fail decode.
 
     payload: {"paths": [str, ...]}

@@ -132,6 +132,13 @@ EMBED_STAGE_SECONDS = REGISTRY.histogram(
     "decode, device forward, DB+sync write",
     labels=("stage",),  # decode | forward | write
 )
+EMBED_DECODE = REGISTRY.counter(
+    "sd_embed_decode_total",
+    "images decoded for the embedder, by the DCT scale the decoder "
+    "applied (1 = full size: not a JPEG, or under 512 px on a side), "
+    "counted in the process that decodes",
+    labels=("scale",),  # 1 | 2 | 4 | 8
+)
 SEARCH_QUERIES = REGISTRY.counter(
     "sd_search_queries_total",
     "semantic search queries by scoring path (device = jitted matmul "
