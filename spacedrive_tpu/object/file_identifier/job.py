@@ -157,7 +157,8 @@ class FileIdentifierJob(StatefulJob):
         jstats = {"hit": 0, "dirty": 0, "dirty_chunks": 0}
         # the row loop is one span per window; the sampled reads inside it
         # are timed per file into a local and observed once per window
-        read_s = 0.0
+        read_s = chunk_cache_s = 0.0
+        n_sampled = 0  # messages in the sampled layout (file over 100 KiB)
         with span("identify.rows"):
             for row in rows:
                 full = _row_full_path(loc_path, row)
@@ -221,13 +222,20 @@ class FileIdentifierJob(StatefulJob):
                         continue
                 messages.append(msg)
                 msg_rows.append(row)
+                n_sampled += size > cas.MINIMUM_FILE_SIZE
                 metas.append({"row": row, "cas_id": "pending"})
                 if ident is not None:
                     # cas filled in post-hash; digest-only chunk cache so the
                     # FIRST in-place modification can already diff chunks
-                    to_record[row["id"]] = (key, ident, None,
-                                            cas.build_chunk_cache(msg), entry)
+                    t_cache = time.perf_counter()
+                    cache = cas.build_chunk_cache(msg)
+                    chunk_cache_s += time.perf_counter() - t_cache
+                    to_record[row["id"]] = (key, ident, None, cache, entry)
         _tm.IDENTIFIER_STAGE_SECONDS.observe(read_s, stage="read")
+        _tm.IDENTIFIER_STAGE_SECONDS.observe(chunk_cache_s,
+                                             stage="chunk_cache")
+        _tm.IDENTIFIER_MESSAGES.inc(n_sampled, layout="sampled")
+        _tm.IDENTIFIER_MESSAGES.inc(len(messages) - n_sampled, layout="whole")
         backend = d["backend"]
         use_device = backend in ("tpu", "device") or (
             backend == "auto" and cas._device_available()
@@ -358,9 +366,10 @@ class FileIdentifierJob(StatefulJob):
                     cas_hex = by_row_id.get(row_id)
                 if cas_hex is not None:  # "" = vouched-empty sentinel
                     records.append((key, ident, cas_hex, cache, carry))
-            _journal.IndexJournal(library.db).record_many(
-                d["location_id"], records
-            )
+            with span("journal.record"):
+                _journal.IndexJournal(library.db).record_many(
+                    d["location_id"], records
+                )
         db_time = time.perf_counter() - t1
         _tm.IDENTIFIER_STAGE_SECONDS.observe(db_time, stage="db")
         _tm.IDENTIFIER_FILES.inc(len(rows))

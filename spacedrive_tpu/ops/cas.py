@@ -477,6 +477,11 @@ def cas_ids_begin(
                         devices=devs if shard else single,
                     )
                 dispatch_s += sp.duration
+                # which compiled shape the part took and what crossed the
+                # link for it: the padded array, not the messages alone
+                bucket_l, rung_l = str(c), str(arr.shape[0])
+                _tm.CAS_DISPATCH_ROWS.inc(len(part), chunks=bucket_l, rung=rung_l)
+                _tm.CAS_DISPATCH_BYTES.inc(arr.nbytes, chunks=bucket_l, rung=rung_l)
                 in_flight.append((bucket, off, words))
         _tm.IDENTIFIER_STAGE_SECONDS.observe(pack_s, stage="pack")
         _tm.IDENTIFIER_STAGE_SECONDS.observe(dispatch_s, stage="dispatch")

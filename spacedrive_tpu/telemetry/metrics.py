@@ -84,9 +84,31 @@ IDENTIFIER_STAGE_SECONDS = REGISTRY.histogram(
     "per-window time split: consumer side, the wait for digests (hash) "
     "and DB linking (db); producer side, inside feeder.fetch, the "
     "sampled reads of the row loop (read), bucketing and packing the "
-    "batch (pack) and handing it to the device (dispatch). Nothing sums "
-    "the labels: the two sides overlap in time",
-    labels=("stage",),  # hash | db | read | pack | dispatch
+    "batch (pack) and handing it to the device (dispatch), and, inside "
+    "the row loop beside the reads, the per-chunk digests of the "
+    "journal's chunk cache (chunk_cache). Nothing sums the labels: the "
+    "two sides overlap in time",
+    labels=("stage",),  # hash | db | read | pack | dispatch | chunk_cache
+)
+IDENTIFIER_MESSAGES = REGISTRY.counter(
+    "sd_identifier_messages_total",
+    "cas_id messages the identifier read and queued for hashing, by the "
+    "layout the file's size gave them (whole = the file itself up to "
+    "100 KiB, sampled = header + 4 samples + footer, 57,352 bytes)",
+    labels=("layout",),  # whole | sampled
+)
+CAS_DISPATCH_ROWS = REGISTRY.counter(
+    "sd_cas_dispatch_rows_total",
+    "filled rows (messages, not pad rows) of the hash batches handed to "
+    "the device, by chunk bucket and pad rung of the dispatched array",
+    labels=("chunks", "rung"),  # <= 9 buckets x 3 rungs per device count
+)
+CAS_DISPATCH_BYTES = REGISTRY.counter(
+    "sd_cas_dispatch_bytes_total",
+    "bytes of the padded batch arrays handed to the device (rung x "
+    "chunks x 1,024 each): what crosses the link, where "
+    "sd_feeder_h2d_bytes_total counts the messages alone",
+    labels=("chunks", "rung"),
 )
 
 # --- thumbnailer (object/media/thumbnail/actor.py) --------------------------
