@@ -607,7 +607,7 @@ def check_thumbnails(chk: Checks, node, lib, corpus: str, meta: dict,
         device_px = process.resize_decoded([d])[0]
         th, tw = d.target
         cpu_px = np.asarray(
-            Image.fromarray(d.array, "RGBA").resize((tw, th), Image.BILINEAR))
+            Image.fromarray(d.array).resize((tw, th), Image.BILINEAR))
         worst = max(worst, float(np.abs(
             device_px.astype(int) - cpu_px.astype(int)).mean()))
     chk.ok(worst < 1.0,

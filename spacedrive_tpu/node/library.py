@@ -76,6 +76,11 @@ class Library:
         return self.config.name
 
     def close(self) -> None:
+        # the process-wide search index holds this library, and through
+        # it the node; a library opened again rebuilds it from its rows
+        from ..object.search.index import drop_index
+
+        drop_index(self)
         self.db.close()
 
     def __repr__(self) -> str:

@@ -86,7 +86,7 @@ class ThumbError(Exception):
 @dataclass
 class Decoded:
     """One decoded frame ready for the device batch."""
-    array: np.ndarray  # HxWx4 uint8 RGBA
+    array: np.ndarray  # uint8, HxWx3 RGB or HxWx4 RGBA where there is alpha
     target: tuple[int, int]  # (th, tw) scaled dims
     orientation: int = 1
     is_video: bool = False  # film-strip overlay on finish
@@ -103,7 +103,8 @@ def is_video(extension: str | None) -> bool:
 
 
 def decode_image(path: str) -> Decoded:
-    """Decode a still image to RGBA, reading EXIF orientation.
+    """Decode a still image to RGB, or to RGBA where the file has an
+    alpha band (or a palette's transparency), reading EXIF orientation.
 
     Uses JPEG draft-mode DCT scaling so huge photos decode near the
     target size instead of full-res (the decode-side analogue of the
@@ -124,7 +125,7 @@ def decode_image(path: str) -> Decoded:
             pass
         if img.format == "JPEG":
             img.draft("RGB", (tw, th))  # smallest DCT scale ≥ target
-        img = img.convert("RGBA")
+        img = img.convert("RGBA" if img.has_transparency_data else "RGB")
         arr = np.asarray(img)
     arr = shrink_to_max_dim(arr)
     h, w = arr.shape[:2]
@@ -136,8 +137,8 @@ def decode_image(path: str) -> Decoded:
 def needs_cpu_fallback(d: Decoded) -> bool:
     """Targets beyond the device output canvas (aspect > 4:1) resize on
     host instead of the batched device path."""
-    th, tw = d.target
-    return th > tj.OUT_CANVAS or tw > tj.OUT_CANVAS or max(
+    oh, ow = tj.OUT_CANVAS_HW
+    return min(d.target) > oh or max(d.target) > ow or max(
         d.array.shape[:2]
     ) > tj.BUCKETS[-1]
 
@@ -232,12 +233,13 @@ def decode(path: str, extension: str | None) -> Decoded:
 
 
 def encode_webp(arr: np.ndarray, quality: int = WEBP_QUALITY) -> bytes:
-    """RGBA uint8 → webp bytes at the reference's quality 30
+    """RGB or RGBA uint8 (by the array's channels: an image with
+    transparency keeps it) → webp bytes at the reference's quality 30
     (ref:process.rs:431-440)."""
     from PIL import Image
 
     buf = io.BytesIO()
-    Image.fromarray(arr, "RGBA").save(buf, "WEBP", quality=quality)
+    Image.fromarray(arr).save(buf, "WEBP", quality=quality)
     return buf.getvalue()
 
 
@@ -277,7 +279,7 @@ def resize_cpu(d: Decoded) -> bytes:
     from PIL import Image
 
     th, tw = d.target
-    img = Image.fromarray(d.array, "RGBA").resize((tw, th), Image.BILINEAR)
+    img = Image.fromarray(d.array).resize((tw, th), Image.BILINEAR)
     arr = tj.apply_orientation(np.asarray(img), d.orientation)
     if d.is_video:
         arr = apply_film_strip(arr)

@@ -8,6 +8,7 @@ matmul per query (index.py).
 
 from .index import (  # noqa: F401
     LibraryIndex,
+    drop_index,
     get_index,
     on_embeddings_applied,
     probe_for,

@@ -262,8 +262,12 @@ _INDEXES: dict[tuple[str, str], LibraryIndex] = {}
 _INDEXES_LOCK = threading.Lock()
 
 
+def _key(library: Any) -> tuple[str, str]:
+    return (str(getattr(library.db, "path", ":memory:")), str(library.id))
+
+
 def get_index(library: Any) -> LibraryIndex:
-    key = (str(getattr(library.db, "path", ":memory:")), str(library.id))
+    key = _key(library)
     with _INDEXES_LOCK:
         idx = _INDEXES.get(key)
         if idx is None:
@@ -274,6 +278,13 @@ def get_index(library: Any) -> LibraryIndex:
             # a fresh db handle for the same path)
             idx._library = library
         return idx
+
+
+def drop_index(library: Any) -> None:
+    """Forget a closing library's index, so that neither its vectors nor
+    the library (and the node behind it) outlive the close."""
+    with _INDEXES_LOCK:
+        _INDEXES.pop(_key(library), None)
 
 
 def refresh(library: Any) -> int:

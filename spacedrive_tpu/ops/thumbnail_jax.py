@@ -21,12 +21,22 @@ which ride the MXU; `antialias=True` + `method="triangle"` is exactly
 the reference's Triangle filter for downscale. Crop to the per-image
 target dims, orientation flips, and webp encode stay on host (cheap,
 variable-shape).
+
+What crosses the link is held to what the pixels need: the colour
+planes go as `[B, bh, bw, 3]` canvases, an alpha plane goes beside them
+(`[B, bh, bw, 1]`, the same jitted function) only for the images that
+have one, every thumbnail comes back in the one landscape
+`OUT_CANVAS_HW` canvas, the host side of a canvas is a kept staging
+buffer, not a fresh mapping per call, and on the link the planes are
+folded into the row (`_resize_rows` says why).
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
+import threading
 from typing import Sequence
 
 import numpy as np
@@ -38,10 +48,14 @@ VIDEO_MAX_DIM = 256  # ref:thumbnail/process.rs:470
 # Square input buckets (images are padded up to the next one). 4096 is
 # the reference's max decodable dimension (ref:crates/images/src/consts.rs:33).
 BUCKETS = (256, 512, 1024, 2048, 4096)
-# Output canvas: covers aspect ratios up to 4:1 at TARGET_PX
-# (tw = sqrt(262144·4) = 1024); more extreme aspects fall back to CPU.
+# Longest side a device thumbnail may have: covers aspect ratios up to
+# 4:1 at TARGET_PX (tw = sqrt(262144·4) = 1024); more extreme aspects
+# fall back to CPU.
 OUT_CANVAS = 1024
 MAX_ASPECT = (OUT_CANVAS * OUT_CANVAS) / TARGET_PX  # 4.0
+# The one output canvas, landscape: every portrait transposes in, so
+# th ≤ tw and th·tw ≈ TARGET_PX give th ≤ 512 at any aspect.
+OUT_CANVAS_HW = (OUT_CANVAS // 2, OUT_CANVAS)
 
 
 def scale_dimensions(w: int, h: int, target_px: int = TARGET_PX) -> tuple[int, int]:
@@ -87,16 +101,22 @@ def bucket_for(h: int, w: int) -> tuple[int, int] | None:
     return (b, b)
 
 
-def _one_resize(out_size: int):
-    """Per-image resize body shared by the single-device and sharded
-    bucket programs (identical math ⇒ identical pixels either way)."""
+def _resize_rows(out_hw: tuple[int, int], planes: int):
+    """The body shared by the single-device and sharded bucket programs
+    (identical math ⇒ identical pixels either way). Canvases cross the
+    link with their planes folded into the row, [B, BH, BW·planes], and
+    so do the results: a row-major `uint8` array with a 3- or 4-wide
+    minor dimension is re-tiled on the host, element by element, on its
+    way to the device (2.4 M `Transpose` calls a 32-image put, each an
+    event of a traced run); a plain row goes as it is, and the device
+    unfolds it. The planes are resized independently."""
     import jax
     import jax.numpy as jnp
 
     def one(img, scale):
         out = jax.image.scale_and_translate(
             img.astype(jnp.float32),
-            shape=(out_size, out_size, 4),
+            shape=(*out_hw, planes),
             spatial_dims=(0, 1),
             scale=scale,
             translation=jnp.zeros((2,), jnp.float32),
@@ -105,7 +125,13 @@ def _one_resize(out_size: int):
         )
         return jnp.clip(jnp.round(out), 0, 255).astype(jnp.uint8)
 
-    return one
+    def rows(canvases, scales):
+        b, bh, row = canvases.shape
+        out = jax.vmap(one)(
+            canvases.reshape(b, bh, row // planes, planes), scales)
+        return out.reshape(b, out_hw[0], out_hw[1] * planes)
+
+    return rows
 
 
 @functools.cache
@@ -113,15 +139,15 @@ def _resize_fn():
     """Lazily built jitted bucket-resize (jax imported on first use)."""
     import jax
 
-    @functools.partial(jax.jit, static_argnames=("out_size",))
-    def resize_bucket(canvases, scales, out_size: int):
-        # [B, BH, BW, 4] uint8 RGBA canvases (square or landscape-half
-        # buckets) + per-image [B, 2] (sy, sx) scales → [B, OUT, OUT, 4]
-        # uint8, resized into the top-left
-        # corner. One compiled program per (bucket, out) pair; the
-        # per-image scale is a traced operand, so every (h, w) in the
-        # bucket reuses it.
-        return jax.vmap(_one_resize(out_size))(canvases, scales)
+    @functools.partial(jax.jit, static_argnames=("out_hw", "planes"))
+    def resize_bucket(canvases, scales, out_hw: tuple[int, int], planes: int):
+        # [B, BH, BW·planes] uint8 canvases (square or landscape-half
+        # buckets; 3 colour planes or 1 alpha plane) + per-image [B, 2]
+        # (sy, sx) scales → [B, OH, OW·planes] uint8, resized into the
+        # top-left corner. One compiled program per (bucket, pad,
+        # planes); the per-image scale is a traced operand, so every
+        # (h, w) in the bucket reuses it.
+        return _resize_rows(out_hw, planes)(canvases, scales)
 
     return resize_bucket
 
@@ -134,7 +160,7 @@ def _resize_fn_sharded(devices):
     every device running the same vmapped per-image program on its
     local rows under shard_map — no collectives, so pixels stay
     bit-identical to the single-device call. One compiled program per
-    (device set, bucket, out) like the single-device cache."""
+    (device set, bucket, pad, planes) like the single-device cache."""
     key = tuple(d.id for d in devices)
     fn = _sharded_resize_fns.get(key)
     if fn is None:
@@ -146,13 +172,13 @@ def _resize_fn_sharded(devices):
 
         mesh = Mesh(_np.array(list(devices)), ("dp",))
 
-        @functools.partial(jax.jit, static_argnames=("out_size",))
-        def resize_bucket_sharded(canvases, scales, out_size: int):
-            def body(c, s):
-                return jax.vmap(_one_resize(out_size))(c, s)
-
+        @functools.partial(jax.jit, static_argnames=("out_hw", "planes"))
+        def resize_bucket_sharded(
+            canvases, scales, out_hw: tuple[int, int], planes: int
+        ):
             return jax.shard_map(
-                body, mesh=mesh, in_specs=(P("dp"), P("dp")),
+                _resize_rows(out_hw, planes), mesh=mesh,
+                in_specs=(P("dp"), P("dp")),
                 out_specs=P("dp"), check_vma=False,
             )(canvases, scales)
 
@@ -161,172 +187,246 @@ def _resize_fn_sharded(devices):
     return fn
 
 
-def _resize_bucket(
-    images, targets, flip, idxs, bh: int, bw: int, out_size: int, devs
-) -> np.ndarray:
-    """Pack one bucket's canvases and run its device call; returns the
-    [bpad, out, out, 4] uint8 result (validated — a device returning
-    the wrong shape is an error the caller can demote on, never a
-    silent corruption)."""
+# Kept host canvases, one per (bucket, planes), grown to the widest pad
+# seen and lent to one call at a time: a fresh 100 MiB mapping per call
+# pays a page fault per 4 KiB filled. Bounded, least recently used out
+# first, so a process that once resized a full batch of 4096² canvases
+# does not hold them for good.
+_STAGING_MAX_BYTES = 512 << 20
+_staging: dict[tuple[int, int, int], np.ndarray] = {}
+_staging_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def _staging_canvas(bpad: int, bh: int, bw: int, planes: int):
+    """Lend the [bpad, bh, bw, planes] staging canvas of a bucket for
+    one device call. A caller that finds it lent (two actors resizing at
+    once) gets a canvas of its own. The canvas is taken back only when
+    the call has returned its output: on the CPU backend `device_put`
+    may alias the host array, and a call that failed may still be
+    reading it. Its bytes are whatever the last call left there."""
+    key = (bh, bw, planes)
+    with _staging_lock:
+        buf = _staging.pop(key, None)
+    if buf is None or buf.shape[0] < bpad:
+        buf = np.empty((bpad, bh, bw, planes), np.uint8)
+    yield buf[:bpad]
+    with _staging_lock:
+        other = _staging.pop(key, None)
+        if other is not None and other.shape[0] > buf.shape[0]:
+            buf = other
+        _staging[key] = buf  # last in the dict: most recently used
+        while sum(c.nbytes for c in _staging.values()) > _STAGING_MAX_BYTES:
+            del _staging[next(iter(_staging))]
+
+
+def _resize_bucket(images, targets, bh: int, bw: int, devs) -> np.ndarray:
+    """Pack one bucket's canvases and run its device call: `images` are
+    [h, w, C] uint8 arrays of one C, landscape, `targets` their (th, tw).
+    Returns the [bpad, OH, OW, C] uint8 result (validated — a device
+    returning the wrong shape is an error the caller can demote on,
+    never a silent corruption)."""
+    import jax
+
+    from ..telemetry import metrics as _tm
+    from ..telemetry import span as _span
     from ..utils import faults as _faults
 
     n_dev = len(devs) if devs else 1
+    n_planes = images[0].shape[2]
     # Pad the batch dim to the next power of two so compile count is
     # bounded at (buckets × log2 max-batch) programs, not one per
     # arbitrary group size; a sharded call also rounds up to the
     # device count so rows divide evenly over the mesh.
-    bpad = 1 << max(0, (len(idxs) - 1).bit_length())
+    bpad = 1 << max(0, (len(images) - 1).bit_length())
     if n_dev > 1:
         bpad = max(bpad, n_dev)
         bpad += (-bpad) % n_dev
-    canv = np.zeros((bpad, bh, bw, 4), np.uint8)
-    scales = np.ones((bpad, 2), np.float32)
-    for j, i in enumerate(idxs):
-        img = images[i]
-        th, tw = targets[i]
-        if flip[i]:
-            img = np.transpose(img, (1, 0, 2))
-            th, tw = tw, th
-        h, w = img.shape[:2]
-        # Edge-replicate into the padding so the antialias window
-        # clamps at the image boundary instead of pulling in zeros
-        # (the reference resampler clamps at edges too).
-        canv[j, :h, :w] = img
-        canv[j, h:, :w] = img[h - 1 : h, :]
-        canv[j, :h, w:] = img[:, w - 1 : w]
-        canv[j, h:, w:] = img[h - 1, w - 1]
-        scales[j] = (th / h, tw / w)
-    spec = _faults.hit("device.thumbnail")
-    if spec is not None:
-        if spec.mode == "raise":
-            raise _faults.InjectedFault("injected device failure (thumbnail)")
-        if spec.mode == "xla":
-            raise _faults.device_error("device.thumbnail")
-    if n_dev > 1:
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    oh, ow = OUT_CANVAS_HW
+    with _staging_canvas(bpad, bh, bw, n_planes) as canv:
+        with _span("pack") as part:
+            scales = np.ones((bpad, 2), np.float32)
+            for j, (img, (th, tw)) in enumerate(zip(images, targets)):
+                h, w = img.shape[:2]
+                # Edge-replicate into the padding so the antialias window
+                # clamps at the image boundary instead of pulling in
+                # whatever the canvas held (the reference resampler
+                # clamps at edges too). Every byte of a used row is
+                # written; rows past the group are never returned.
+                canv[j, :h, :w] = img
+                canv[j, h:, :w] = img[h - 1 : h, :]
+                canv[j, :h, w:] = img[:, w - 1 : w]
+                canv[j, h:, w:] = img[h - 1, w - 1]
+                scales[j] = (th / h, tw / w)
+        _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="pack")
+        spec = _faults.hit("device.thumbnail")
+        if spec is not None:
+            if spec.mode == "raise":
+                raise _faults.InjectedFault(
+                    "injected device failure (thumbnail)")
+            if spec.mode == "xla":
+                raise _faults.device_error("device.thumbnail")
+        if n_dev > 1:
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from ..telemetry import metrics as _tm
-        from .cas import shard_occupancy
+            from .cas import shard_occupancy
 
-        mesh, fn = _resize_fn_sharded(devs)
-        _tm.SHARD_BATCH_ROWS.observe(bpad // n_dev, op="thumbnail")
-        for frac in shard_occupancy(len(idxs), bpad, n_dev):
-            _tm.DEVICE_DISPATCH_OCCUPANCY.observe(frac, op="thumbnail")
-        sh = NamedSharding(mesh, P("dp"))
-        out = np.asarray(fn(
-            jax.device_put(canv, sh),
-            jax.device_put(scales, sh),
-            out_size=out_size,
-        ))
-    elif devs:
-        # single surviving device: committed inputs pin the jit there,
-        # not on a default device that may be the dead one
-        import jax
-
-        out = np.asarray(_resize_fn()(
-            jax.device_put(canv, devs[0]), jax.device_put(scales, devs[0]),
-            out_size=out_size,
-        ))
-    else:
-        out = np.asarray(_resize_fn()(canv, scales, out_size=out_size))
+            mesh, fn = _resize_fn_sharded(devs)
+            _tm.SHARD_BATCH_ROWS.observe(bpad // n_dev, op="thumbnail")
+            for frac in shard_occupancy(len(images), bpad, n_dev):
+                _tm.DEVICE_DISPATCH_OCCUPANCY.observe(frac, op="thumbnail")
+            where = NamedSharding(mesh, P("dp"))
+        else:
+            fn = _resize_fn()
+            # a single surviving device: committed inputs pin the jit
+            # there, not on a default device that may be the dead one
+            where = devs[0] if devs else None
+        # the three transfers depend on one another, so blocking between
+        # them hides nothing and gives each its own span
+        with _span("put") as part:
+            on_device = jax.block_until_ready((
+                jax.device_put(canv.reshape(bpad, bh, -1), where),
+                jax.device_put(scales, where)))
+        _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="put")
+        _tm.THUMB_DEVICE_BYTES.inc(canv.nbytes + scales.nbytes, dir="h2d")
+        with _span("run") as part:
+            result = jax.block_until_ready(
+                fn(*on_device, out_hw=(oh, ow), planes=n_planes))
+        _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="run")
+        with _span("get") as part:
+            out = np.asarray(result)
+        _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="get")
+        _tm.THUMB_DEVICE_BYTES.inc(out.nbytes, dir="d2h")
     if spec is not None and spec.mode == "wrong_shape":
-        out = out[:, : out_size // 2]
-    if out.shape != (bpad, out_size, out_size, 4):
+        out = out[:, : oh // 2]
+    if out.shape != (bpad, oh, ow * n_planes):
         raise ValueError(
             f"device resize returned shape {out.shape}, "
-            f"expected {(bpad, out_size, out_size, 4)}"
+            f"expected {(bpad, oh, ow * n_planes)}"
         )
-    return out
+    return out.reshape(bpad, oh, ow, n_planes)
+
+
+def _resize_on_ladder(images, targets, bh: int, bw: int) -> np.ndarray:
+    """`_resize_bucket` on the degradation ladder (parallel.mesh.LADDER):
+    a failed bucket call demotes — full mesh → surviving subset → single
+    default device (the per-image math is identical at every rung, so
+    pixels never change) — and the bucket re-runs at the demoted rung
+    instead of failing the chunk."""
+    from ..parallel import mesh as _mesh
+
+    # bounded: one attempt per rung plus one half-open probe — a tiny
+    # reset_timeout must not oscillate probe/demote forever
+    for attempt in range(4):
+        devs, level = _mesh.ladder_devices()
+        if (
+            level < _mesh.LEVEL_HOST
+            and len(devs) > 1 and len(images) >= len(devs)
+        ):
+            use = devs
+        elif level == _mesh.LEVEL_SUBSET and devs:
+            # unsharded at the subset rung: still pin to a surviving
+            # chip, never the (possibly dead) default
+            use = devs[:1]
+        else:
+            use = None
+        try:
+            out = _resize_bucket(images, targets, bh, bw, use)
+        except Exception as exc:  # noqa: BLE001 - demote & retry
+            # always settle the ladder bookkeeping (a probe left
+            # unreported would block re-arming), THEN decide whether
+            # anything is left to demote to
+            _mesh.LADDER.record_failure(level, devs)
+            if level >= _mesh.LEVEL_HOST or attempt == 3:
+                raise
+            from ..telemetry import events as _events
+
+            _events.record_error("thumbnail.ladder", exc)
+            continue
+        if use is not None:
+            _mesh.LADDER.record_success(level)
+        else:
+            # ran on the single default device — says nothing about the
+            # rung's chips; release a held probe
+            _mesh.LADDER.probe_inconclusive(level)
+        return out
+    raise AssertionError("unreachable: the last attempt returns or raises")
 
 
 def resize_batch(
     images: Sequence[np.ndarray],
     targets: Sequence[tuple[int, int]],
-    out_size: int = OUT_CANVAS,
     devices: Sequence | None = None,
 ) -> list[np.ndarray]:
-    """Resize a batch of HxWx4 uint8 RGBA images to per-image (th, tw).
+    """Resize a batch of uint8 images, HxWx3 RGB or HxWx4 RGBA, to
+    per-image (th, tw); a result has its input's channels.
 
     Groups by input bucket, pads to the bucket canvas, runs one device
-    call per bucket, crops on host. Returns resized uint8 arrays in
-    input order. Images too large for any bucket or with th/tw beyond
-    the output canvas must be filtered by the caller beforehand.
+    call per bucket for the colour planes and one more, through the same
+    program at one plane, for the alpha of those images that have it;
+    crops on host. Returns resized uint8 arrays in input order. Images
+    too large for any bucket or with th/tw beyond the output canvas
+    must be filtered by the caller beforehand.
 
     With >1 local device (or an explicit `devices` list) the batch dim
     of each bucket call dp-shards over the chip mesh — one dispatch,
     every chip resizing its slice of the canvases.
 
-    Auto dispatches ride the degradation ladder (parallel.mesh.LADDER):
-    a failed bucket call demotes — full mesh → surviving subset →
-    single default device (the per-image math is identical at every
-    rung, so pixels never change) — and the bucket re-runs at the
-    demoted rung instead of failing the chunk. Explicit `devices` stay
-    strict and re-raise."""
+    Auto dispatches ride the degradation ladder (`_resize_on_ladder`);
+    explicit `devices` stay strict and re-raise."""
+    from ..telemetry import metrics as _tm
+    from ..telemetry import span as _span
+
+    oh, ow = OUT_CANVAS_HW
     results: list[np.ndarray | None] = [None] * len(images)
     by_bucket: dict[tuple[int, int], list[int]] = {}
+    # every image rides its canvas in landscape: a portrait transposes in
+    # (cheap uint8 host view; un-transposed after the crop)
     flip: list[bool] = [False] * len(images)
+    landscape: list[np.ndarray] = list(images)
+    canvas_targets = list(targets)
     for i, img in enumerate(images):
+        if img.ndim != 3 or img.shape[2] not in (3, 4):
+            raise ValueError(f"image {i}: shape {img.shape} is not HxWx3|4")
         h, w = img.shape[:2]
         b = bucket_for(h, w)
         if b is None:
             raise ValueError(f"image {i} ({h}x{w}) exceeds max bucket")
-        # portrait images ride the landscape half-canvas transposed
-        # (cheap uint8 host transpose; un-transposed after the crop)
-        flip[i] = b[0] < b[1] and h > w
+        th, tw = targets[i]
+        if h > w:
+            flip[i] = True
+            landscape[i] = np.transpose(img, (1, 0, 2))
+            th, tw = canvas_targets[i] = (tw, th)
+        if th > oh or tw > ow:
+            raise ValueError(
+                f"image {i}: target {targets[i]} exceeds the output canvas")
         by_bucket.setdefault(b, []).append(i)
 
-    for (bh, bw), idxs in by_bucket.items():
+    def dispatch(members, channels, bh, bw):
+        group = [landscape[i][..., channels] for i in members]
+        want = [canvas_targets[i] for i in members]
         if devices is not None:
-            out = _resize_bucket(
-                images, targets, flip, idxs, bh, bw, out_size, list(devices)
-            )
-        else:
-            from ..parallel import mesh as _mesh
+            return _resize_bucket(group, want, bh, bw, list(devices))
+        return _resize_on_ladder(group, want, bh, bw)
 
-            # bounded: one attempt per rung plus one half-open probe —
-            # a tiny reset_timeout must not oscillate probe/demote forever
-            for attempt in range(4):
-                devs, level = _mesh.ladder_devices()
-                if (
-                    level < _mesh.LEVEL_HOST
-                    and len(devs) > 1 and len(idxs) >= len(devs)
-                ):
-                    use = devs
-                elif level == _mesh.LEVEL_SUBSET and devs:
-                    # unsharded at the subset rung: still pin to a
-                    # surviving chip, never the (possibly dead) default
-                    use = devs[:1]
-                else:
-                    use = None
-                try:
-                    out = _resize_bucket(
-                        images, targets, flip, idxs, bh, bw, out_size, use
-                    )
-                except Exception as exc:  # noqa: BLE001 - demote & retry
-                    # always settle the ladder bookkeeping (a probe left
-                    # unreported would block re-arming), THEN decide
-                    # whether anything is left to demote to
-                    _mesh.LADDER.record_failure(level, devs)
-                    if level >= _mesh.LEVEL_HOST or attempt == 3:
-                        raise
-                    from ..telemetry import events as _events
-
-                    _events.record_error("thumbnail.ladder", exc)
-                    continue
-                if use is not None:
-                    _mesh.LADDER.record_success(level)
-                else:
-                    # ran on the single default device — says nothing
-                    # about the rung's chips; release a held probe
-                    _mesh.LADDER.probe_inconclusive(level)
-                break
-        for j, i in enumerate(idxs):
-            th, tw = targets[i]
-            if flip[i]:
-                results[i] = np.transpose(out[j, :tw, :th], (1, 0, 2))
-            else:
-                results[i] = out[j, :th, :tw]
+    for (bh, bw), idxs in by_bucket.items():
+        with_alpha = [i for i in idxs if landscape[i].shape[2] == 4]
+        colour = dispatch(idxs, slice(0, 3), bh, bw)
+        alpha = dispatch(with_alpha, slice(3, 4), bh, bw) if with_alpha else None
+        with _span("crop") as part:
+            alpha_row = {i: k for k, i in enumerate(with_alpha)}
+            for j, i in enumerate(idxs):
+                th, tw = canvas_targets[i]
+                out = colour[j, :th, :tw]
+                if i in alpha_row:
+                    out = np.concatenate(
+                        [out, alpha[alpha_row[i], :th, :tw]], axis=-1)
+                results[i] = np.transpose(out, (1, 0, 2)) if flip[i] else out
+        _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="crop")
+        if with_alpha:
+            _tm.THUMB_RESIZE_IMAGES.inc(len(with_alpha), alpha="1")
+        if len(with_alpha) < len(idxs):
+            _tm.THUMB_RESIZE_IMAGES.inc(len(idxs) - len(with_alpha), alpha="0")
     return results  # type: ignore[return-value]
 
 

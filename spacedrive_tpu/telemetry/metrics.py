@@ -138,6 +138,25 @@ THUMB_WORK_SECONDS = REGISTRY.histogram(
     "the chunk's wall with semaphore queueing and pipeline overlap in it",
     labels=("stage",),  # decode | encode
 )
+THUMB_DEVICE_SECONDS = REGISTRY.counter(
+    "sd_thumbnail_device_seconds",
+    "the device stage of the thumbnailer by part, each blocked to its end: "
+    "pack (fill the staging canvases), put (host to device), run (dispatch "
+    "to block_until_ready), get (device to host), crop",
+    labels=("part",),  # pack | put | run | get | crop
+)
+THUMB_DEVICE_BYTES = REGISTRY.counter(
+    "sd_thumbnail_device_bytes_total",
+    "nbytes of the canvases put on the device and of the output canvases "
+    "fetched from it, padding included",
+    labels=("dir",),  # h2d | d2h
+)
+THUMB_RESIZE_IMAGES = REGISTRY.counter(
+    "sd_thumbnail_resize_images_total",
+    "images resized on the device, by whether an alpha plane went with "
+    "the colour planes",
+    labels=("alpha",),  # 0 | 1
+)
 
 # --- semantic search (models/embedder.py, object/search/index.py) -----------
 

@@ -36,15 +36,18 @@ hashed = blake3_jax._hash_batch_impl_modes["tpu"].lower(
 assert hashed.out_info.shape == (32, 8), hashed.out_info
 assert hashed.memory_analysis().generated_code_size_in_bytes > 0
 
-# one resize bucket: 4 canvases of 1024² RGBA → the 1024² output canvas
-resized = thumbnail_jax._resize_fn().lower(
-    spec((4, 1024, 1024, 4), np.uint8), spec((4, 2), np.float32),
-    out_size=thumbnail_jax.OUT_CANVAS,
-).compile()
-assert resized.out_info.shape == (4, 1024, 1024, 4), resized.out_info
-# canvases + scales in, one output canvas each out — and it fits a chip
-mem = resized.memory_analysis()
-assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 << 30
+# one resize bucket: 4 canvases of 1024², the colour planes and the alpha
+# plane, planes folded into the row → the 512 × 1024 output canvas,
+# through the one jitted function
+for planes in (3, 1):
+    resized = thumbnail_jax._resize_fn().lower(
+        spec((4, 1024, 1024 * planes), np.uint8), spec((4, 2), np.float32),
+        out_hw=thumbnail_jax.OUT_CANVAS_HW, planes=planes,
+    ).compile()
+    assert resized.out_info.shape == (4, 512, 1024 * planes), resized.out_info
+    # canvases + scales in, one output canvas each out — and it fits a chip
+    mem = resized.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 << 30
 print("AOT_OK")
 """
 

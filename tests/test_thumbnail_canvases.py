@@ -1,0 +1,251 @@
+"""The canvases of the thumbnailer's device stage (ISSUE 28): three
+colour planes where the image has no alpha, an alpha plane beside them
+where it has, one landscape 512 × 1024 output canvas, a kept staging
+buffer; and the contract `benchmark/warm.py` holds the stage to."""
+
+import io
+import sys
+import threading
+
+import numpy as np
+import pytest
+from PIL import Image
+
+from spacedrive_tpu.object.media.thumbnail import Thumbnailer, process
+from spacedrive_tpu.ops import thumbnail_jax as tj
+
+RNG = np.random.default_rng(28)
+
+
+def _photo(h: int, w: int, channels: int = 4) -> np.ndarray:
+    """A gradient that says which way is up (red grows to the right,
+    green downwards) under some noise; alpha is a gradient of its own."""
+    y, x = np.mgrid[0:h, 0:w]
+    img = np.stack([x * 255 // w, y * 255 // h, (x + y) % 256,
+                    255 - (x * 255 // w)], -1).astype(np.int16)
+    img[..., :3] += RNG.integers(-12, 13, (h, w, 3), dtype=np.int16)
+    return np.clip(img, 0, 255).astype(np.uint8)[..., :channels]
+
+
+def _targets(images):
+    out = []
+    for img in images:
+        tw, th = tj.scale_dimensions(img.shape[1], img.shape[0])
+        out.append((th, tw))
+    return out
+
+
+# (a) the colour planes do not depend on whether an alpha plane rides along
+
+
+@pytest.mark.parametrize("shapes", [
+    [(600, 900)],               # a square bucket
+    [(400, 900)],               # a landscape half bucket
+    [(900, 600)],               # a portrait, transposed into a square bucket
+    [(200, 150), (700, 1000)],  # two buckets in one call
+], ids=["square", "half", "portrait_in_square", "two_buckets"])
+def test_rgb_in_gives_the_colour_planes_rgba_in_gives(shapes):
+    rgba = [_photo(h, w) for h, w in shapes]
+    rgb = [np.ascontiguousarray(img[..., :3]) for img in rgba]
+    targets = _targets(rgba)
+    out4 = tj.resize_batch(rgba, targets)
+    out3 = tj.resize_batch(rgb, targets)
+    for a, b, t in zip(out4, out3, targets):
+        assert a.shape == (*t, 4) and b.shape == (*t, 3)
+        # equal expected; XLA may tile a 3-wide minor dimension otherwise
+        assert np.abs(a[..., :3].astype(int) - b.astype(int)).max() <= 1
+
+
+# (b) every aspect the device path takes fits the one output canvas
+
+
+@pytest.mark.parametrize("h,w", [
+    (512, 2048), (600, 1800), (720, 1280), (800, 1200), (1000, 1000),
+    (1200, 800), (1280, 720), (1800, 600), (2048, 512),
+], ids=lambda v: str(v))
+def test_aspects_up_to_four_fit_the_canvas_the_right_way_up(h, w):
+    img = _photo(h, w, 3)
+    (th, tw), = _targets([img])
+    d = process.Decoded(array=img, target=(th, tw))
+    assert not process.needs_cpu_fallback(d)
+    oh, ow = tj.OUT_CANVAS_HW
+    assert min(th, tw) <= oh and max(th, tw) <= ow
+    out = process.resize_decoded([d])[0]
+    assert out.shape == (th, tw, 3)
+    # red still grows to the right and green downwards
+    assert out[:, -8:, 0].mean() > out[:, :8, 0].mean() + 100
+    assert out[-8:, :, 1].mean() > out[:8, :, 1].mean() + 100
+    ref = np.asarray(Image.fromarray(img).resize((tw, th), Image.BILINEAR))
+    assert np.abs(out.astype(int) - ref.astype(int)).mean() < 1.5
+
+
+@pytest.mark.parametrize("h,w", [(500, 2005), (2005, 500)])
+def test_beyond_four_to_one_still_resizes_on_the_host(h, w):
+    img = _photo(h, w, 3)
+    d = process.Decoded(array=img, target=_targets([img])[0])
+    assert process.needs_cpu_fallback(d)
+    with pytest.raises(ValueError, match="exceeds the output canvas"):
+        process.resize_decoded([d])
+    with Image.open(io.BytesIO(process.resize_cpu(d))) as im:
+        assert im.size == (d.target[1], d.target[0])
+
+
+# (c) what is stored: transparency is kept, and only where there is some
+
+
+@pytest.mark.parametrize("ext,mode", [("png", "RGBA"), ("jpg", "RGB")])
+@pytest.mark.asyncio
+async def test_stored_webp_keeps_alpha_where_the_file_has_it(
+        tmp_path, ext, mode):
+    src = _photo(600, 900)
+    path = str(tmp_path / f"src.{ext}")
+    if mode == "RGBA":
+        Image.fromarray(src).save(path)
+    else:
+        Image.fromarray(src[..., :3]).save(path, quality=92)
+    th = Thumbnailer(tmp_path / "data")
+    try:
+        cas = "c0ffee0000000028"
+        assert th.new_indexed_thumbnails_batch("lib", [(cas, path, ext)]) > 0
+        await th.wait_library_batch("lib")
+        assert th.generated == 1 and th.errors == 0
+        with Image.open(th.store.path_for("lib", cas)) as im:
+            assert im.format == "WEBP" and im.mode == mode
+            got = np.asarray(im)
+    finally:
+        await th.shutdown()
+    tw, t_h = tj.scale_dimensions(900, 600)
+    assert got.shape == (t_h, tw, len(mode))
+    want = np.asarray(Image.fromarray(src[..., :len(mode)]).resize(
+        (tw, t_h), Image.BILINEAR))
+    if mode == "RGBA":
+        gap = np.abs(got[..., 3].astype(int) - want[..., 3].astype(int))
+        assert gap.mean() <= 3
+        assert got[..., 3].min() < 16 and got[..., 3].max() > 240
+
+
+def test_decode_hands_over_alpha_only_where_the_file_has_it(tmp_path):
+    src = _photo(64, 96)
+    cases = {"rgba.png": (src, 4), "rgb.png": (src[..., :3], 3),
+             "rgb.jpg": (src[..., :3], 3)}
+    for name, (arr, channels) in cases.items():
+        path = str(tmp_path / name)
+        Image.fromarray(arr).save(path)
+        assert process.decode_image(path).array.shape == (64, 96, channels)
+    # a palette image with a transparent index has alpha too
+    pal = Image.fromarray(src[..., :3]).convert("P")
+    pal.save(str(tmp_path / "pal.png"), transparency=0)
+    assert process.decode_image(
+        str(tmp_path / "pal.png")).array.shape == (64, 96, 4)
+
+
+# (d) the kept staging canvas
+
+
+@pytest.fixture
+def staging(monkeypatch):
+    """An empty set of kept canvases: what other tests of this process
+    left there does not decide which canvas is kept here."""
+    kept: dict = {}
+    monkeypatch.setattr(tj, "_staging", kept)
+    return kept
+
+
+def test_second_call_through_the_kept_canvas_returns_nothing_of_the_first(
+        staging):
+    bright = [np.full((700, 1000, 3), 255, np.uint8) for _ in range(4)]
+    tj.resize_batch(bright, _targets(bright))
+    key = (1024, 1024, 3)
+    kept = staging[key]
+    assert kept.shape[0] >= 4 and (kept[:4] == 255).all()
+    dark = [np.zeros((520, 640, 3), np.uint8)]
+    out = tj.resize_batch(dark, _targets(dark))[0]
+    assert out.shape == (*_targets(dark)[0], 3)
+    assert (out == 0).all()
+    # one canvas per (bucket, planes), the same one, and never the result
+    assert staging[key] is kept and list(staging) == [key]
+    assert not np.shares_memory(out, kept)
+
+
+def test_kept_canvases_are_bounded_least_recently_used_first(
+        staging, monkeypatch):
+    # room for the colour canvas and the small one, not for the alpha too
+    monkeypatch.setattr(tj, "_STAGING_MAX_BYTES",
+                        2 * 256 * 256 * 3 + 128 * 128 * 3)
+    for planes in (3, 1, 3):  # colour, alpha, colour again: alpha is oldest
+        with tj._staging_canvas(2, 256, 256, planes) as canvas:
+            assert canvas.shape == (2, 256, 256, planes)
+    with tj._staging_canvas(1, 128, 128, 3):
+        pass
+    assert list(staging) == [(256, 256, 3), (128, 128, 3)]
+    with pytest.raises(RuntimeError):
+        with tj._staging_canvas(2, 256, 256, 3):
+            raise RuntimeError("the call failed: its canvas is not kept")
+    assert list(staging) == [(128, 128, 3)]
+
+
+def test_two_threads_resizing_at_once_get_a_canvas_each(staging):
+    images = {0: [np.full((300, 400, 3), 40, np.uint8)] * 3,
+              1: [np.full((280, 500, 3), 200, np.uint8)] * 2}
+    failures: list[str] = []
+
+    def work(k: int) -> None:
+        value = int(images[k][0][0, 0, 0])
+        for _ in range(6):
+            for out in tj.resize_batch(images[k], _targets(images[k])):
+                if not (out == value).all():
+                    failures.append(f"thread {k}: pixels of the other")
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(k % 2,))
+                   for k in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not failures
+    assert list(staging) == [(512, 512, 3)]
+
+
+# the warm-up contract (benchmark/warm.py:75-90, 131-135): warm.py runs ONE
+# call per (bucket, pad), a 4-channel array of zeros, and the benchmark holds
+# a timed pass to zero compile requests. So that call has to reach every
+# program an RGB photo or an RGBA image of that bucket and pad reaches.
+
+_compile_requests: list[str] = []
+
+
+def _count_compile_requests() -> None:
+    import jax.monitoring as monitoring
+
+    if not _compile_requests:
+        _compile_requests.append("listening")
+        monitoring.register_event_duration_secs_listener(
+            lambda event, _secs, **_kw: _compile_requests.append(event)
+            if event == "/jax/core/compile/backend_compile_duration" else None)
+
+
+@pytest.mark.parametrize("bucket,pad", [
+    ((256, 256), 2), ((512, 1024), 1), ((1024, 1024), 4),
+], ids=["256sq_pad2", "half1024_pad1", "1024sq_pad4"])
+def test_warm_call_reaches_every_program_a_pass_dispatches(bucket, pad):
+    _count_compile_requests()
+    bh, bw = bucket
+    # the literal call of benchmark/warm.py:resize_one
+    tj.resize_batch([np.zeros((bh, bw, 4), np.uint8)] * pad,
+                    [(min(bh, tj.OUT_CANVAS) // 2,
+                      min(bw, tj.OUT_CANVAS) // 2)] * pad)
+    before = len(_compile_requests)
+    h, w = bh - 7, bw - 3
+    assert tj.bucket_for(h, w) == bucket
+    for channels in (3, 4):
+        for shape in ((h, w), (w, h)):
+            images = [_photo(*shape, channels)] * pad
+            outs = tj.resize_batch(images, _targets(images))
+            assert outs[0].shape[2] == channels
+    assert len(_compile_requests) == before
