@@ -32,8 +32,7 @@ protected-class sheds occur, fd/RSS deltas stay bounded, and files/s
 stays flat across warm passes; a trend breach leaves a triggered
 profile capture behind as the forensics artifact.
 
-Output: ``BENCH_SCALE.json`` (schema ``bench-scale/v1``), gated by
-``tools/bench_compare.check_scale`` under ``make bench-check``.
+Output: ``BENCH_SCALE.json`` (schema ``bench-scale/v1``).
 
 Knobs (script-scope; docs/telemetry.md): ``SD_SOAK_FILES`` (default
 20000), ``SD_SOAK_SECONDS`` (default 120), ``SD_SOAK_SEED`` (default
@@ -57,7 +56,7 @@ from typing import Any
 
 SCHEMA = "bench-scale/v1"
 
-# the bars (mirrored in tools/bench_compare.py check_scale)
+# the bars
 FD_DELTA_MAX = 32
 RSS_DELTA_MAX_MB = 512.0
 FLATNESS_MIN = 0.5
@@ -216,8 +215,8 @@ class SoakDriver:
 
     async def scenario_reads(self) -> None:
         """Serve-layer read swarm against the node's own HTTP API (a
-        short in-process burst; bench_serve owns the calibrated
-        capacity figures — the soak only needs sustained read load)."""
+        short in-process burst: the soak only needs sustained read
+        load, not capacity figures)."""
         import aiohttp
 
         args = [
@@ -337,14 +336,6 @@ async def _boot(data_dir: str, corpus: str):
     return node, lib, loc["id"], port, time.monotonic() - t0
 
 
-def _rig_stamp() -> dict:
-    """cpu_count + live procpool size, stamped into the artifact so
-    comparators can tell honest-floor single-core recordings apart."""
-    from spacedrive_tpu.parallel.procpool import rig_stamp
-
-    return rig_stamp()
-
-
 def _flatness(passes: list[dict[str, float]]) -> float:
     """Last-half median files/s over first-half median: 1.0 is flat,
     below :data:`FLATNESS_MIN` means warm passes are getting slower —
@@ -456,7 +447,7 @@ async def run_soak(files: int | None = None, seconds: float | None = None,
             "schema": SCHEMA,
             "ts": time.time(),
             "host": {"platform": platform.platform(),
-                     "cpus": os.cpu_count(), **_rig_stamp()},
+                     "cpus": os.cpu_count()},
             "params": {"files": files, "seconds": seconds, "seed": seed,
                        "mix": mix, "p2p": p2p_on, "faults": faults_on,
                        "rounds": rounds,

@@ -20,7 +20,7 @@ Variants, all bit-exact against the production kernel:
                 directly where possible (no early combine)  [dropped if
                 it can't be made bit-exact cheaply]
 
-Timing: chained-marginal device cost (the bench.py technique — a
+Timing: chained-marginal device cost (a
 single dispatch times launch latency, the marginal chained dispatch is
 device-bound), distinct inputs each link, plus digest equality checks.
 

@@ -4,7 +4,7 @@ Parity: two reference hosts in one binary — the headless server
 (ref:apps/server/src/main.rs: node + HTTP API) and the crypto
 inspector CLI (ref:apps/cli/src/main.rs: prints encrypted-file header
 details). Plus the survey's build-plan surface (SURVEY §7 step 4):
-`sdx index <path> --backend=tpu|cpu` and `sdx bench`.
+`sdx index <path> --backend=tpu|cpu`.
 
 Run as `python -m spacedrive_tpu <command>`.
 """
@@ -704,14 +704,6 @@ def cmd_labeler(args: argparse.Namespace) -> int:
     return 2
 
 
-def cmd_bench(_args: argparse.Namespace) -> int:
-    import runpy
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(__file__)), "bench.py")
-    runpy.run_path(bench, run_name="__main__")
-    return 0
-
-
 def _write_or_print(doc: str, out: str | None) -> None:
     if out:
         with open(out, "w") as f:
@@ -1109,8 +1101,6 @@ def build_parser() -> argparse.ArgumentParser:
     rl.add_argument("--stats-interval", type=float, default=60.0,
                     help="seconds between stats log lines (0 disables)")
 
-    sub.add_parser("bench", help="run the headline benchmark")
-
     te = sub.add_parser(
         "trace-export",
         help="export a running node's span ring as Perfetto-loadable "
@@ -1279,8 +1269,6 @@ def main(argv: list[str] | None = None) -> int:
         return cmd_crypto(args)
     if args.cmd == "labeler":
         return cmd_labeler(args)
-    if args.cmd == "bench":
-        return cmd_bench(args)
     if args.cmd == "trace-export":
         return cmd_trace_export(args)
     if args.cmd == "attrib":

@@ -133,8 +133,7 @@ class LibraryDb:
         """`db.slow` fault point: one `is None` check in production; an
         armed `stall` spec sleeps delay_s per read — the deterministic
         stand-in for a slow/contended disk that the serve layer's
-        overload chaos tests (and bench_serve.py's throttled arm) put
-        under the whole read surface."""
+        overload chaos tests put under the whole read surface."""
         from ..utils import faults as _faults
 
         spec = _faults.hit("db.slow")

@@ -3,7 +3,7 @@
  *
  * Written from the public BLAKE3 specification; mirrors the Python
  * golden reference in ops/blake3_ref.py. Role in the framework:
- *   - honest multi-core CPU baseline for bench.py (the reference uses
+ *   - honest multi-core CPU baseline (the reference uses
  *     the Rust blake3 crate for cas_id, ref:core/src/object/cas.rs:3);
  *   - fast host-side fallback when no accelerator is attached;
  *   - streaming full-file hashing for the validator pipeline

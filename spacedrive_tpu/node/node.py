@@ -101,7 +101,7 @@ class Node:
         self.loop_monitor = LoopLagMonitor()
         # persistent telemetry history: sampled allowlisted series into
         # an append-only segment store under the data dir — constructed
-        # unconditionally so offline readers (sdx slo, bench_compare)
+        # unconditionally so offline readers (sdx slo)
         # can open the same directory; sampling only starts with the
         # node and only when SD_HISTORY != 0
         from ..telemetry.history import HistoryWriter, history_dir

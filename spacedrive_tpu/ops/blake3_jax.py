@@ -295,8 +295,8 @@ def _dp_mesh(devices):
     return Mesh(np.array(list(devices)), ("dp",))
 
 
-def _sharded_impl(mode: str | None, devices, donate_input: bool = True):
-    key = (mode, tuple(d.id for d in devices), donate_input)
+def _sharded_impl(mode: str | None, devices):
+    key = (mode, tuple(d.id for d in devices))
     impl = _sharded_impls.get(key)
     if impl is None:
         from jax.sharding import PartitionSpec as P
@@ -304,11 +304,8 @@ def _sharded_impl(mode: str | None, devices, donate_input: bool = True):
         mesh = _dp_mesh(devices)
         # donation frees the (large) message buffer for reuse the
         # moment the transfer is consumed; CPU backends don't implement
-        # it and would only warn. Callers that re-hash a placed buffer
-        # (bench's chained sweep) opt out.
-        donate = (
-            (0,) if donate_input and devices[0].platform != "cpu" else ()
-        )
+        # it and would only warn
+        donate = (0,) if devices[0].platform != "cpu" else ()
 
         @functools.partial(
             jax.jit, static_argnames=("max_chunks",), donate_argnums=donate
@@ -333,27 +330,24 @@ def _sharded_impl(mode: str | None, devices, donate_input: bool = True):
 def shard_put(arr, devices):
     """Place a batch on the flat `dp` mesh over `devices` (dim 0
     split, trailing dims replicated). A no-op when the array already
-    has that sharding — bench pre-places its chained inputs through
-    here so timed dispatches measure compute, not transfer."""
+    has that sharding."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     return jax.device_put(arr, NamedSharding(_dp_mesh(devices), P("dp")))
 
 
-def _hash_batch_sharded(
-    msgs, lengths, max_chunks: int, devices, donate_input: bool = True
-) -> jax.Array:
+def _hash_batch_sharded(msgs, lengths, max_chunks: int, devices) -> jax.Array:
     from ..telemetry import metrics as _tm
 
     _tm.SHARD_BATCH_ROWS.observe(msgs.shape[0] // len(devices), op="blake3")
-    return _sharded_impl(blake3_pallas.pallas_mode(), devices, donate_input)(
+    return _sharded_impl(blake3_pallas.pallas_mode(), devices)(
         shard_put(msgs, devices), shard_put(lengths, devices),
         max_chunks=max_chunks,
     )
 
 
 def hash_batch(msgs, lengths, max_chunks: int | None = None,
-               devices=None, donate_input: bool = True) -> jax.Array:
+               devices=None) -> jax.Array:
     """Hash B messages. msgs: uint8[B, C*1024] (zero-padded) or its
     uint32[B, C*256] LE-word view; lengths: int32[B] actual byte
     counts. Returns uint32[B, 8] — the first 32 digest bytes as LE
@@ -398,9 +392,7 @@ def hash_batch(msgs, lengths, max_chunks: int | None = None,
                 f"batch of {msgs.shape[0]} rows does not divide over "
                 f"{len(devices)} devices — pad through pack_canonical_batch"
             )
-        out = _hash_batch_sharded(
-            msgs, lengths, max_chunks, devices, donate_input
-        )
+        out = _hash_batch_sharded(msgs, lengths, max_chunks, devices)
     else:
         if devices is not None and len(devices) == 1:
             # pin the single-device dispatch to THIS device (the

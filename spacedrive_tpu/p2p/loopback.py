@@ -6,8 +6,7 @@ that drives the real wire protocol (``Header`` SYNC / SYNC_REQUEST /
 TELEMETRY / WORK, msgpack frames) without the encrypted socket layer,
 so it runs in dep-less CI containers where ``cryptography`` is absent.
 Extracted from tests/test_mesh_observability.py so the mesh-parallel
-index tests and ``bench_e2e.py``'s ``config_mesh`` drive the SAME
-loopback instead of three drifting copies.
+index tests drive the SAME loopback instead of drifting copies.
 
 Note: both nodes live in one process and therefore share the global
 metrics registry and flight-recorder rings — per-peer series stay

@@ -1,12 +1,12 @@
 """Multi-process execution plane — escape the GIL for CPU-bound stages.
 
-BENCH_E2E ``config_mesh`` records 0.122 scaling efficiency for two
-in-process nodes, and the PR 13 host profiler names why: one shared
-GIL serializes every per-entry Python between the spans — journal
-payload decode, chunk-cache digesting, linking SQL prep, image
-decode/webp encode, pHash planes. The reference's execution layer is a
-work-stealing multi-threaded Rust task system (``crates/task-system``)
-that simply uses the cores; this Python mirror needs **processes**.
+Two in-process nodes scale poorly, and the PR 13 host profiler names
+why: one shared GIL serializes every per-entry Python between the
+spans — journal payload decode, chunk-cache digesting, linking SQL
+prep, image decode/webp encode, pHash planes. The reference's
+execution layer is a work-stealing multi-threaded Rust task system
+(``crates/task-system``) that simply uses the cores; this Python
+mirror needs **processes**.
 
 This module is the owner-side half: a persistent pool of worker
 processes (each a fresh ``python -m spacedrive_tpu.parallel.procworker``
@@ -45,9 +45,9 @@ dispatches CPU-bound stages onto:
   so the serialize+frame tax is paid per quantum, not per row.
 
 Evidence plane: ``sd_procpool_*`` (workers alive, dispatch/roundtrip
-seconds, batch rows, restarts, job outcomes), the bench_e2e
-``config_procs`` A/B, and the attribution report's ``gap``/``gil_wait``
-shares shrinking (docs/performance.md "Multi-process execution plane").
+seconds, batch rows, restarts, job outcomes) and the attribution
+report's ``gap``/``gil_wait`` shares (docs/performance.md
+"Multi-process execution plane").
 """
 
 from __future__ import annotations
@@ -100,16 +100,6 @@ def procs() -> int:
 
 def enabled() -> bool:
     return procs() > 0
-
-
-def rig_stamp() -> dict:
-    """Host execution-rig facts stamped into every BENCH_*.json so a
-    comparator can tell an honest-floor single-core run from a real
-    scaling regression before gating any parallelism ratio."""
-    return {
-        "cpu_count": os.cpu_count() or 1,
-        "procpool_procs": procs(),
-    }
 
 
 class ProcPoolError(RuntimeError):

@@ -1,9 +1,9 @@
 """Telemetry — metrics registry, pipeline spans, snapshot/scrape APIs.
 
-The observability layer for the TPU dispatch path (BENCH_r05's lesson:
-device compute at 610k files/s with e2e at 489 files/s was only
-explainable by ad-hoc prints — now the queue waits, batch occupancy,
-H2D byte counts, and per-phase durations are first-class series).
+The observability layer for the TPU dispatch path: queue waits, batch
+occupancy, H2D byte counts, and per-phase durations are first-class
+series, so a gap between device and end-to-end rates needs no ad-hoc
+prints to explain.
 
 Surface:
 
@@ -12,8 +12,7 @@ Surface:
 - ``metrics`` — every predeclared family for the hot paths;
 - ``span(stage, nbytes=0)`` — sync/async context manager recording
   per-stage wall time and bytes;
-- ``snapshot()`` — the JSON read path (rspc ``telemetry.snapshot``,
-  bench.py);
+- ``snapshot()`` — the JSON read path (rspc ``telemetry.snapshot``);
 - ``render()`` — Prometheus exposition text (the ``/metrics`` route);
 - ``trace`` / ``trace_export()`` — distributed trace ids on every span,
   exported as Chrome-trace JSON (the ``/trace`` route);

@@ -61,7 +61,7 @@ from typing import Any, Iterable
 from . import metrics as _tm
 from . import trace as _trace
 
-#: bucket vocabulary (stable: bench_e2e + bench_compare gate on it)
+#: bucket vocabulary (stable: history series and /attrib readers key on it)
 DEVICE = "device"
 HOST_CPU = "host_cpu"
 LINK = "link"

@@ -4,9 +4,8 @@ restarts.
 Every metric in the registry dies with the process; every federation
 snapshot ages out of the cache in a minute. That makes "is sync lag
 getting worse week over week?" unanswerable — exactly the question the
-SLO burn-rate engine (``telemetry/slo.py``) and the perf-trajectory
-gate (``tools/bench_compare.py``) need answered. This module is the
-smallest durable answer:
+SLO burn-rate engine (``telemetry/slo.py``) needs answered. This
+module is the smallest durable answer:
 
 - a :class:`HistoryWriter` samples a configurable **allowlist** of
   derived series (sync lag, observed files/s, interactive p99,
@@ -27,8 +26,8 @@ smallest durable answer:
   the durable segments.
 
 Reading is process-independent: :func:`read` merges segments in time
-order, so ``sdx slo`` and ``tools/bench_compare.py`` can gate against a
-node's history from outside the node process — and a node restarted on
+order, so ``sdx slo`` can gate against a node's history from outside
+the node process — and a node restarted on
 the same data dir continues the same series.
 """
 
@@ -116,9 +115,8 @@ def default_samplers() -> dict[str, Callable[[], float]]:
 
     samplers: dict[str, Callable[[], float]] = {
         # cumulative top-frame-group shares from the host profiler —
-        # the continuous record bench_compare gates attribution drift
-        # against (a pass whose sql share doubles week-over-week fails
-        # even if no bench round ran in between)
+        # the continuous record of attribution drift (a pass whose sql
+        # share doubles week-over-week shows here)
         f"profile_share_{g}": profile_share(g)
         for g in _sampler.HISTORY_GROUPS
     }
@@ -478,8 +476,7 @@ def read(directory: str, *, since: float | None = None,
 
 def series(directory: str, name: str, *, since: float | None = None,
            until: float | None = None) -> list[tuple[float, float]]:
-    """One named series as (ts, value) pairs — the bench_compare read
-    path."""
+    """One named series as (ts, value) pairs."""
     out: list[tuple[float, float]] = []
     for rec in read(directory, since=since, until=until, names=(name,)):
         v = (rec.get("v") or {}).get(name)

@@ -7,8 +7,8 @@ registry.MAX_SERIES_PER_FAMILY for the backstop.
 
 Hot paths import these handles directly (module attribute access, no
 lookup or allocation per event); everything registers on the process
-default ``REGISTRY`` so /metrics, telemetry.snapshot, and bench.py all
-read the same series.
+default ``REGISTRY`` so /metrics and telemetry.snapshot read the same
+series.
 """
 
 from __future__ import annotations
@@ -221,22 +221,6 @@ JOB_PHASE_SECONDS = REGISTRY.histogram(
     "sd_job_phase_seconds",
     "wall time per job phase (phase transitions via ctx.progress)",
     labels=("job", "phase"),
-)
-
-# --- bench (bench.py) -------------------------------------------------------
-
-# bench reads its median/spread back out of these rings, so they must
-# hold every sample of the largest plausible SD_BENCH_REPEATS run —
-# the default 128-sample ring would silently truncate repeats > 128
-BENCH_DEVICE_BATCH_SECONDS = REGISTRY.histogram(
-    "sd_bench_device_batch_seconds",
-    "marginal device compute per chained batch (bench.py)",
-    recent_samples=4096,
-)
-BENCH_E2E_BATCH_SECONDS = REGISTRY.histogram(
-    "sd_bench_e2e_batch_seconds",
-    "end-to-end host→device→digest time per batch (bench.py)",
-    recent_samples=4096,
 )
 
 # --- multi-device dp dispatch (ops/blake3_jax.py + ops/thumbnail_jax.py) ----
@@ -564,7 +548,7 @@ RING_DROPPED = REGISTRY.counter(
 ATTRIB_REPORTS = REGISTRY.counter(
     "sd_attrib_reports_total",
     "critical-path attribution reports computed (GET /attrib, rspc "
-    "telemetry.attrib, sdx attrib, bench_e2e summaries)",
+    "telemetry.attrib, sdx attrib)",
 )
 ATTRIB_BUCKET_SECONDS = REGISTRY.gauge(
     "sd_attrib_bucket_seconds",

@@ -15,7 +15,7 @@ process:
   series instead of growing memory without bound — a hot path must
   never be able to DoS its own telemetry;
 - histograms keep a small ring of raw observations (``recent()``) so
-  in-process consumers (bench.py, telemetry.snapshot) can compute
+  in-process consumers (health, history, telemetry.snapshot) can compute
   medians/spreads from the same source the /metrics endpoint scrapes —
   one set of numbers, two read paths;
 - unlabeled counters/gauges materialize their default series at
@@ -198,8 +198,8 @@ class Histogram(_Family):
             s.recent.append(v)
 
     def recent(self, **labels: Any) -> list[float]:
-        """Raw recent observations — the in-process read path bench.py
-        and telemetry.snapshot share with the scrape endpoint."""
+        """Raw recent observations — the in-process read path
+        telemetry.snapshot shares with the scrape endpoint."""
         with self._lock:
             s = self._peek(labels)
             return list(s.recent) if s is not None else []

@@ -2,10 +2,10 @@
 
 PR 12's attribution engine ends the wall-clock story at an anonymous
 bucket: the *unattributed gap*, the per-entry Python orchestration no
-span covers (the GIL signature that also explains BENCH_E2E
-``config_mesh``'s 0.12 scaling efficiency). The reference's execution
-layer is a multi-threaded Rust task system whose contention any native
-profiler can see; our Python mirror had no host-side profiler at all.
+span covers (the GIL signature that also explains why two in-process
+nodes scale poorly). The reference's execution layer is a
+multi-threaded Rust task system whose contention any native profiler
+can see; our Python mirror had no host-side profiler at all.
 This module is that instrument, stdlib-only:
 
 - a daemon thread walks ``sys._current_frames()`` at ``SD_PROFILE_HZ``

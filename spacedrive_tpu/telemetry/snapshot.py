@@ -1,9 +1,8 @@
 """telemetry.snapshot — the JSON read path.
 
 The same registry the /metrics endpoint scrapes, shaped for rspc
-consumers (the explorer's diagnostics pane) and for bench.py, which
-builds its reported JSON from here so the benchmark and the live
-system can never disagree about what was measured.
+consumers (the explorer's diagnostics pane) and for in-process readers
+(health verdicts, history samplers).
 """
 
 from __future__ import annotations
@@ -23,7 +22,7 @@ def snapshot() -> dict[str, Any]:
 
 def histogram_recent(name: str, **labels: Any) -> list[float]:
     """Raw recent observations of a histogram series ([] when the
-    metric is unknown) — bench.py's median/spread source."""
+    metric is unknown)."""
     fam = REGISTRY.get(name)
     if fam is None or not hasattr(fam, "recent"):
         return []

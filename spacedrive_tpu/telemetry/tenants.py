@@ -15,8 +15,7 @@ count it inherited on eviction-replacement), plus a single aggregated
 ``other`` bucket for everything that never earned residency. Resident
 counts are exact for tenants that were never evicted (``err == 0``) —
 under zipf-shaped load the true top-K land there with high
-probability, which the multi-tenant ``bench_serve`` leg measures as
-top-K **recall vs an exact oracle** (gated ≥ 0.9).
+probability.
 
 Tenant keys are NEVER raw identifiers: :func:`tenant_label` is the
 ``peers.peer_label`` discipline (blake2b, 8 hex chars) applied to

@@ -589,8 +589,11 @@ def test_keys_unlock_wrong_password_retry_keeps_vault_intact(tmp_path):
             lid = str(lib.id)
             await r.exec(node, "keys.unlock", {"password": "hunter2"},
                          library_id=lid)
-            await r.exec(node, "keys.add", {"automount": True},
-                         library_id=lid)
+            added = await r.exec(node, "keys.add", {"automount": True},
+                                 library_id=lid)
+            # keys.add stores the flag and mounts nothing (a key
+            # automounts at keys.unlock): mount it as a user would
+            await r.exec(node, "keys.mount", added["uuid"], library_id=lid)
             st = await r.exec(node, "keys.state", None, library_id=lid)
             assert st["unlocked"] and st["keys"][0]["mounted"]
 

@@ -313,45 +313,6 @@ def test_cross_node_assembly_degrades_on_peer_vanish(tmp_path):
     assert telemetry.counter_value("sd_attrib_pull_failures_total") >= 1
 
 
-# --- bench gate: per-config attribution summary ----------------------------
-
-
-def test_bench_compare_gates_attrib_bucket_regression():
-    """A bucket absorbing >15% more time per file fails bench-check
-    like any rate regression; sub-floor buckets are noise."""
-    from tools.bench_compare import compare_e2e
-
-    old = {"config1": {
-        "device_files_per_s": 1000.0,
-        "attrib": {"host_cpu_s_per_kfile": 2.0, "gap_s_per_kfile": 1.0,
-                   "link_s_per_kfile": 0.01, "coverage": 0.97},
-    }}
-
-    def variant(**attrib):
-        merged = dict(old["config1"]["attrib"], **attrib)
-        return {"config1": {"device_files_per_s": 1000.0,
-                            "attrib": merged}}
-
-    assert compare_e2e(old, variant())["regressions"] == []
-    # within threshold: clean
-    ok = compare_e2e(old, variant(host_cpu_s_per_kfile=2.2))
-    assert ok["regressions"] == []
-    # past threshold: fails, named by config + bucket
-    bad = compare_e2e(old, variant(host_cpu_s_per_kfile=3.0))
-    assert [r["name"] for r in bad["regressions"]] == [
-        "config1.attrib.host_cpu_s_per_kfile"]
-    # an IMPROVING bucket never regresses
-    assert compare_e2e(
-        old, variant(host_cpu_s_per_kfile=1.0))["regressions"] == []
-    # sub-floor noise both sides: not gated at all
-    noise = compare_e2e(old, variant(link_s_per_kfile=0.02))
-    assert not any("link" in r["name"] for r in noise["regressions"])
-    # a bucket appearing from (near) nothing gates absolutely
-    appeared = compare_e2e(old, variant(link_s_per_kfile=1.5))
-    assert [r["name"] for r in appeared["regressions"]] == [
-        "config1.attrib.link_s_per_kfile"]
-
-
 def test_assemble_caches_only_settled_complete_reports():
     """Review fix: a mid-pass or partial assembly must NOT freeze in
     the report cache — only a settled pass's complete report is

@@ -21,7 +21,6 @@ def test_parser_covers_commands():
         ["status"],
         ["browse", "/x"],
         ["duplicates"],
-        ["bench"],
         ["peers"],
         ["pair", "someidentity"],
         ["spacedrop", "someidentity", "/tmp/f"],

@@ -12,8 +12,7 @@ Covers the PR-7 acceptance surface:
   cold-resume (no vouch for an unstored thumb);
 - duplicates/orphan-remover consult the journal (phash reuse, orphan
   pruning);
-- the watcher's targeted invalidations (stale / rename / delete);
-- bench_compare's BENCH_E2E warm-pass gating.
+- the watcher's targeted invalidations (stale / rename / delete).
 """
 
 import asyncio
@@ -836,25 +835,3 @@ async def test_touch_storm_widens_content_storm_does_not(tmp_path, monkeypatch):
         entry.flush_handle.cancel()
     await mgr.system.shutdown()
     library.close()
-
-
-# --- bench_compare: BENCH_E2E warm-pass gating -----------------------------
-
-
-def test_bench_compare_gates_warm_regression():
-    from tools.bench_compare import compare_e2e
-
-    old = {"config_warm": {"warm_files_per_s": 1000.0,
-                           "journal_hit_rate": 0.99}}
-    new_ok = {"config_warm": {"warm_files_per_s": 950.0,
-                              "journal_hit_rate": 0.99}}
-    new_bad = {"config_warm": {"warm_files_per_s": 500.0,
-                               "journal_hit_rate": 0.99}}
-    assert compare_e2e(old, new_ok)["regressions"] == []
-    regs = compare_e2e(old, new_bad)["regressions"]
-    assert [r["name"] for r in regs] == ["config_warm.warm_files_per_s"]
-    # hit-rate regressions gate too
-    new_rate = {"config_warm": {"warm_files_per_s": 1000.0,
-                                "journal_hit_rate": 0.5}}
-    regs = compare_e2e(old, new_rate)["regressions"]
-    assert [r["name"] for r in regs] == ["config_warm.journal_hit_rate"]

@@ -18,7 +18,6 @@ short-hash.
 
 import asyncio
 import json
-import os
 import shutil
 import time
 import uuid
@@ -306,88 +305,6 @@ def test_federation_cache_staleness_rules():
     assert m["stale"] is False and m["transport"] == "p2p"
 
 
-# --- bench gate (satellite: tools/bench_compare.py) ------------------------
-
-
-def _bench_doc(metric, value, extras=None):
-    return {"parsed": {"metric": metric, "value": value,
-                       "extras": extras or {}}}
-
-
-def test_bench_compare_gates_regressions():
-    from tools.bench_compare import compare
-
-    old = _bench_doc("cas_id_e2e_throughput", 100.0,
-                     {"device_compute_files_per_s": 1000.0})
-    bad = _bench_doc("cas_id_e2e_throughput", 80.0,
-                     {"device_compute_files_per_s": 1000.0})
-    res = compare(old, bad, 0.15)
-    assert [r["name"] for r in res["regressions"]] == ["cas_id_e2e_throughput"]
-
-    ok = _bench_doc("cas_id_e2e_throughput", 90.0,
-                    {"device_compute_files_per_s": 940.0})
-    assert compare(old, ok, 0.15)["regressions"] == []
-
-    # renamed headline metric: incomparable, never a 98% "regression"
-    renamed = _bench_doc("cas_id_blake3_throughput", 2.0)
-    res = compare(old, renamed, 0.15)
-    assert res["regressions"] == []
-    assert any("absent in newer run" in s for s in res["skipped"])
-
-
-def test_bench_compare_e2e_warm_and_mesh_series():
-    """Journal-/host-bound configs (config_warm, config_mesh) gate on
-    their headline rates AND their cold-leg rates — nothing about a run
-    excuses a series — and the mesh scaling series is comparable."""
-    from tools.bench_compare import compare_e2e
-
-    warm = {
-        "warm_files_per_s": 300.0, "cold_files_per_s": 100.0,
-        "warm_speedup_vs_cold": 10.0, "journal_hit_rate": 0.99,
-    }
-    old = {"config_warm": dict(warm),
-           "config_mesh": {"mesh1_files_per_s": 300.0,
-                           "mesh2_files_per_s": 450.0,
-                           "scaling_efficiency": 0.75}}
-    bad = {"config_warm": dict(warm, warm_files_per_s=100.0,
-                               cold_files_per_s=50.0),
-           "config_mesh": dict(old["config_mesh"])}
-    res = compare_e2e(old, bad, 0.15)
-    names = [r["name"] for r in res["regressions"]]
-    assert "config_warm.warm_files_per_s" in names
-    assert "config_warm.cold_files_per_s" in names
-
-    # mesh scaling regressions are first-class comparable series
-    slow_mesh = {"config_warm": dict(warm),
-                 "config_mesh": {"mesh1_files_per_s": 300.0,
-                                 "mesh2_files_per_s": 200.0,
-                                 "scaling_efficiency": 0.33}}
-    res = compare_e2e(old, slow_mesh, 0.15)
-    names = [r["name"] for r in res["regressions"]]
-    assert "config_mesh.mesh2_files_per_s" in names
-    assert "config_mesh.scaling_efficiency" in names
-
-
-def test_bench_compare_cli_gates_two_rounds(tmp_path):
-    """The CLI diffs the two newest BENCH_r*.json rounds in --dir and
-    exits 1 on a >15% drop of a same-named headline rate."""
-    import json
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for name, value in (("BENCH_r01.json", 100.0), ("BENCH_r02.json", 79.0)):
-        (tmp_path / name).write_text(
-            json.dumps(_bench_doc("cas_id_e2e_throughput", value)))
-    rc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_compare.py"),
-         "--dir", str(tmp_path)],
-        capture_output=True, text=True,
-    )
-    assert rc.returncode == 1, rc.stdout + rc.stderr
-    assert "REGRESSION" in rc.stdout
-
-
 # --- cloud-relay federation fallback ---------------------------------------
 
 
@@ -447,8 +364,8 @@ async def test_telemetry_header_roundtrip():
 
 
 # the in-process duplex + two-node pair now live in the production
-# harness module (p2p/loopback.py) so the mesh-parallel index tests and
-# bench_e2e's config_mesh drive the SAME transport as this suite
+# harness module (p2p/loopback.py) so the mesh-parallel index tests
+# drive the SAME transport as this suite
 from spacedrive_tpu.p2p.loopback import (  # noqa: E402
     DuplexEnd as _DuplexEnd,
     Pipe as _Pipe,

@@ -278,7 +278,7 @@ def test_reset_clears_sampler_state(monkeypatch):
         telemetry.reset()
 
 
-# --- history + bench_compare integration -----------------------------------
+# --- history integration ---------------------------------------------------
 
 
 def test_history_samplers_include_profile_shares():
@@ -291,64 +291,6 @@ def test_history_samplers_include_profile_shares():
         assert name in samplers
         v = samplers[name]()
         assert 0.0 <= v <= 1.0
-
-
-def test_bench_compare_gates_gap_group_regression():
-    from tools.bench_compare import compare_e2e
-
-    def doc(gap_sql):
-        return {"config1": {
-            "files_per_s": 100.0,
-            "attrib": {
-                "gap_s_per_kfile": 5.0,
-                "gap_sql_s_per_kfile": gap_sql,
-            },
-        }}
-
-    res = compare_e2e(doc(2.0), doc(4.0))
-    names = [r["name"] for r in res["regressions"]]
-    assert "config1.attrib.gap_sql_s_per_kfile" in names
-    # a group absent on ONE side is top-5 truncation churn or a
-    # profiler-off run, not perf — skipped, while the TOTAL gap bucket
-    # still gates unconditionally
-    res2 = compare_e2e(
-        {"config1": {"files_per_s": 100.0,
-                     "attrib": {"gap_s_per_kfile": 5.0}}},
-        doc(3.0),
-    )
-    names2 = [r["name"] for r in res2["regressions"]]
-    assert "config1.attrib.gap_sql_s_per_kfile" not in names2
-    # gap_other growth is classifier coverage, not perf — exempt
-    def doc_other(v):
-        return {"config1": {"files_per_s": 100.0, "attrib": {
-            "gap_s_per_kfile": 5.0, "gap_other_s_per_kfile": v}}}
-
-    res_other = compare_e2e(doc_other(1.0), doc_other(4.0))
-    assert not res_other["regressions"]
-    # improvement (group shrinking / vanishing) never fails
-    res3 = compare_e2e(doc(4.0), doc(2.0))
-    assert not res3["regressions"]
-
-
-def test_bench_e2e_attrib_summary_carries_gap_groups():
-    from bench_e2e import attrib_summary
-
-    raw = {
-        "buckets": {"gap": 3.0, "host_cpu": 1.0, "device": 0.5,
-                    "link": 0.2, "queue_wait": 0.1},
-        "wall_seconds": 4.8,
-        "gap_decomposition": {
-            "samples": 100, "coverage": 0.85,
-            "groups": {"sql": 1.5, "journal": 0.9, "msgpack": 0.3,
-                       "linking": 0.2, "decode": 0.05, "other": 0.05},
-        },
-    }
-    out = attrib_summary(raw, items=1000, wall_s=5.0)
-    assert out["gap_sql_s_per_kfile"] == pytest.approx(1.5)
-    assert out["gap_journal_s_per_kfile"] == pytest.approx(0.9)
-    assert out["gap_decomposed_coverage"] == 0.85
-    # top-5 only: the sixth group stays out of the gated surface
-    assert "gap_other_s_per_kfile" not in out
 
 
 def test_gap_bucket_decomposes_into_named_groups(monkeypatch):

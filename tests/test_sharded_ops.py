@@ -3,8 +3,7 @@ indexing hot paths (ISSUE 4 acceptance: forced-8-device cas_id and
 thumbnail outputs bit-identical to single-device and CPU reference).
 
 conftest.py forces an 8-device virtual CPU platform before jax loads,
-so every test here exercises the REAL shard_map programs with no TPU —
-`make bench-devices` runs this file as its smoke leg.
+so every test here exercises the REAL shard_map programs with no TPU.
 """
 
 import numpy as np

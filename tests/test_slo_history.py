@@ -6,8 +6,8 @@ The acceptance bars proven here:
 
 - history **survives restart**: a writer samples into a data dir, a
   second writer (a new node generation) continues the same series, and
-  the offline readers (``sdx slo``, ``tools/bench_compare.py``) see one
-  continuous series across the boundary;
+  the offline reader (``sdx slo``) sees one continuous series across
+  the boundary;
 - a **sustained injected SLO violation** flips the ``slo`` health
   subsystem, and — because health rides every federation snapshot — a
   peer's ``GET /mesh`` shows it with zero new wire surface.
@@ -251,34 +251,6 @@ def test_sdx_slo_reads_history_offline(tmp_path, capsys):
     assert by_name["sync_lag"]["status"] == slo.BREACH
     # the evaluation window saw BOTH generations' samples
     assert by_name["sync_lag"]["windows"]["fast"]["samples"] == 12
-
-
-def test_bench_compare_history_gate(tmp_path):
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from tools.bench_compare import check_history
-
-    w = _writer(tmp_path, samplers=None)
-    now = time.time()
-    # a healthy run then a regressed tail: 100 f/s → 60 f/s
-    w._samplers = _fixed_samplers({"files_per_s": 100.0})
-    for i in range(40):
-        w.sample(now=now - 60 + i)
-    w._samplers = _fixed_samplers({"files_per_s": 60.0})
-    for i in range(10):
-        w.sample(now=now - 10 + i)
-    result = check_history(w.dir)
-    assert result["regressions"], result
-    assert result["regressions"][0]["name"] == "history.files_per_s"
-    # flat history gates clean
-    w2 = _writer(os.path.join(tmp_path, "flat"),
-                 samplers=_fixed_samplers({"files_per_s": 100.0}))
-    for i in range(50):
-        w2.sample(now=now - 50 + i)
-    result = check_history(w2.dir)
-    assert not result["regressions"]
-    assert result["checked"]
 
 
 # --- the health subsystem + federation visibility --------------------------

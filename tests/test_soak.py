@@ -7,9 +7,8 @@ The acceptance bars proven here:
   accelerated sampler/history cadence, warmup-scaled trend bars) runs
   end-to-end through the real planes and passes its own verdict: zero
   trend breaches, zero protected sheds, bounded fd/RSS drift, a
-  schema-valid BENCH_SCALE.json that ``bench_compare.check_scale``
-  gates clean — and the journal row inventory tracks CORPUS SIZE, not
-  pass count;
+  schema-valid BENCH_SCALE.json — and the journal row inventory tracks
+  CORPUS SIZE, not pass count;
 - **journal prune at 10⁵ rows** runs in bounded batches with event-loop
   yields between them (the heartbeat keeps beating), deletes exactly
   the orphans, and keeps the vouched rows;
@@ -77,20 +76,11 @@ def test_mini_soak_end_to_end(tmp_path, monkeypatch):
         "touch", "rename", "reindex", "reads", "orphan"}
     assert all(n > 0 for n in doc["scenarios"].values())
 
-    # the artifact on disk is the same schema-valid document, and the
-    # offline gate re-derives the same verdict
+    # the artifact on disk is the same schema-valid document
     with open(out) as f:
         on_disk = json.load(f)
     assert on_disk["schema"] == doc["schema"]
     assert on_disk["verdict"] == doc["verdict"]
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from tools.bench_compare import check_scale
-
-    result = check_scale(on_disk)
-    assert not result["regressions"], result
-    assert not result["skipped"], result
 
 
 def test_corpus_and_deck_are_seed_deterministic(tmp_path):

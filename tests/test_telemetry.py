@@ -1,6 +1,6 @@
 """Telemetry subsystem: registry semantics, spans, Prometheus text,
 and the dispatch-path instrumentation populated by a real dry-run
-identify+thumbnail pass (BENCH_r05's missing observability layer)."""
+identify+thumbnail pass."""
 
 import asyncio
 import os

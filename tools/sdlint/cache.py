@@ -39,8 +39,7 @@ stale-baseline warnings on warm runs, and the baseline hygiene commands
 refuse ``--changed``; the next cold run restores the authoritative
 picture), and the closure of a widely-imported hub module approaches
 the whole tree — a hub edit costs near-cold, a leaf edit re-analyzes a
-handful of files, and the no-change run (the repeated ``bench-check``
-case) is near-free.
+handful of files, and the no-change run is near-free.
 
 Invalidation is content-addressed twice over: each file by the hash of
 its bytes, and the whole manifest by a *salt* hashing the linter's own

@@ -18,10 +18,10 @@ tree.
 The catalog (default ``docs/telemetry.md``, override with
 ``SDLINT_KNOB_CATALOG`` for fixtures) is a markdown table whose first
 cell backticks the knob name. A row whose SECOND cell is ``script``
-documents a knob read by the repo-root bench/CI scripts *outside* the
-linted package (``bench.py``, ``bench_e2e.py``, …) — those stay
-cataloged for operators without tripping the stale-row check, since
-the analyzer never parses them.
+documents a knob read by a repo-root script *outside* the linted
+package (``bench_scale.py``) — those stay cataloged for operators
+without tripping the stale-row check, since the analyzer never parses
+them.
 """
 
 from __future__ import annotations
@@ -167,7 +167,7 @@ def check_env_knob_catalog(project: ProjectContext) -> Iterator[Finding]:
             )
     for name, scope, line_no, raw in rows:
         if scope == "script":
-            # documented repo-root-script knob (bench.py & co live
+            # documented repo-root-script knob (bench_scale.py lives
             # outside the analyzed package) — cataloged on purpose
             continue
         if name not in read:
