@@ -136,42 +136,62 @@ def forward(p: dict[str, Any], images):
     return (h @ p["w2"] + p["b2"]).astype(jnp.float32)
 
 
-def decode_image(path: str, image_size: int = IMAGE_SIZE) -> np.ndarray | None:
-    """Decode one image to the embedder's input plane, at the scale the
-    plane needs: a JPEG is DCT-scaled by the largest of 1/2, 1/4, 1/8
-    that leaves both sides at least 8 source pixels per output pixel
-    (under 512 px on a side: none), and whatever PIL opens goes straight
-    to RGB and through one bicubic resize. The stored pixels, no EXIF
-    orientation. HEIF, SVG and PDF do not come from PIL and ride
-    `format_image`. Module-level so the procpool `embed.decode` stage,
-    the inline leg and query-by-image run the EXACT same code path;
-    None = undecodable or over `MAXIMUM_FILE_SIZE`."""
+def plane_from_frame(frame: Any, scale: int = 1) -> np.ndarray:
+    """A decoded frame → the embedder's `uint8` [S, S, 3] plane: RGB
+    (an alpha band dropped as `convert("RGB")` drops it), one PIL
+    default resize, the stored pixels with no EXIF orientation. The one
+    definition of the plane: `decode_image` hands it the opened PIL
+    image, the thumbnailer's tap (object/media/job.py) the RGB/RGBA
+    frame it decoded for the thumbnail (a PIL image, or libheif's
+    `uint8` array), and because both asked `images.draft_jpeg` for the
+    frame, the plane is a function of the file's bytes alone. Counts
+    the plane in `sd_embed_decode_total` by the DCT `scale` its frame
+    was decoded at, in the process that made it."""
+    from PIL import Image
+
+    from ..telemetry import metrics as _tm
+
+    img = frame if isinstance(frame, Image.Image) else Image.fromarray(frame)
+    if img.mode != "RGB":
+        img = img.convert("RGB")
+    plane = np.asarray(img.resize((IMAGE_SIZE, IMAGE_SIZE)))
+    _tm.EMBED_DECODE.inc(scale="8" if scale >= 8 else "4" if scale >= 4
+                         else "2" if scale >= 2 else "1")
+    return plane
+
+
+def input_plane(plane: np.ndarray) -> np.ndarray:
+    """`plane_from_frame`'s `uint8` plane → the forward's f32 in [0, 1]."""
+    return plane.astype(np.float32) / 255.0
+
+
+def decode_image(path: str) -> np.ndarray | None:
+    """Decode one image file to the embedder's input plane: a JPEG at
+    the DCT scale `images.draft_jpeg` asks for (the thumbnailer's own
+    request, so a 12 MP photo decodes at 1/4), everything else PIL
+    opens as it is, then `plane_from_frame`. HEIF, SVG and PDF do not
+    come from PIL and ride `format_image`. Module-level so the procpool
+    `embed.decode` stage, the inline leg and query-by-image run the
+    EXACT same code path, and the media job's planes from the
+    thumbnailer's frames are the same bits; None = undecodable or over
+    `MAXIMUM_FILE_SIZE`."""
     from PIL import Image
 
     from ..object.media import images
-    from ..telemetry import metrics as _tm
 
-    shrink = 1.0
     try:
         ext = os.path.splitext(path)[1].lstrip(".").lower()
         if ext in (images.HEIF_EXTENSIONS | images.SVG_EXTENSIONS
                    | images.PDF_EXTENSIONS):
-            rgb = Image.fromarray(images.format_image(path)).convert("RGB")
+            plane = plane_from_frame(images.format_image(path))
         else:
             if os.path.getsize(path) > images.MAXIMUM_FILE_SIZE:
                 return None
             with Image.open(path) as img:
-                if img.format == "JPEG":
-                    width = img.size[0]
-                    img.draft("RGB", (8 * image_size, 8 * image_size))
-                    shrink = width / img.size[0]
-                rgb = img.convert("RGB")
-        plane = np.asarray(rgb.resize((image_size, image_size)), np.float32)
+                plane = plane_from_frame(img, images.draft_jpeg(img))
     except Exception:  # noqa: BLE001 - undecodable → caller skips
         return None
-    _tm.EMBED_DECODE.inc(scale="8" if shrink > 6 else "4" if shrink > 3
-                         else "2" if shrink > 1.5 else "1")
-    return plane / 255.0
+    return input_plane(plane)
 
 
 def vector_to_blob(vec: np.ndarray) -> bytes:

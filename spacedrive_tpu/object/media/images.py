@@ -22,6 +22,11 @@ import numpy as np
 MAXIMUM_FILE_SIZE = 192 * 1024 * 1024  # ref:consts.rs:9
 SVG_RENDER_SIZE = 512  # ref:consts.rs:33 (SVG render cap 512²)
 PDF_RENDER_WIDTH = 1024  # ref:consts.rs:39
+# A side of the frame a JPEG is decoded to is never asked under this:
+# 8 source pixels per pixel of the embedder's 32 x 32 plane
+# (8 * models/embedder.IMAGE_SIZE; tests/test_decode_once.py holds the
+# two together), so one decoded frame serves thumbnail and plane.
+PLANE_SOURCE_SIDE = 256
 
 HEIF_EXTENSIONS = {"heif", "heifs", "heic", "heics", "avif", "avci", "avcs"}
 SVG_EXTENSIONS = {"svg", "svgz"}
@@ -218,3 +223,23 @@ def format_image(path: str, extension: str | None = None) -> np.ndarray:
     if ext in PDF_EXTENSIONS:
         return decode_pdf(path)
     return decode_generic(path)
+
+
+def draft_jpeg(img) -> int:
+    """The one DCT-scale request every decode of a JPEG makes, the
+    thumbnailer's and the embedder's alike, so both hold the same
+    frame whoever opened the file: the smallest of 1/1, 1/2, 1/4, 1/8
+    that still covers the thumbnail's target (`scale_dimensions`) and
+    leaves `PLANE_SOURCE_SIDE` pixels a side for the embedder's plane.
+    `img` is a just-opened PIL image; → the scale applied (1 for what
+    is not a JPEG, or too small to scale)."""
+    if img.format != "JPEG":
+        return 1
+    from ...ops.thumbnail_jax import scale_dimensions
+
+    width, height = img.size
+    tw, th = scale_dimensions(width, height)
+    img.draft("RGB", (max(tw, PLANE_SOURCE_SIDE), max(th, PLANE_SOURCE_SIDE)))
+    # draft leaves ceil(side / scale); with 256 px or more a side left,
+    # the ratio rounds to the scale itself
+    return round(width / img.size[0])

@@ -9,8 +9,10 @@ rendezvous steps (:83-88, :199-230).
 
 from __future__ import annotations
 
+import collections
 import logging
 import os
+import threading
 from typing import Any
 
 import hashlib
@@ -54,6 +56,56 @@ EXIF_EXTENSIONS = ("jpg", "jpeg", "png", "tiff", "webp")
 MEDIA_DATA_EXTENSIONS = EXIF_EXTENSIONS + tuple(VIDEO_EXTENSIONS)
 
 
+class _EmbedPlanes:
+    """The embedder's input planes made from the frames the thumbnailer
+    decoded for this job's batch, so the embed step does not open the
+    files again. `offer` is the batch's sink (thumbnail/actor.py
+    FrameSink, called on the decode worker threads); `take` is the
+    embed step's. Keyed by cas_id, `uint8` as the resize gives them
+    (3,072 B an image), and only for rows the job will embed, each kept
+    until the last of them has taken it (copies share a cas_id and so a
+    plane). In memory only, never in the job's serialised state, empty
+    once `close`d: at most 3,072 B x the job's embeddable rows, 300 MB
+    at 100,000 photos."""
+
+    def __init__(self, cas_ids: list[str]):
+        self._lock = threading.Lock()
+        self._uses = collections.Counter(cas_ids)  # takes still to come
+        self._claimed: set[str] = set()  # a plane is made or being made
+        self._planes: dict[str, Any] = {}
+        self._closed = False
+
+    def __len__(self) -> int:
+        return len(self._planes)
+
+    def offer(self, cas_id: str, frame: Any, scale: int) -> None:
+        from ...models import embedder as _embedder
+
+        with self._lock:
+            if (self._closed or cas_id in self._claimed
+                    or self._uses[cas_id] <= 0):
+                return
+            self._claimed.add(cas_id)
+        plane = _embedder.plane_from_frame(frame, scale)
+        with self._lock:
+            if not self._closed:
+                self._planes[cas_id] = plane
+
+    def take(self, cas_id: str) -> Any:
+        """The `uint8` plane for one row, or None where none arrived."""
+        with self._lock:
+            plane = self._planes.get(cas_id)
+            self._uses[cas_id] -= 1
+            if self._uses[cas_id] <= 0:
+                self._planes.pop(cas_id, None)
+            return plane
+
+    def close(self) -> None:
+        with self._lock:
+            self._closed = True
+            self._planes.clear()
+
+
 @register_job
 class MediaProcessorJob(StatefulJob):
     """init: {location_id, sub_path?, backend?}"""
@@ -61,6 +113,15 @@ class MediaProcessorJob(StatefulJob):
     NAME = "media_processor"
     INVALIDATES = ("search.paths", "labels.list", "search.semantic")
     IS_BATCHED = True
+
+    # planes from the thumbnailer's frames (`_EmbedPlanes`); a job that
+    # was resumed from its serialised state has none and decodes
+    _planes: _EmbedPlanes | None = None
+
+    def cleanup(self) -> None:
+        if self._planes is not None:
+            self._planes.close()
+            self._planes = None
 
     async def init_job(self, ctx: JobContext) -> None:
         async with span("media.init"):
@@ -121,6 +182,31 @@ class MediaProcessorJob(StatefulJob):
 
         vouched = await asyncio.to_thread(consult_all)
 
+        # semantic embedding stage (SD_EMBED=0 ⇒ a true no-op: no
+        # steps, no DB writes, no sync ops — today's pipeline exactly).
+        # Its rows are chosen before the thumbnails are dispatched: the
+        # batch's decode stage makes the planes of exactly these.
+        from ...models import embedder as _embedder
+
+        embed_rows = []
+        if _embedder.enabled():
+            from ...telemetry import metrics as _tm
+
+            for r in rows:
+                if (r["extension"] or "").lower() not in IMAGE_EXTENSIONS:
+                    continue
+                entry = vouched[r["id"]]
+                if entry is not None and entry.embed:
+                    # journal vouched: unchanged bytes are never
+                    # re-read, never re-embedded
+                    journal.bytes_saved(
+                        blob_u64(r["size_in_bytes_bytes"]) or 0,
+                        location_id=loc_id,
+                    )
+                    _tm.EMBED_FILES.inc(result="skipped")
+                    continue
+                embed_rows.append(r)
+
         # dispatch remaining thumbnails up-front to the node thumbnailer
         # actor (ref:job.rs:148-156); the job only awaits counts later.
         thumbnailer = getattr(getattr(library, "node", None), "thumbnailer", None)
@@ -142,8 +228,13 @@ class MediaProcessorJob(StatefulJob):
                     [*_journal.key_of(r), r["cas_id"]]
                 )
             if batch:
+                sink = None
+                if embed_rows:
+                    self._planes = _EmbedPlanes(
+                        [r["cas_id"] for r in embed_rows])
+                    sink = self._planes.offer
                 thumb_batch_id = thumbnailer.new_indexed_thumbnails_batch(
-                    library.id, batch, background=False
+                    library.id, batch, background=False, sink=sink
                 )
             dispatched = len(batch)
         self.data["thumbs_dispatched"] = dispatched
@@ -180,30 +271,10 @@ class MediaProcessorJob(StatefulJob):
                     "vouch": thumb_vouch,
                 }
             )
-        # semantic embedding stage (SD_EMBED=0 ⇒ a true no-op: no
-        # steps, no DB writes, no sync ops — today's pipeline exactly)
-        from ...models import embedder as _embedder
-
-        if _embedder.enabled():
+        if embed_rows:
             from ...parallel import autotune as _autotune
             from ...parallel import mesh as _mesh
-            from ...telemetry import metrics as _tm
 
-            embed_rows = []
-            for r in rows:
-                if (r["extension"] or "").lower() not in IMAGE_EXTENSIONS:
-                    continue
-                entry = vouched[r["id"]]
-                if entry is not None and entry.embed:
-                    # journal vouched: unchanged bytes are never
-                    # re-read, never re-embedded
-                    journal.bytes_saved(
-                        blob_u64(r["size_in_bytes_bytes"]) or 0,
-                        location_id=loc_id,
-                    )
-                    _tm.EMBED_FILES.inc(result="skipped")
-                    continue
-                embed_rows.append(r)
             chunk_rows = _autotune.policy("embed").embed_chunk_rows(
                 _mesh.accelerator_count()
             )
@@ -302,9 +373,11 @@ class MediaProcessorJob(StatefulJob):
         )
 
     def _embed_files(self, ctx: JobContext, step: dict) -> StepResult:
-        """One embedding chunk: decode (procpool leg when the pool is
-        up, inline otherwise — the EXACT same decode_image body either
-        way) → one padded device forward (ops/embed_jax, DeviceLadder
+        """One embedding chunk: the planes (the thumbnailer's frames
+        gave them where this job's batch decoded the file; what is left
+        is decoded here, procpool leg when the pool is up, inline
+        otherwise — the same plane bit for bit whichever made it) →
+        one padded device forward (ops/embed_jax, DeviceLadder
         demotion inside) → object_embedding rows + their CRDT ops in
         ONE transaction via sync.write_ops, so the vectors replicate
         live like any other shared model. Journal vouches are written
@@ -333,7 +406,7 @@ class MediaProcessorJob(StatefulJob):
             return StepResult()
 
         with span("embed.decode") as stage:
-            planes = self._decode_for_embed([p for _, _, p in items])
+            planes = self._planes_for_embed(items)
         _tm.EMBED_STAGE_SECONDS.observe(stage.duration, stage="decode")
 
         batch_rows: list[tuple[dict, int]] = []
@@ -418,14 +491,39 @@ class MediaProcessorJob(StatefulJob):
             _search_index.refresh(library)
         return len(writes)
 
+    def _planes_for_embed(self, items: list[tuple[dict, int, str]]) -> list:
+        """One f32 plane (or None: undecodable) per (row, object_id,
+        path): taken from `_planes` where the thumbnailer's decode
+        stage made one, decoded by `_decode_for_embed` for the rest.
+        `sd_embed_planes_total{source}` counts the planes by origin."""
+        from ...models import embedder as _embedder
+        from ...telemetry import metrics as _tm
+
+        holder = self._planes
+        planes: list = []
+        for row, _object_id, _path in items:
+            shared = None if holder is None else holder.take(row["cas_id"])
+            planes.append(
+                None if shared is None else _embedder.input_plane(shared))
+        rest = [i for i, plane in enumerate(planes) if plane is None]
+        if rest:
+            own = self._decode_for_embed([items[i][2] for i in rest])
+            for i, plane in zip(rest, own):
+                planes[i] = plane
+            _tm.EMBED_PLANES.inc(
+                sum(plane is not None for plane in own), source="own")
+        if len(rest) < len(items):
+            _tm.EMBED_PLANES.inc(len(items) - len(rest), source="shared")
+        return planes
+
     def _decode_for_embed(self, paths: list[str]) -> list:
         """The embedding decode leg: pooled when the multi-process
         plane is up (stage `embed.decode` — SD022 keeps the payload
         msgpack-plain), inline fallback otherwise; both run
-        models/embedder.decode_image (a JPEG DCT-scaled to what the
-        32×32 plane needs, everything else straight to RGB) so the
-        planes are bit-identical. `sd_embed_decode_total` counts in the
-        process that decodes: a pooled decode counts in its worker."""
+        models/embedder.decode_image (a JPEG at `images.draft_jpeg`'s
+        DCT scale, everything else as PIL opens it) so the planes are
+        bit-identical. `sd_embed_decode_total` counts in the process
+        that decodes: a pooled decode counts in its worker."""
         import numpy as np
 
         from ...models import embedder as _embedder

@@ -19,12 +19,13 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import functools
 import itertools
 import logging
 import os
 import secrets
 import time
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 from ....parallel import autotune as _autotune
 from ....parallel import procpool as _procpool
@@ -54,6 +55,21 @@ GENERATION_TIMEOUT_S = 30  # ref:process.rs:172
 
 
 ThumbKey = tuple[str, str, str]  # (namespace, shard, cas_id)
+# What a batch's submitter may hang on the decode stage: called on the
+# decode worker thread, once per still image decoded, with the cas_id,
+# the frame (process.FrameTap's: a PIL image or a uint8 array, RGB or
+# RGBA) and the DCT scale it was decoded at.
+FrameSink = Callable[[str, Any, int], None]
+
+
+def _offer(sink: FrameSink, cas_id: str, frame: Any, scale: int) -> None:
+    """The tap `decode` calls for a batch that has a sink, inside the
+    decode stage's `_timed`: the sink's work is decode work. A sink
+    that fails costs its owner the frame, never the thumbnail."""
+    try:
+        sink(cas_id, frame, scale)
+    except Exception:  # noqa: BLE001 - the sink is a guest here
+        logger.exception("frame sink failed for %s", cas_id)
 
 
 class Thumbnailer:
@@ -196,17 +212,23 @@ class Thumbnailer:
         library_id: str,
         entries: Sequence[tuple[str, str] | tuple[str, str, str]],
         background: bool = False,
+        sink: FrameSink | None = None,
     ) -> int:
         """entries: (cas_id, path[, extension]); returns a batch id for
-        `wait_batch`, or 0 if nothing was queued."""
-        return self._enqueue(library_id, entries, background)
+        `wait_batch`, or 0 if nothing was queued. `sink` is offered
+        every still frame the batch decodes, so a submitter that needs
+        the pixels too (the media job's embed step) does not open the
+        file again. It lives with this process's batch only: a batch
+        reloaded after a restart, and the pooled software path, offer
+        nothing, and the submitter decodes for itself."""
+        return self._enqueue(library_id, entries, background, sink)
 
     def new_ephemeral_thumbnails_batch(
         self, entries: Sequence[tuple[str, str] | tuple[str, str, str]]
     ) -> int:
         return self._enqueue(None, entries, background=False)
 
-    def _enqueue(self, library_id, entries, background) -> int:
+    def _enqueue(self, library_id, entries, background, sink=None) -> int:
         library_id = str(library_id) if library_id is not None else None
         norm: list[tuple[str, str, str]] = []
         for e in entries:
@@ -225,7 +247,8 @@ class Thumbnailer:
             norm.append((cas_id, path, ext))
         if not norm:
             return 0
-        batch = Batch(library_id=library_id, entries=norm, background=background)
+        batch = Batch(library_id=library_id, entries=norm,
+                      background=background, sink=sink)
         batch.id = next(self._batch_ids)
         # the actor worker is a separate task: the batch carries the
         # enqueueing trace (media job, watcher, ephemeral walk) across
@@ -488,10 +511,14 @@ class Thumbnailer:
         async def _decode(entry: tuple[str, str, str],
                           work: list[float]) -> Decoded | None:
             cas_id, path, ext = entry
+            tap = None
+            if batch.sink is not None:
+                tap = functools.partial(_offer, batch.sink, cas_id)
             async with sem:
                 try:
                     return await asyncio.wait_for(
-                        asyncio.to_thread(_timed, work, decode, path, ext),
+                        asyncio.to_thread(
+                            _timed, work, decode, path, ext, tap),
                         timeout=GENERATION_TIMEOUT_S,
                     )
                 except (ThumbError, asyncio.TimeoutError, OSError) as e:

@@ -18,6 +18,7 @@ import logging
 import math
 import os
 from dataclasses import dataclass
+from typing import Any, Callable
 
 import numpy as np
 
@@ -55,7 +56,7 @@ _CV2_DECODABLE = {
     "mp4", "mov", "avi", "mkv", "webm", "m4v", "mpg", "mpeg", "mpe",
     "wmv", "flv", "3gp", "ogv", "mts", "m2ts", "m2v", "ts", "vob", "qt",
 }
-from ..images import HEIF_EXTENSIONS, format_image, heif_available
+from ..images import HEIF_EXTENSIONS, draft_jpeg, format_image, heif_available
 from ..svg import svg_available
 
 IMAGE_EXTENSIONS = tuple(
@@ -83,6 +84,15 @@ class ThumbError(Exception):
     pass
 
 
+# What a caller may hang on a still image's decode: called on the
+# decoding thread with the frame as decoded (RGB or RGBA, before
+# `shrink_to_max_dim`, EXIF orientation not applied: the PIL image
+# where PIL decoded it, so the caller reads the decoder's own buffer,
+# the uint8 array where libheif did) and the DCT scale it was decoded
+# at. Video and document frames are not offered.
+FrameTap = Callable[[Any, int], None]
+
+
 @dataclass
 class Decoded:
     """One decoded frame ready for the device batch."""
@@ -102,7 +112,7 @@ def is_video(extension: str | None) -> bool:
     return (extension or "").lower() in VIDEO_EXTENSIONS
 
 
-def decode_image(path: str) -> Decoded:
+def decode_image(path: str, tap: FrameTap | None = None) -> Decoded:
     """Decode a still image to RGB, or to RGBA where the file has an
     alpha band (or a palette's transparency), reading EXIF orientation.
 
@@ -110,6 +120,9 @@ def decode_image(path: str) -> Decoded:
     target size instead of full-res (the decode-side analogue of the
     reference's resize-after-full-decode; output parity is held by the
     device resample, which always produces `scale_dimensions` dims).
+    The request is `images.draft_jpeg`'s, the one every decode of a
+    JPEG makes, and `tap` sees the frame it gave, before the array is
+    made and `shrink_to_max_dim` thins it.
     """
     from PIL import Image
 
@@ -123,9 +136,10 @@ def decode_image(path: str) -> Decoded:
             orientation = int(img.getexif().get(0x0112, 1) or 1)
         except Exception:
             pass
-        if img.format == "JPEG":
-            img.draft("RGB", (tw, th))  # smallest DCT scale ≥ target
+        scale = draft_jpeg(img)  # smallest DCT scale ≥ target
         img = img.convert("RGBA" if img.has_transparency_data else "RGB")
+        if tap is not None:
+            tap(img, scale)
         arr = np.asarray(img)
     arr = shrink_to_max_dim(arr)
     h, w = arr.shape[:2]
@@ -197,10 +211,14 @@ def decode_video_frame(path: str) -> Decoded:
     return Decoded(array=np.ascontiguousarray(arr), target=(th, tw), is_video=True)
 
 
-def decode_heif_image(path: str, extension: str) -> Decoded:
+def decode_heif_image(path: str, extension: str,
+                      tap: FrameTap | None = None) -> Decoded:
     """HEIC/HEIF/AVIF through the libheif dispatch (ref:crates/images
     HEIF handler); orientation is baked in by libheif's transforms."""
-    arr = shrink_to_max_dim(format_image(path, extension))
+    arr = format_image(path, extension)
+    if tap is not None:
+        tap(arr, 1)
+    arr = shrink_to_max_dim(arr)
     h, w = arr.shape[:2]
     tw, th = tj.scale_dimensions(w, h)
     return Decoded(array=arr, target=(th, tw))
@@ -221,15 +239,16 @@ def decode_document(path: str, extension: str) -> Decoded:
     return Decoded(array=arr, target=(th, tw))
 
 
-def decode(path: str, extension: str | None) -> Decoded:
+def decode(path: str, extension: str | None,
+           tap: FrameTap | None = None) -> Decoded:
     ext = (extension or "").lower()
     if is_video(extension):
         return decode_video_frame(path)
     if ext in HEIF_EXTENSIONS:
-        return decode_heif_image(path, extension)
+        return decode_heif_image(path, extension, tap)
     if ext in SVG_EXTENSIONS or ext in PDF_EXTENSIONS:
         return decode_document(path, ext)
-    return decode_image(path)
+    return decode_image(path, tap)
 
 
 def encode_webp(arr: np.ndarray, quality: int = WEBP_QUALITY) -> bytes:

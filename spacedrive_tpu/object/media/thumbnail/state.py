@@ -10,7 +10,8 @@ from __future__ import annotations
 
 import logging
 import os
-from dataclasses import dataclass, field
+from dataclasses import dataclass
+from typing import Callable
 
 import msgpack
 
@@ -30,6 +31,9 @@ class Batch:
     # originating trace context (wire dict) — persisted, so a batch
     # resumed after a crash still reports into the trace that queued it
     trace: dict | None = None
+    # the submitter's tap on the decode stage (actor.FrameSink); the
+    # process's own, never persisted: a reloaded batch has none
+    sink: Callable | None = None
 
     def to_wire(self) -> dict:
         return {

@@ -175,10 +175,19 @@ EMBED_STAGE_SECONDS = REGISTRY.histogram(
 )
 EMBED_DECODE = REGISTRY.counter(
     "sd_embed_decode_total",
-    "images decoded for the embedder, by the DCT scale the decoder "
-    "applied (1 = full size: not a JPEG, or under 512 px on a side), "
-    "counted in the process that decodes",
+    "embedder input planes made, by the DCT scale the frame they were "
+    "made from was decoded at (1 = full size: not a JPEG, or too small "
+    "to scale), wherever the plane was made: the embedder's own decode "
+    "or the thumbnailer's frame; counted in the process that made it",
     labels=("scale",),  # 1 | 2 | 4 | 8
+)
+EMBED_PLANES = REGISTRY.counter(
+    "sd_embed_planes_total",
+    "planes the media job's embed step consumed, by where they came "
+    "from: shared = made from the frame the thumbnailer decoded, own = "
+    "the step decoded the file itself (thumbnail already stored, "
+    "resumed job, restarted actor, pooled software path)",
+    labels=("source",),  # shared | own
 )
 SEARCH_QUERIES = REGISTRY.counter(
     "sd_search_queries_total",

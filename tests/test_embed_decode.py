@@ -1,8 +1,10 @@
-"""`embedder.decode_image` (ISSUE 26): a JPEG is decoded at the DCT
-scale the 32×32 plane needs, everything PIL opens goes straight to RGB,
-and what is not DCT-scaled gives the plane the old path gave, bit for
-bit. The old path (`format_image` → RGBA array → `fromarray` → RGB →
-resize) is restated here as the reference."""
+"""`embedder.decode_image` (ISSUE 26, ISSUE 30): a JPEG is decoded at
+the DCT scale `images.draft_jpeg` asks for (the thumbnailer's target,
+never under 8 source pixels per pixel of the 32×32 plane a side, so
+one frame serves both), everything PIL opens goes straight to RGB, and
+what is not DCT-scaled gives the plane the old path gave, bit for bit.
+The old path (`format_image` → RGBA array → `fromarray` → RGB → resize)
+is restated here as the reference."""
 
 import os
 
@@ -72,7 +74,7 @@ def _decode_counting(path: str):
 
 
 @pytest.mark.parametrize("noisy", [False, True], ids=["smooth", "noisy"])
-@pytest.mark.parametrize("w,h,scale", [(4032, 3024, "8"), (2016, 1512, "4")])
+@pytest.mark.parametrize("w,h,scale", [(4032, 3024, "4"), (2016, 1512, "2")])
 def test_scaled_jpeg_vector_close_to_full_size(tmp_path, w, h, scale, noisy):
     path = str(tmp_path / "photo.jpg")
     _field(7, w, h, noisy).save(path, "JPEG", quality=88)
@@ -155,7 +157,7 @@ def test_unscaled_plane_bit_identical_to_old_path(tmp_path, case):
 
 
 @pytest.mark.parametrize("mode", ["L", "CMYK"])
-@pytest.mark.parametrize("w,h,scale", [(500, 375, "1"), (1024, 768, "2")])
+@pytest.mark.parametrize("w,h,scale", [(500, 375, "1"), (1024, 768, "1")])
 def test_grey_and_cmyk_jpegs_give_an_rgb_plane(tmp_path, mode, w, h, scale):
     path = str(tmp_path / "photo.jpg")
     _field(13, w, h).convert(mode).save(path, "JPEG", quality=88)
@@ -242,10 +244,11 @@ def test_handler_formats_keep_format_image(tmp_path, monkeypatch):
     assert len(seen) == 3
 
 
-# --- (d) the procpool stage runs the same function -------------------------
+# --- (d) the procpool stage and the thumbnailer's tap give the same plane ---
 
 
 def test_stage_embed_decode_bytes_equal_inline_planes(tmp_path):
+    from spacedrive_tpu.object.media.thumbnail import process
     from spacedrive_tpu.parallel.procworker import _stage_embed_decode
 
     paths = []
@@ -262,3 +265,16 @@ def test_stage_embed_decode_bytes_equal_inline_planes(tmp_path):
         == reply["planes"]
     assert reply["planes"][-1] is None
     assert all(len(b) == 32 * 32 * 3 * 4 for b in reply["planes"][:-1])
+
+    # the third side: the plane made from the frame the thumbnailer
+    # decoded (what the media job's holder keeps, as uint8)
+    tapped = []
+    for path in paths[:-1]:
+        process.decode_image(
+            path, lambda frame, scale: tapped.append(
+                embedder.plane_from_frame(frame, scale)))
+    assert [embedder.input_plane(p).tobytes() for p in tapped] \
+        == reply["planes"][:-1]
+    with pytest.raises(Exception):
+        process.decode_image(paths[-1], lambda frame, scale: tapped.append(0))
+    assert len(tapped) == 3
