@@ -7,6 +7,12 @@ rescan cell the rewritten, added and deleted files of every pass, and
 that the pass used the chip. Every number compared has a limit; exact
 comparisons have the limit 0. PERF.md §2 gives the readings the two
 other limits were set from.
+
+A configuration's kinds of file (`generators/common.py`) bring their own
+guarantees: a kind module's `compare(c, state) -> set of rels` is called
+once for every data directory checked, after the comparisons here. It
+adds numbers under names of its own, each beside its limit, and returns
+the files that lack their final state.
 """
 
 from __future__ import annotations
@@ -18,7 +24,7 @@ import time
 
 import numpy as np
 
-from .generators.common import seed_words
+from .generators.common import entries_of, read_from_disk, seed_words
 from .reference import blake3_np, cas_layout, media
 
 #: mean |difference| of 255 between a stored webp and the reference
@@ -32,6 +38,16 @@ THUMB_GAP_LIMIT = 10.0
 EMBED_GAP_LIMIT = 0.03
 #: images compared pixel by pixel and vector by vector in each pass
 MEDIA_SAMPLE = 8
+#: the names this file compares under; a kind may add none of them, or
+#: it could loosen a limit by adding it again
+OWN_NAMES = frozenset((
+    "compiles_in_window", "pallas_mode_not_tpu", "jobs_not_completed",
+    "ladder_level", "device_fallbacks", "device_stamp_differs",
+    "journal_mode_not_wal", "walk_missing", "walk_extra", "cas_mismatch",
+    "object_unlinked", "object_link_errors", "objects_not_distinct_cas",
+    "crdt_ops_missing", "thumbnail_missing", "thumbnail_wrong_size",
+    "embedding_missing", "thumbnail_pixel_gap", "embedding_gap",
+    "rescan_stale", "rescan_not_deleted", "rescan_file_count_off"))
 
 
 def plain_message(entry: dict) -> bytes:
@@ -43,19 +59,24 @@ def plain_message(entry: dict) -> bytes:
     return b"".join(parts)
 
 
-def reference_cas(location: str, entries: list[dict]) -> dict[str, str]:
-    """rel → cas_id; plain files from their seeds, images from disk.
-    Entries with the same size and content are hashed once."""
+def reference_cas(location: str, entries: list[dict],
+                  kinds: dict | None = None) -> dict[str, str]:
+    """rel → cas_id; plain files from their seeds, images and the files
+    a kind wrote from disk. Entries with the same size and content are
+    hashed once (a kind's files one by one: what else of the entry their
+    bytes depend on is the kind's to know)."""
     out: dict[str, str] = {}
     todo: dict[tuple, list[dict]] = {}
     for e in entries:
-        todo.setdefault((e["size"], tuple(e["content"])), []).append(e)
+        own = e["rel"] if e.get("kind") else None
+        todo.setdefault((e["size"], tuple(e["content"]), own), []).append(e)
     groups = list(todo.values())
     for lo in range(0, len(groups), 4096):
         part = groups[lo:lo + 4096]
         messages = [
             cas_layout.message(os.path.join(location, g[0]["rel"]))
-            if g[0].get("image") else plain_message(g[0]) for g in part]
+            if read_from_disk(g[0], kinds) else plain_message(g[0])
+            for g in part]
         for g, digest in zip(part, blake3_np.hash_many(
                 messages, cas_layout.CAS_HEX // 2)):
             for e in g:
@@ -126,6 +147,7 @@ class Compared:
 
     def __init__(self) -> None:
         self.numbers: dict[str, list[float]] = {}
+        self._owner: dict[str, str] = {}
 
     def worst(self, name: str, value: float, limit: float) -> None:
         """Keep the largest reading of `name` over the passes."""
@@ -137,6 +159,11 @@ class Compared:
         old = self.numbers.get(name, [0, limit])
         self.numbers[name] = [old[0] + value, limit]
 
+    def scoped(self, kind: str) -> "_Scoped":
+        """What a kind's `compare` gets: `worst` and `add` under names
+        that neither this file nor another kind compares under."""
+        return _Scoped(self, kind)
+
     @property
     def correct(self) -> bool:
         return all(v <= lim for v, lim in self.numbers.values())
@@ -145,6 +172,27 @@ class Compared:
         return [f"compared {name} = {v:.6g} (limit {lim:g}) "
                 f"{'ok' if v <= lim else 'FAIL'}"
                 for name, (v, lim) in self.numbers.items()]
+
+
+class _Scoped:
+    def __init__(self, c: Compared, kind: str) -> None:
+        self._c, self._kind = c, kind
+
+    def _own(self, name: str) -> None:
+        holder = "check.py" if name in OWN_NAMES else \
+            self._c._owner.setdefault(name, self._kind)
+        if holder != self._kind:
+            raise SystemExit(
+                f"benchmark: the kind {self._kind!r} compares under "
+                f"{name!r}, a name {holder} holds")
+
+    def worst(self, name: str, value: float, limit: float) -> None:
+        self._own(name)
+        self._c.worst(name, value, limit)
+
+    def add(self, name: str, value: float, limit: float) -> None:
+        self._own(name)
+        self._c.add(name, value, limit)
 
 
 def media_sample(images: list[dict], seed: int) -> list[dict]:
@@ -187,10 +235,17 @@ def media_references(location: str, sample: list[dict], target_px: int,
     return out
 
 
+def _stored_thumbnails(data_dir: str) -> dict[str, str]:
+    stored = {}
+    for d, _dirs, names in os.walk(os.path.join(data_dir, "thumbnails")):
+        stored.update({n: os.path.join(d, n) for n in names})
+    return stored
+
+
 def _check_state(c: Compared, data_dir: str, location: str,
                  entries: list[dict], want_cas: dict[str, str],
                  config: dict, sample: list[dict], refs: dict,
-                 exact_objects: bool) -> set[str]:
+                 exact_objects: bool, kinds: dict, seed: int) -> set[str]:
     """One data directory against the location's expected state; → the
     files that lack their final state. `exact_objects`: a fresh library
     holds exactly one object per distinct content (a rescanned one may
@@ -254,11 +309,11 @@ def _check_state(c: Compared, data_dir: str, location: str,
         c.add("crdt_ops_missing", crdt_missing, 0)
 
         images = [e for e in entries if e.get("image")]
+        comparing = {name: mod for name, mod in kinds.items()
+                     if hasattr(mod, "compare")}
+        stored = _stored_thumbnails(data_dir) if images or comparing else {}
         if images:
             target = config["upstream"]["thumbnail"]["target_px"]
-            stored = {}
-            for d, _dirs, names in os.walk(os.path.join(data_dir, "thumbnails")):
-                stored.update({n: os.path.join(d, n) for n in names})
             vectors = {_rel(r): r["vector"] for r in db.execute(
                 "SELECT fp.materialized_path, fp.name, fp.extension, "
                 "e.vector FROM file_path fp JOIN object_embedding e ON "
@@ -294,6 +349,13 @@ def _check_state(c: Compared, data_dir: str, location: str,
                 c.worst("embedding_gap", media.embed_gap(
                     np.frombuffer(vectors[rel], "<f4"), refs[rel]["vector"]),
                     EMBED_GAP_LIMIT)
+
+        for name, mod in comparing.items():
+            bad |= set(mod.compare(c.scoped(name), {
+                "data_dir": data_dir, "location": location,
+                "entries": entries_of(entries, name),
+                "rows": rows, "want_cas": want_cas, "stored": stored,
+                "config": config, "seed": seed, "db": db}))
     finally:
         db.close()
     return bad
@@ -301,9 +363,11 @@ def _check_state(c: Compared, data_dir: str, location: str,
 
 def decide(config: dict, traffic: dict, location: str, manifest: list[dict],
            passes: list[dict], seed: int, *, compiles_in_window: int,
-           stamp: dict) -> dict:
-    """→ {"correct", "failed", "compared", "lines", "seconds"}"""
+           stamp: dict, kinds: dict | None = None) -> dict:
+    """→ {"correct", "failed", "compared", "lines", "seconds"}; `kinds`
+    are the configuration's kind modules by name."""
     t0 = time.perf_counter()
+    kinds = kinds or {}
     c = Compared()
 
     # the pass used the chip, and nothing stalled or left it
@@ -327,12 +391,13 @@ def decide(config: dict, traffic: dict, location: str, manifest: list[dict],
     target = config["upstream"]["thumbnail"]["target_px"]
     sample = media_sample([e for e in manifest if e.get("image")], seed)
     refs = media_references(location, sample, target)
-    want_cas = reference_cas(location, manifest)
+    want_cas = reference_cas(location, manifest, kinds)
     failed = 0
     if traffic["fresh_data_dir"]:
         for p in passes:
             failed += len(_check_state(c, p["data_dir"], location, manifest,
-                                       want_cas, config, sample, refs, True))
+                                       want_cas, config, sample, refs, True,
+                                       kinds, seed))
     else:
         # every pass's own changes, as probed right after it ...
         for p in passes:
@@ -352,6 +417,6 @@ def decide(config: dict, traffic: dict, location: str, manifest: list[dict],
         # ... and the whole library as the last pass left it
         failed += len(_check_state(c, passes[-1]["data_dir"], location,
                                    manifest, want_cas, config, sample, refs,
-                                   False))
+                                   False, kinds, seed))
     return {"correct": c.correct, "failed": failed, "compared": c.numbers,
             "lines": c.lines(), "seconds": round(time.perf_counter() - t0, 2)}

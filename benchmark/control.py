@@ -13,6 +13,10 @@ is host arithmetic on the location a seed gives.
              the configuration states bfloat16
   cas_id     BLAKE3 over the file's bytes without the 8-byte size prefix
              the upstream layout states (an exact comparison: limit 0)
+
+A kind of file a configuration lists brings the control of its own
+guarantee: `control(config, entries, location, seed) -> {name: [value,
+limit]}`, its reference a notch below, printed beside these three.
 """
 
 from __future__ import annotations
@@ -28,19 +32,24 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import check, harness  # noqa: E402
-from benchmark.generators.common import write_manifest  # noqa: E402
+from benchmark.generators.common import (  # noqa: E402
+    entries_of, is_plain, write_manifest)
 from benchmark.reference import blake3_np, cas_layout, media  # noqa: E402
 
 
-def readings(config: dict, generator, seed: int, work: str) -> dict:
-    """Control readings for one seed, beside the limits they must pass."""
+def readings(config: dict, generator, seed: int, work: str,
+             kinds: dict | None = None) -> dict:
+    """Control readings for one seed, beside the limits they must pass.
+    `kinds` are the configuration's kind modules by name."""
+    kinds = kinds or {}
     location = os.path.join(work, "control-location")
     shutil.rmtree(location, ignore_errors=True)
     os.makedirs(location)
     try:
         manifest = generator.plan(config, seed)
         images = [e for e in manifest if e.get("image")]
-        write_manifest(location, images)
+        write_manifest(location, [e for e in manifest if not is_plain(e)],
+                       kinds)
         sample = check.media_sample(images, seed)
         upstream = config["upstream"]["thumbnail"]
         refs = check.media_references(location, sample, upstream["target_px"])
@@ -60,18 +69,29 @@ def readings(config: dict, generator, seed: int, work: str) -> dict:
             refs[r]["pixels"]) for r in refs)
         embed = max(media.embed_gap(fp8[r]["vector"], refs[r]["vector"])
                     for r in refs)
-        plain = [e for e in manifest if not e.get("image")][:256]
+        plain = [e for e in manifest if is_plain(e)][:256]
         want = check.reference_cas(location, plain)
         no_prefix = blake3_np.hash_many(
             [check.plain_message(e)[8:] for e in plain], cas_layout.CAS_HEX // 2)
         cas = sum(d.hex() != want[e["rel"]] for e, d in zip(plain, no_prefix))
-        return {
+        out = {
             "seed": seed, "sample": len(sample),
             "thumbnail_pixel_gap": [thumb, check.THUMB_GAP_LIMIT],
             "thumbnail_codec_alone": codec,
             "embedding_gap": [embed, check.EMBED_GAP_LIMIT],
             "cas_mismatch": [cas, 0] if plain else None,
         }
+        for kind, mod in kinds.items():
+            if not hasattr(mod, "control"):
+                continue
+            own = mod.control(config, entries_of(manifest, kind), location,
+                              seed)
+            if set(own) & set(out):
+                raise SystemExit(f"benchmark: the kind {kind!r} gives a "
+                                 f"control under {sorted(set(own) & set(out))}"
+                                 ", which control.py holds")
+            out.update(own)
+        return out
     finally:
         shutil.rmtree(location, ignore_errors=True)
 
@@ -90,8 +110,9 @@ def main(argv: list[str] | None = None) -> int:
     bench = harness.Bench(ROOT)
     spec = bench.cell(args.workload)
     generator = bench.generator(spec["config"])
+    kinds = bench.kinds(spec["config"])
     for seed in (int(s) for s in args.seeds.split(",")):
-        r = readings(spec["config"], generator, seed, harness.WORK)
+        r = readings(spec["config"], generator, seed, harness.WORK, kinds)
         print(json.dumps({**r, "workload": args.workload,
                           "fails": not_correct(r)}), flush=True)
     return 0

@@ -7,8 +7,19 @@ A manifest is a list of entries; an entry is a dict with
   size     bytes (plain files; images learn theirs when written)
   content  [seed, serial] the bytes are drawn from: two entries with the
            same size and content are exact duplicates
-  image    absent for plain files, else {"w", "h", "orientation",
-           "format": "jpg"|"png", "blocky": bool}
+  image    absent, else {"w", "h", "orientation", "format": "jpg"|"png",
+           "blocky": bool}: a JPEG or PNG the harness writes and holds
+           to a thumbnail and an embedding
+  kind     absent, else the name of a kind of file the configuration
+           lists under "kinds" and a module `<path>/kinds/<name>.py`
+           brings (benchmark/README.md): its `write(path, entry)` puts
+           the file on disk from `content` and the entry's own keys,
+           and the entry learns `size` from disk, as an image does
+
+A *plain* file has neither `image` nor `kind`: seeded bytes in the
+ranges a cas_id reads, holes elsewhere. Only plain files are rewritten,
+added and deleted by a traffic mix. An entry whose kind has no `write`
+is written as its `image` says, or else as a plain file is.
 """
 
 from __future__ import annotations
@@ -58,22 +69,63 @@ def write_image(path: str, content: list[int], image: dict) -> None:
         img.save(path, "JPEG", quality=88, exif=exif)
 
 
-def write_manifest(root: str, manifest: list[dict]) -> None:
-    """Put every entry on disk; images learn their size."""
+def is_plain(entry: dict) -> bool:
+    return not entry.get("image") and not entry.get("kind")
+
+
+def entries_of(manifest: list[dict], kind: str) -> list[dict]:
+    return [e for e in manifest if e.get("kind") == kind]
+
+
+def kind_writer(entry: dict, kinds: dict | None):
+    """The `write` of the entry's kind, or None where the entry has no
+    kind or its kind writes nothing of its own. A kind the configuration
+    does not list ends the run."""
+    name = entry.get("kind")
+    if name is None:
+        return None
+    if name not in (kinds or {}):
+        raise SystemExit(
+            f"benchmark: {entry['rel']} has the kind {name!r}, which the "
+            f"configuration does not list under \"kinds\" "
+            f"({sorted(kinds or {})})")
+    return getattr(kinds[name], "write", None)
+
+
+def read_from_disk(entry: dict, kinds: dict | None) -> bool:
+    """Whether the file's bytes are known only once it is written (an
+    image, a kind's own format) and not from `size` and `content`."""
+    return bool(entry.get("image")) or kind_writer(entry, kinds) is not None
+
+
+def write_manifest(root: str, manifest: list[dict],
+                   kinds: dict | None = None) -> None:
+    """Put every entry on disk; images and the files a kind (name →
+    module) writes learn their size."""
     for rel_dir in sorted({os.path.dirname(e["rel"]) for e in manifest}):
         os.makedirs(os.path.join(root, rel_dir), exist_ok=True)
-    images = [e for e in manifest if e.get("image")]
+
+    def job(e: dict):
+        path = os.path.join(root, e["rel"])
+        own = kind_writer(e, kinds)
+        if own is not None:
+            return own, path, e
+        if e.get("image"):
+            return write_image, path, e["content"], e["image"]
+        return None
+
+    jobs = [(e, job(e)) for e in manifest]
     with ThreadPoolExecutor(IMAGE_THREADS) as pool:
-        futures = [pool.submit(write_image, os.path.join(root, e["rel"]),
-                               e["content"], e["image"]) for e in images]
-        for e in manifest:
-            if not e.get("image"):
+        futures = [pool.submit(*j) for _e, j in jobs if j is not None]
+        for e, j in jobs:
+            if j is None:
                 write_plain(os.path.join(root, e["rel"]), e["size"],
                             e["content"])
         for f in futures:
             f.result()
-    for e in images:
-        e["size"] = os.path.getsize(os.path.join(root, e["rel"]))
+    for e, j in jobs:
+        if j is not None:
+            e["size"] = os.path.getsize(os.path.join(root, e["rel"]))
 
 
 def seed_words(seed: int, *more: int) -> list[int]:

@@ -79,6 +79,22 @@ class Bench:
         return load_module(
             self.find("generators", config["generator"] + ".py"))
 
+    def kinds(self, config: dict) -> dict:
+        """name → module of every kind of file the configuration lists
+        (`generators/common.py` says what an entry's `kind` means). A
+        listed kind that no directory of `paths` holds ends the run."""
+        out = {}
+        for name in config.get("kinds", []):
+            try:
+                out[name] = load_module(self.find("kinds", name + ".py"))
+            except FileNotFoundError:
+                tried = [os.path.join(base, "kinds", name + ".py")
+                         for base in self.doc["paths"]]
+                raise SystemExit(
+                    f"benchmark: the configuration lists the kind {name!r} "
+                    f"and none of {tried} is there") from None
+        return out
+
     def metrics_for(self, workload: str, kind: str) -> list[dict]:
         return [m for m in self.doc[kind]
                 if workload in m.get("workloads", [workload])]
@@ -318,12 +334,12 @@ class Traffic:
     def before_pass(self) -> dict | None:
         """Apply this pass's mutations to the location and the manifest;
         → {"rewritten", "added", "deleted"} lists of entries, or None."""
-        from .generators.common import write_plain
+        from .generators.common import is_plain, write_plain
 
         shares = self.params.get("mutate")
         if not shares:
             return None
-        plain = [e for e in self.manifest if not e.get("image")]
+        plain = [e for e in self.manifest if is_plain(e)]
         n = len(plain)
         counts = {k: max(1, round(n * shares[k + "_share"]))
                   for k in ("rewrite", "add", "delete")}
@@ -423,6 +439,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     spec = bench.cell(workload)
     cell, config, traffic_params = spec["cell"], spec["config"], spec["traffic"]
     generator = bench.generator(config)
+    kinds = bench.kinds(config)
     with open(bench.find("peaks.json")) as f:
         peaks = json.load(f)
 
@@ -447,7 +464,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     part("native_build_s")
 
     from . import check, warm
-    from .generators.common import write_manifest
+    from .generators.common import entries_of, write_manifest
 
     run_dir = os.path.join(work, "run")
     shutil.rmtree(run_dir, ignore_errors=True)
@@ -456,17 +473,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     breakdown = full_gc = None
     try:
         manifest = generator.plan(config, seed)
-        write_manifest(location, manifest)
+        write_manifest(location, manifest, kinds)
         warm_location = None
         if traffic_params.get("warmup_scale"):
             warm_location = os.path.join(run_dir, "warm-location")
             os.makedirs(warm_location)
             write_manifest(warm_location, generator.plan(
-                config, seed + 1, scale=traffic_params["warmup_scale"]))
+                config, seed + 1, scale=traffic_params["warmup_scale"]),
+                kinds)
         part("location_s")
         log(f"location: {len(manifest)} files, "
             f"{sum(e['size'] for e in manifest) >> 20} MiB apparent, "
-            f"{sum(1 for e in manifest if e.get('image'))} images")
+            f"{sum(1 for e in manifest if e.get('image'))} images"
+            + "".join(f", {len(entries_of(manifest, k))} {k}" for k in kinds))
 
         n_dev = stamp["count"]
         hashes = warm.hash_programs([e["size"] for e in manifest], n_dev)
@@ -475,7 +494,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              if e.get("image")], n_dev)
         threads = warm.compile_threads(memory.limit, _rss_with_children())
         compiles.phase = memory.phase = "program_warm"
-        programs = warm.run_programs(hashes, media, n_dev, threads)
+        own = [p for name, mod in kinds.items() if hasattr(mod, "programs")
+               for p in mod.programs(entries_of(manifest, name), location,
+                                     n_dev)]
+        programs = warm.run_programs(hashes, media, n_dev, threads, own)
         warm.release_freed_heap()
         part("program_warm_s")
         log(f"programs: {len(programs)} warmed on {threads} threads, "
@@ -602,7 +624,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
         verdict = check.decide(
             config, traffic_params, location, manifest, passes, seed,
-            compiles_in_window=ctx["compiles_in_window"], stamp=stamp)
+            compiles_in_window=ctx["compiles_in_window"], stamp=stamp,
+            kinds=kinds)
         result = {
             "correct": verdict["correct"],
             "attempted": sum(p["offered"] for p in passes),

@@ -16,26 +16,49 @@ if ROOT not in sys.path:
 import pytest  # noqa: E402
 
 
-def tiny_configs() -> dict:
-    """The two configurations at a size a test run can hold."""
-    out = {}
-    for name in ("homedir", "photolib"):
-        with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
-            out[name] = json.load(f)
-    home, photo = out["homedir"], out["photolib"]
-    home["files"] = 120
-    home["file_size"]["max_bytes"] = 3000
-    home["image_share"] = 0.04
-    home["images"] = [
+def _tiny_home_tree(config: dict) -> None:
+    config["files"] = 120
+    config["file_size"]["max_bytes"] = 3000
+    config["image_share"] = 0.04
+    config["images"] = [
         {"name": "camera", "share": 0.5, "format": "jpg", "width": [640, 640],
          "height": [480, 480], "exif_orientations": [3, 6, 1, 8]},
         {"name": "icons", "share": 0.5, "format": "png", "width": [96, 128],
          "height": [0, 1]},
     ]
-    photo["photos"] = 8
-    photo["photos_per_screenshot"] = 3
-    photo["photo"].update(width=640, height=480, exif_orientations=[3, 6, 1, 8])
-    photo["screenshot"].update(width=234, height=506)
+
+
+def _tiny_camera_roll(config: dict) -> None:
+    config["photos"] = 8
+    config["photos_per_screenshot"] = 3
+    config["photo"].update(width=640, height=480, exif_orientations=[3, 6, 1, 8])
+    config["screenshot"].update(width=234, height=506)
+
+
+def _tiny_raw_shoot(config: dict) -> None:
+    config["frames"] = 8
+    config["frame"].update(min_bytes=200_000, max_bytes=400_000)
+    config["exports"] = 2
+    config["export"].update(width=640, height=427)
+
+
+#: generator → what cuts its configuration to a size a test run can hold
+TINY = {"home_tree": _tiny_home_tree, "camera_roll": _tiny_camera_roll,
+        "raw_shoot": _tiny_raw_shoot}
+
+
+def tiny_configs() -> dict:
+    """Every configuration of BENCHMARK.json by name, cut by its
+    generator's entry in TINY; one whose generator has none stays as its
+    file has it."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entries = json.load(f)["configs"]
+    out = {}
+    for entry in entries:
+        with open(os.path.join(ROOT, entry["file"])) as f:
+            config = json.load(f)
+        TINY.get(config["generator"], lambda _config: None)(config)
+        out[entry["name"]] = config
     return out
 
 

@@ -6,7 +6,7 @@ import copy
 
 import pytest
 
-from benchmark import harness
+from benchmark import check, harness
 from benchmark.tests.conftest import cpu_stamp
 
 
@@ -21,7 +21,7 @@ def failing(result) -> set:
 
 
 @pytest.mark.parametrize("workload", ["homedir.cold", "photolib.cold",
-                                      "homedir.rescan"])
+                                      "homedir.rescan", "photolib.raw"])
 def test_sound_run_is_correct(tiny_root, tmp_path, workload):
     r = run(tiny_root, tmp_path, workload)
     assert r["correct"] is True, failing(r)
@@ -29,6 +29,8 @@ def test_sound_run_is_correct(tiny_root, tmp_path, workload):
     assert set(r["metrics"]) == {"pass_rate", "setup_s"}
     assert r["metrics"]["pass_rate"]["value"] > 0
     assert list(r)[-1] == "compared"
+    # every name compared is one a kind of file may not add again
+    assert set(r["compared"]) <= check.OWN_NAMES
     # whole passes only: the window ends with its last pass
     assert r["window_s"] == pytest.approx(sum(r["pass_cycle_s"]), rel=0.02)
     assert r["window_s"] >= 1.0

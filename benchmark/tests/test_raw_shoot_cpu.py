@@ -1,7 +1,9 @@
 """`photolib.raw` through the harness at a tiny size on the CPU, with a
-fixture of its own (conftest.py's `tiny_root` knows two configurations
-by name): a sound run is correct, a run whose sampled read is moved by
-a byte is not, and the cas_id control fails."""
+fixture of its own: conftest.py's `tiny_root` cuts the frames to a few
+hundred KB, here they keep their 25-40 MB (they are holes), so the
+sampled ranges lie where the cell's do. A sound run is correct, a run
+whose sampled read is moved by a byte is not, and the cas_id control
+fails."""
 
 import json
 import os

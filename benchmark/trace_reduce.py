@@ -1,7 +1,6 @@
 """From a profiler trace to numbers: the device's busy union, its idle
 gaps by the job that was running, op and module times under the names
-the trace prints. Read with `jax.profiler.ProfileData` alone (seeded by
-`profile_kernel.py:parse_trace`, which read the JSON export).
+the trace prints. Read with `jax.profiler.ProfileData` alone.
 
 The harness wraps the measured window in a `bench.window` annotation;
 everything is clipped to it, and it maps the host's wall clock (job

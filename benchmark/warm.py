@@ -104,10 +104,13 @@ def compile_threads(memory_limit: int, rss: int) -> int:
     return int(max(1, min((os.cpu_count() or 2) - 2, by_memory)))
 
 
-def run_programs(hashes, media: dict, n_dev: int, threads: int) -> list:
+def run_programs(hashes, media: dict, n_dev: int, threads: int,
+                 own=()) -> list:
     """Dispatch every program once, widest first, in `threads` threads
     (XLA and Mosaic compile outside the GIL; with a warm compile cache
-    each call only loads and runs). → [(name, seconds), ...]"""
+    each call only loads and runs). `own` are the (weight, name, fn) a
+    configuration's kinds of file bring: `fn()` runs one program to its
+    end. → [(name, seconds), ...]"""
     import jax
     import numpy as np
 
@@ -145,6 +148,7 @@ def run_programs(hashes, media: dict, n_dev: int, threads: int) -> list:
              for b, pad in media["resize"]]
     jobs += [(0, f"embed_pad{pad}", lambda pad=pad: embed_one(pad))
              for pad in media["embed"]]
+    jobs += list(own)
     jobs.sort(key=lambda j: -j[0])
     with ThreadPoolExecutor(threads, thread_name_prefix="bench-warm") as pool:
         futures = [pool.submit(timed, name, fn) for _w, name, fn in jobs]
