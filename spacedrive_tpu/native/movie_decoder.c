@@ -70,8 +70,13 @@ static int frame_to_rgba(const AVFrame *frame, uint8_t **out, int *w,
         set_err(errbuf, errlen, "swscale context failed", 0);
         return -1;
     }
-    int stride = frame->width * 4;
-    uint8_t *buf = av_malloc((size_t)stride * frame->height);
+    /* swscale's vector code writes whole blocks: a row whose width is
+     * not a multiple of 16 pixels is written past its end (1080 wide: a
+     * portrait phone clip), so the rows it scales into are 64-byte
+     * aligned and padded, and a tight copy is handed on. */
+    int tight = frame->width * 4;
+    int stride = (tight + 63) & ~63;
+    uint8_t *buf = av_malloc((size_t)stride * frame->height + 64);
     if (!buf) {
         sws_freeContext(sws);
         set_err(errbuf, errlen, "out of memory", 0);
@@ -82,6 +87,9 @@ static int frame_to_rgba(const AVFrame *frame, uint8_t **out, int *w,
     sws_scale(sws, (const uint8_t *const *)frame->data, frame->linesize, 0,
               frame->height, dst, dst_stride);
     sws_freeContext(sws);
+    if (stride != tight)
+        for (int y = 1; y < frame->height; y++)
+            memmove(buf + (size_t)y * tight, buf + (size_t)y * stride, tight);
     *out = buf;
     *w = frame->width;
     *h = frame->height;

@@ -13,6 +13,7 @@ import collections
 import logging
 import os
 import threading
+import time
 from typing import Any
 
 import hashlib
@@ -329,6 +330,8 @@ class MediaProcessorJob(StatefulJob):
         return StepResult()
 
     def _extract_media_data(self, ctx: JobContext, step: dict) -> StepResult:
+        from ...telemetry import metrics as _tm
+
         library = ctx.library
         loc_path = self.data["location_path"]
         loc_id = self.data["location_id"]
@@ -344,9 +347,15 @@ class MediaProcessorJob(StatefulJob):
             if ext in VIDEO_EXTENSIONS:
                 from .media_data import VideoMetadata
 
-                meta = VideoMetadata.from_path(full)
+                with span("video") as probe:
+                    meta = VideoMetadata.from_path(full)
+                _tm.MEDIA_EXTRACT_SECONDS.observe(
+                    probe.duration, kind="video")
             else:
+                t0 = time.perf_counter()
                 meta = ImageMetadata.from_path(full)
+                _tm.MEDIA_EXTRACT_SECONDS.observe(
+                    time.perf_counter() - t0, kind="image")
             if meta is None:
                 skipped += 1
                 # still a vouch: "probed, nothing extractable" — stops

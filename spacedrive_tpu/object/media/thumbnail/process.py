@@ -17,12 +17,15 @@ import io
 import logging
 import math
 import os
+import time
 from dataclasses import dataclass
 from typing import Any, Callable
 
 import numpy as np
 
 from ....ops import thumbnail_jax as tj
+from ....telemetry import metrics as _tm
+from ....telemetry import span
 
 logger = logging.getLogger(__name__)
 
@@ -157,33 +160,17 @@ def needs_cpu_fallback(d: Decoded) -> bool:
     ) > tj.BUCKETS[-1]
 
 
-def decode_video_frame(path: str) -> Decoded:
-    """Grab one frame ~10% into the video through the native FFmpeg
-    frontend (native/movie_decoder.c — preferred stream with
-    embedded-cover preference, ~10% seek, display-matrix rotation;
-    ref:movie_decoder.rs:32-629, cover check :352), with cv2 as the
-    fallback when libav isn't present. Target dims bound the max
-    dimension to 256 (ref:process.rs:470)."""
-    from ....native import video_available, video_frame
+def _video_frame_native(path: str) -> tuple[np.ndarray, int, bool]:
+    from ....native import video_frame
 
-    if video_available():
-        try:
-            arr, rotation, is_cover = video_frame(
-                path, seek_fraction=VIDEO_SEEK_FRACTION
-            )
-        except ValueError as exc:
-            raise ThumbError(str(exc))
-        if rotation % 360 and rotation % 90 == 0:
-            # display matrix says rotate clockwise by `rotation`; only
-            # right-angle rotations are meaningful for a raster thumb
-            arr = np.ascontiguousarray(
-                np.rot90(arr, k=(-rotation // 90) % 4)
-            )
-        arr = shrink_to_max_dim(arr)
-        h, w = arr.shape[:2]
-        tw, th = tj.video_dimensions(w, h)
-        # embedded cover art is album art, not footage: no film strip
-        return Decoded(array=arr, target=(th, tw), is_video=not is_cover)
+    try:
+        return video_frame(path, seek_fraction=VIDEO_SEEK_FRACTION)
+    except ValueError as exc:
+        raise ThumbError(str(exc))
+
+
+def _video_frame_cv2(path: str) -> np.ndarray:
+    """The frame exactly ~10% in, BGR as cv2 hands it on."""
     try:
         import cv2
     except Exception as e:  # pragma: no cover
@@ -204,11 +191,52 @@ def decode_video_frame(path: str) -> Decoded:
             raise ThumbError(f"no decodable frame: {path}")
     finally:
         cap.release()
-    rgb = shrink_to_max_dim(frame[:, :, ::-1])  # BGR → RGB
-    h, w = rgb.shape[:2]
-    arr = np.dstack([rgb, np.full((h, w, 1), 255, np.uint8)])
+    return frame
+
+
+def decode_video_frame(path: str) -> Decoded:
+    """Grab one frame ~10% into the video through the native FFmpeg
+    frontend (native/movie_decoder.c — preferred stream with
+    embedded-cover preference, ~10% seek, display-matrix rotation;
+    ref:movie_decoder.rs:32-629, cover check :352), with cv2 as the
+    fallback when libav isn't present. Target dims bound the max
+    dimension to 256 (ref:process.rs:470)."""
+    from ....native import video_available
+
+    by_libav = video_available()
+    rotation, is_cover = 0, False
+    try:
+        with span("video.frame") as grab:
+            if by_libav:
+                arr, rotation, is_cover = _video_frame_native(path)
+            else:
+                arr = _video_frame_cv2(path)
+    except ThumbError:
+        _tm.THUMB_VIDEO_FRAMES.inc(
+            decoder="native" if by_libav else "cv2", result="error")
+        raise
+    t0 = time.perf_counter()
+    if by_libav:
+        if rotation % 360 and rotation % 90 == 0:
+            # display matrix says rotate clockwise by `rotation`; only
+            # right-angle rotations are meaningful for a raster thumb
+            arr = np.ascontiguousarray(
+                np.rot90(arr, k=(-rotation // 90) % 4)
+            )
+        arr = shrink_to_max_dim(arr)
+    else:
+        rgb = shrink_to_max_dim(arr[:, :, ::-1])  # BGR → RGB
+        arr = np.ascontiguousarray(np.dstack(
+            [rgb, np.full((*rgb.shape[:2], 1), 255, np.uint8)]))
+    h, w = arr.shape[:2]
     tw, th = tj.video_dimensions(w, h)
-    return Decoded(array=np.ascontiguousarray(arr), target=(th, tw), is_video=True)
+    _tm.THUMB_VIDEO_SECONDS.inc(grab.duration, part="frame")
+    _tm.THUMB_VIDEO_SECONDS.inc(time.perf_counter() - t0, part="orient")
+    _tm.THUMB_VIDEO_FRAMES.inc(
+        decoder="native" if by_libav else "cv2", result="ok")
+    _tm.THUMB_VIDEO_BYTES.inc(arr.nbytes)
+    # embedded cover art is album art, not footage: no film strip
+    return Decoded(array=arr, target=(th, tw), is_video=not is_cover)
 
 
 def decode_heif_image(path: str, extension: str,
@@ -265,6 +293,7 @@ def encode_webp(arr: np.ndarray, quality: int = WEBP_QUALITY) -> bytes:
 def apply_film_strip(arr: np.ndarray) -> np.ndarray:
     """Sprocket-hole side strips marking video thumbs
     (ref:crates/ffmpeg/src/film_strip.rs draws the same overlay)."""
+    t0 = time.perf_counter()
     arr = arr.copy()
     h, w = arr.shape[:2]
     strip = max(4, min(w // 10, 20))
@@ -276,6 +305,7 @@ def apply_film_strip(arr: np.ndarray) -> np.ndarray:
         cx0 = x0 + (strip - hole_w) // 2
         for y in range((pitch - hole_h) // 2, h - hole_h, pitch):
             arr[y : y + hole_h, cx0 : cx0 + hole_w, :3] = 235
+    _tm.THUMB_VIDEO_SECONDS.inc(time.perf_counter() - t0, part="overlay")
     return arr
 
 

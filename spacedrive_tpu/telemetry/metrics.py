@@ -158,6 +158,31 @@ THUMB_RESIZE_IMAGES = REGISTRY.counter(
     labels=("alpha",),  # 0 | 1
 )
 
+THUMB_VIDEO_FRAMES = REGISTRY.counter(
+    "sd_thumbnail_video_frames_total",
+    "clips the thumbnailer asked a frame of, by the frontend that decoded "
+    "it (the native libav one, or cv2 where libav is absent) and outcome",
+    labels=("decoder", "result"),  # native | cv2 ; ok | error
+)
+THUMB_VIDEO_SECONDS = REGISTRY.counter(
+    "sd_thumbnail_video_seconds",
+    "seconds a clip's thumbnail costs beside a still's, on the worker "
+    "threads: frame (open, seek, decode one frame, to RGB), orient "
+    "(display-matrix rotation, the oversize stride, cv2's alpha plane), "
+    "overlay (the film strips, after the resize)",
+    labels=("part",),  # frame | orient | overlay
+)
+THUMB_VIDEO_BYTES = REGISTRY.counter(
+    "sd_thumbnail_video_bytes_total",
+    "nbytes of the video frames handed to the resize",
+)
+MEDIA_EXTRACT_SECONDS = REGISTRY.histogram(
+    "sd_media_extract_seconds",
+    "the media job's metadata step, per file: EXIF of an image, the "
+    "container probe of a clip (a second open, after the thumbnailer's)",
+    labels=("kind",),  # image | video
+)
+
 # --- semantic search (models/embedder.py, object/search/index.py) -----------
 
 EMBED_FILES = REGISTRY.counter(

@@ -1,0 +1,35 @@
+"""The benchmark's own tests (`benchmark/tests`, run apart from these:
+their conftest differs) held by tier-1, one case a file, each `python3
+-m pytest <file> -q` in a process of its own under a time limit: PRs
+27-30 left the whole-run cases dead for want of this (PERF.md §7)."""
+
+import glob
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FILES = sorted(os.path.relpath(p, ROOT) for p in glob.glob(
+    os.path.join(ROOT, "benchmark", "tests", "test_*.py")))
+#: seconds one file may take; the slowest, the whole runs of
+#: `test_harness_cpu.py`, takes 85 s alone on the sandbox's CPU
+LIMIT_S = 900
+
+
+def test_there_are_files_to_hold():
+    assert len(FILES) >= 13
+
+
+@pytest.mark.parametrize("path", FILES)
+def test_benchmark_tests_pass(path):
+    # as from a shell: one CPU device (this suite's conftest forces
+    # eight), no word of the worker that runs this case
+    env = {k: v for k, v in os.environ.items()
+           if k != "XLA_FLAGS" and not k.startswith("PYTEST_")}
+    done = subprocess.run(
+        [sys.executable, "-m", "pytest", path, "-q", "-p", "no:cacheprovider"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=LIMIT_S)
+    assert done.returncode == 0, (done.stdout[-3000:], done.stderr[-1000:])
+    assert " passed" in done.stdout and " failed" not in done.stdout

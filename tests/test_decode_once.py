@@ -625,8 +625,11 @@ def test_embed_shared_decode_share_is_declared_with_its_cells():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         declared = {m["name"]: m for m in json.load(f)["per_layer"]}
-    assert declared["embed_shared_decode_share"] == {
+    entry = dict(declared["embed_shared_decode_share"])
+    # as PR 30 listed them; a later cell that embeds appends itself
+    assert entry.pop("workloads")[:3] == [
+        "photolib.cold", "photolib.raw", "homedir.cold"]
+    assert entry == {
         "name": "embed_shared_decode_share", "unit": "%",
         "better": "higher", "source": "program_counter",
-        "layer": "media host", "moves": "pass_rate",
-        "workloads": ["photolib.cold", "photolib.raw", "homedir.cold"]}
+        "layer": "media host", "moves": "pass_rate"}

@@ -373,8 +373,9 @@ def test_reader_gives_none_without_its_counter(name):
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_is_declared_for_the_two_cells_that_hash(name):
+    """As PR 27 listed them; a later cell that hashes appends itself."""
     declared = {m["name"]: m for m in harness.Bench(ROOT).doc["per_layer"]}
-    assert declared[name]["workloads"] == ["photolib.raw", "homedir.cold"]
+    assert declared[name]["workloads"][:2] == ["photolib.raw", "homedir.cold"]
     assert declared[name]["moves"] == "pass_rate"
 
 
