@@ -182,6 +182,7 @@ def load_video() -> ctypes.CDLL | None:
                 ctypes.POINTER(ctypes.c_void_p),
                 ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
+                ctypes.POINTER(ctypes.c_int),
                 ctypes.c_char_p, ctypes.c_int,
             ]
             lib.sd_video_meta.restype = ctypes.c_int
@@ -203,7 +204,9 @@ def video_available() -> bool:
 
 
 def video_frame(path: str, seek_fraction: float = 0.1):
-    """(rgba HxWx4 uint8, rotation_degrees, is_cover) or None.
+    """(HxWxC uint8, rotation_degrees, is_cover) or None. C is what the
+    frontend converted to: 3, RGB, for footage; 4, RGBA, where the
+    decoded frame's pixel format has alpha (a PNG cover).
 
     Preferred-stream selection with embedded-cover preference, ~10%
     seek, display-matrix rotation (ref:movie_decoder.rs:32-629, cover
@@ -214,23 +217,24 @@ def video_frame(path: str, seek_fraction: float = 0.1):
     buf = ctypes.c_void_p()
     w = ctypes.c_int()
     h = ctypes.c_int()
+    channels = ctypes.c_int()
     rot = ctypes.c_int()
     cover = ctypes.c_int()
     err = ctypes.create_string_buffer(256)
     rc = lib.sd_video_frame(
         os.fsencode(path), seek_fraction, ctypes.byref(buf),
-        ctypes.byref(w), ctypes.byref(h), ctypes.byref(rot),
-        ctypes.byref(cover), err, len(err),
+        ctypes.byref(w), ctypes.byref(h), ctypes.byref(channels),
+        ctypes.byref(rot), ctypes.byref(cover), err, len(err),
     )
     if rc != 0:
         raise ValueError(
             f"video decode failed: {err.value.decode(errors='replace')}"
         )
     try:
-        n = w.value * h.value * 4
+        n = w.value * h.value * channels.value
         arr = np.frombuffer(
             ctypes.string_at(buf.value, n), np.uint8
-        ).reshape(h.value, w.value, 4).copy()
+        ).reshape(h.value, w.value, channels.value).copy()
     finally:
         lib.sd_video_free(buf)
     return arr, rot.value, bool(cover.value)

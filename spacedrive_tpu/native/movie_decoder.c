@@ -8,12 +8,15 @@
  *     ATTACHED_PIC disposition wins outright),
  *   - seek ~10% into the container before grabbing a frame,
  *   - rotation read from the stream display matrix and reported to the
- *     caller (the Python side rotates the RGBA array; same output as
- *     the reference's rotation-aware filter graph),
- *   - RGBA conversion through swscale.
+ *     caller (the Python side rotates the array; same output as the
+ *     reference's rotation-aware filter graph),
+ *   - conversion through swscale to tight RGB, 3 bytes a pixel, or to
+ *     RGBA where the decoded frame's pixel format has an alpha
+ *     component (a PNG cover): footage has none, and a plane of 255s
+ *     is a quarter more bytes through every copy after this one.
  *
  * Exported C ABI (ctypes):
- *   int  sd_video_frame(path, seek_fraction, &buf, &w, &h,
+ *   int  sd_video_frame(path, seek_fraction, &buf, &w, &h, &channels,
  *                       &rotation_deg, &is_cover, errbuf, errlen);
  *   int  sd_video_meta(path, &duration_s, &fps, &w, &h, &nb_frames,
  *                      codec_buf, codec_len);
@@ -24,6 +27,7 @@
 #include <libavformat/avformat.h>
 #include <libavutil/display.h>
 #include <libavutil/imgutils.h>
+#include <libavutil/pixdesc.h>
 #include <libswscale/swscale.h>
 #include <math.h>
 #include <stdint.h>
@@ -60,11 +64,16 @@ static int stream_rotation(const AVStream *st) {
     return deg;
 }
 
-static int frame_to_rgba(const AVFrame *frame, uint8_t **out, int *w,
-                         int *h, char *errbuf, int errlen) {
+/* RGB, or RGBA where the frame's own format carries alpha */
+static int frame_to_rgb(const AVFrame *frame, uint8_t **out, int *w, int *h,
+                        int *channels, char *errbuf, int errlen) {
+    const AVPixFmtDescriptor *desc =
+        av_pix_fmt_desc_get((enum AVPixelFormat)frame->format);
+    int planes = desc && (desc->flags & AV_PIX_FMT_FLAG_ALPHA) ? 4 : 3;
     struct SwsContext *sws = sws_getContext(
         frame->width, frame->height, (enum AVPixelFormat)frame->format,
-        frame->width, frame->height, AV_PIX_FMT_RGBA,
+        frame->width, frame->height,
+        planes == 4 ? AV_PIX_FMT_RGBA : AV_PIX_FMT_RGB24,
         SWS_BILINEAR, NULL, NULL, NULL);
     if (!sws) {
         set_err(errbuf, errlen, "swscale context failed", 0);
@@ -74,7 +83,7 @@ static int frame_to_rgba(const AVFrame *frame, uint8_t **out, int *w,
      * not a multiple of 16 pixels is written past its end (1080 wide: a
      * portrait phone clip), so the rows it scales into are 64-byte
      * aligned and padded, and a tight copy is handed on. */
-    int tight = frame->width * 4;
+    int tight = frame->width * planes;
     int stride = (tight + 63) & ~63;
     uint8_t *buf = av_malloc((size_t)stride * frame->height + 64);
     if (!buf) {
@@ -93,6 +102,7 @@ static int frame_to_rgba(const AVFrame *frame, uint8_t **out, int *w,
     *out = buf;
     *w = frame->width;
     *h = frame->height;
+    *channels = planes;
     return 0;
 }
 
@@ -159,8 +169,9 @@ static int decode_one_frame(AVCodecContext *ctx, AVFormatContext *fmt,
 }
 
 int sd_video_frame(const char *path, double seek_fraction, uint8_t **out,
-                   int *out_w, int *out_h, int *out_rotation,
-                   int *out_is_cover, char *errbuf, int errlen) {
+                   int *out_w, int *out_h, int *out_channels,
+                   int *out_rotation, int *out_is_cover, char *errbuf,
+                   int errlen) {
     AVFormatContext *fmt = NULL;
     AVCodecContext *ctx = NULL;
     AVFrame *frame = NULL;
@@ -236,7 +247,8 @@ int sd_video_frame(const char *path, double seek_fraction, uint8_t **out,
                            errbuf, errlen);
     if (ret < 0) goto done;
 
-    if (frame_to_rgba(frame, out, out_w, out_h, errbuf, errlen) < 0)
+    if (frame_to_rgb(frame, out, out_w, out_h, out_channels, errbuf,
+                     errlen) < 0)
         goto done;
     *out_rotation = stream_rotation(st);
     *out_is_cover = is_cover;

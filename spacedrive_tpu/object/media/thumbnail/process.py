@@ -199,8 +199,10 @@ def decode_video_frame(path: str) -> Decoded:
     frontend (native/movie_decoder.c — preferred stream with
     embedded-cover preference, ~10% seek, display-matrix rotation;
     ref:movie_decoder.rs:32-629, cover check :352), with cv2 as the
-    fallback when libav isn't present. Target dims bound the max
-    dimension to 256 (ref:process.rs:470)."""
+    fallback when libav isn't present. The frame is RGB (RGBA only
+    where libav found alpha in the decoded format: a PNG cover), so a
+    clip rides the device resize as three planes in one call. Target
+    dims bound the max dimension to 256 (ref:process.rs:470)."""
     from ....native import video_available
 
     by_libav = video_available()
@@ -225,9 +227,8 @@ def decode_video_frame(path: str) -> Decoded:
             )
         arr = shrink_to_max_dim(arr)
     else:
-        rgb = shrink_to_max_dim(arr[:, :, ::-1])  # BGR → RGB
-        arr = np.ascontiguousarray(np.dstack(
-            [rgb, np.full((*rgb.shape[:2], 1), 255, np.uint8)]))
+        arr = np.ascontiguousarray(
+            shrink_to_max_dim(arr[:, :, ::-1]))  # BGR → RGB
     h, w = arr.shape[:2]
     tw, th = tj.video_dimensions(w, h)
     _tm.THUMB_VIDEO_SECONDS.inc(grab.duration, part="frame")

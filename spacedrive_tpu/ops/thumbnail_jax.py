@@ -11,8 +11,8 @@ Parity targets (behavior, not implementation):
 
 TPU-first design. The reference resizes one image at a time on a CPU
 pool. Here, decoded images are padded into a small set of canvas
-*buckets* (squares + landscape halves; portraits transpose in — bounded
-XLA compile shapes) and a whole batch is resized in
+*buckets* (squares + landscape halves; a portrait transposes in where
+it does not fit as it stands) and a whole batch is resized in
 ONE device call per bucket via `jax.image.scale_and_translate`, vmapped
 with *per-image* scale factors as traced arguments — so a single
 compiled program handles arbitrary (h, w) inputs inside a bucket. XLA
@@ -27,8 +27,8 @@ planes go as `[B, bh, bw, 3]` canvases, an alpha plane goes beside them
 (`[B, bh, bw, 1]`, the same jitted function) only for the images that
 have one, every thumbnail comes back in the one landscape
 `OUT_CANVAS_HW` canvas, the host side of a canvas is a kept staging
-buffer, not a fresh mapping per call, and on the link the planes are
-folded into the row (`_resize_rows` says why).
+buffer that `pack` writes a frame and the filter's margin into, and on
+the link the planes are folded into the row (`_resize_rows` says why).
 """
 
 from __future__ import annotations
@@ -53,8 +53,8 @@ BUCKETS = (256, 512, 1024, 2048, 4096)
 # fall back to CPU.
 OUT_CANVAS = 1024
 MAX_ASPECT = (OUT_CANVAS * OUT_CANVAS) / TARGET_PX  # 4.0
-# The one output canvas, landscape: every portrait transposes in, so
-# th ≤ tw and th·tw ≈ TARGET_PX give th ≤ 512 at any aspect.
+# The one output canvas, landscape: a portrait whose target is higher
+# transposes in, so th ≤ tw and th·tw ≈ TARGET_PX give th ≤ 512.
 OUT_CANVAS_HW = (OUT_CANVAS // 2, OUT_CANVAS)
 
 
@@ -85,8 +85,8 @@ def bucket_for(h: int, w: int) -> tuple[int, int] | None:
     photos are 4:3/3:2/16:9, so the half canvas cuts the padded
     host→device transfer nearly 2× while keeping the compiled-shape
     count at 2 per ladder rung (the reason canvases exist at all:
-    SURVEY §7 hard part 3, shape bucketing vs recompilation). Portrait
-    images transpose into the landscape canvas on the host
+    SURVEY §7 hard part 3, shape bucketing vs recompilation). A portrait
+    that does not fit it as it stands transposes in on the host
     (resize_batch), so both orientations share one device call."""
     m = max(h, w)
     b = next((x for x in BUCKETS if m <= x), None)
@@ -191,7 +191,9 @@ def _resize_fn_sharded(devices):
 # seen and lent to one call at a time: a fresh 100 MiB mapping per call
 # pays a page fault per 4 KiB filled. Bounded, least recently used out
 # first, so a process that once resized a full batch of 4096² canvases
-# does not hold them for good.
+# does not hold them for good. A chunk of 32 RGB clips (2048², 384 MiB)
+# and its stills' canvas fit together; `sd_thumbnail_staging_total` says
+# whether a call found its canvas.
 _STAGING_MAX_BYTES = 512 << 20
 _staging: dict[tuple[int, int, int], np.ndarray] = {}
 _staging_lock = threading.Lock()
@@ -201,15 +203,21 @@ _staging_lock = threading.Lock()
 def _staging_canvas(bpad: int, bh: int, bw: int, planes: int):
     """Lend the [bpad, bh, bw, planes] staging canvas of a bucket for
     one device call. A caller that finds it lent (two actors resizing at
-    once) gets a canvas of its own. The canvas is taken back only when
-    the call has returned its output: on the CPU backend `device_put`
-    may alias the host array, and a call that failed may still be
-    reading it. Its bytes are whatever the last call left there."""
+    once) or too narrow gets a fresh one (counted `mapped`, else `kept`).
+    The canvas is taken back only when the call has returned its output:
+    on the CPU backend `device_put` may alias the host array, and a call
+    that failed may still be reading it. Its bytes are whatever the last
+    call left there, or nothing yet: `_pack_one` says what a call has to
+    write."""
+    from ..telemetry import metrics as _tm
+
     key = (bh, bw, planes)
     with _staging_lock:
         buf = _staging.pop(key, None)
-    if buf is None or buf.shape[0] < bpad:
+    kept = buf is not None and buf.shape[0] >= bpad
+    if not kept:
         buf = np.empty((bpad, bh, bw, planes), np.uint8)
+    _tm.THUMB_STAGING.inc(result="kept" if kept else "mapped")
     yield buf[:bpad]
     with _staging_lock:
         other = _staging.pop(key, None)
@@ -220,9 +228,45 @@ def _staging_canvas(bpad: int, bh: int, bw: int, planes: int):
             del _staging[next(iter(_staging))]
 
 
+def _pack_one(canvas: np.ndarray, img: np.ndarray,
+              scale: tuple[float, float]) -> int:
+    """Write `img` ([h, w, C]) into the top-left corner of its [bh, bw, C]
+    canvas with the margin the filter reads; → bytes written.
+
+    The margin: `scale_and_translate` with the triangle kernel and
+    `antialias=True` gives input row i a weight of exactly 0 for output
+    row o unless |(o + 0.5)/s − 0.5 − i| < max(1/s, 1), and output rows
+    past th = s·h are cropped on the host. So no kept pixel reads below
+    row h − 1 + ⌈max(1/s, 1)⌉, and likewise to the right: that many
+    rows and columns (one more, for float32's rounding of the bound)
+    are the image's edge replicated, so that the window clamps at the
+    image's boundary as the reference resampler does, and the corner
+    between them. Whatever the rest of the canvas holds is a finite
+    uint8 times a weight of 0, in the sum and in the weights' own
+    normalising sum alike, so nothing else is written.
+
+    A frame whose rows are contiguous goes in as one copy. Anything
+    else (a portrait transposed in, the colour planes of an RGBA still)
+    goes plane by plane: numpy copies a [w, h, 3] view against the
+    grain pixel by pixel, a [w, h] plane in blocks, 2-4 times faster."""
+    h, w, planes = img.shape
+    my, mx = (min(room, math.ceil(max(1.0 / s, 1.0)) + 1)
+              for room, s in zip((canvas.shape[0] - h, canvas.shape[1] - w),
+                                 scale))
+    if img.strides[1:] == (planes, 1):
+        canvas[:h, :w] = img
+    else:
+        for c in range(planes):
+            canvas[:h, :w, c] = img[..., c]
+    canvas[h:h + my, :w] = canvas[h - 1:h, :w]
+    canvas[:h + my, w:w + mx] = canvas[:h + my, w - 1:w]
+    return (h + my) * (w + mx) * planes
+
+
 def _resize_bucket(images, targets, bh: int, bw: int, devs) -> np.ndarray:
     """Pack one bucket's canvases and run its device call: `images` are
-    [h, w, C] uint8 arrays of one C, landscape, `targets` their (th, tw).
+    [h, w, C] uint8 arrays of one C that fit (bh, bw) as they are handed
+    in, `targets` their (th, tw).
     Returns the [bpad, OH, OW, C] uint8 result (validated — a device
     returning the wrong shape is an error the caller can demote on,
     never a silent corruption)."""
@@ -246,19 +290,14 @@ def _resize_bucket(images, targets, bh: int, bw: int, devs) -> np.ndarray:
     with _staging_canvas(bpad, bh, bw, n_planes) as canv:
         with _span("pack") as part:
             scales = np.ones((bpad, 2), np.float32)
+            written = 0
+            # canvases past the group are never written nor returned
             for j, (img, (th, tw)) in enumerate(zip(images, targets)):
-                h, w = img.shape[:2]
-                # Edge-replicate into the padding so the antialias window
-                # clamps at the image boundary instead of pulling in
-                # whatever the canvas held (the reference resampler
-                # clamps at edges too). Every byte of a used row is
-                # written; rows past the group are never returned.
-                canv[j, :h, :w] = img
-                canv[j, h:, :w] = img[h - 1 : h, :]
-                canv[j, :h, w:] = img[:, w - 1 : w]
-                canv[j, h:, w:] = img[h - 1, w - 1]
-                scales[j] = (th / h, tw / w)
+                scale = (th / img.shape[0], tw / img.shape[1])
+                scales[j] = scale
+                written += _pack_one(canv[j], img, scale)
         _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="pack")
+        _tm.THUMB_PACK_BYTES.inc(written)
         spec = _faults.hit("device.thumbnail")
         if spec is not None:
             if spec.mode == "raise":
@@ -361,12 +400,18 @@ def resize_batch(
     """Resize a batch of uint8 images, HxWx3 RGB or HxWx4 RGBA, to
     per-image (th, tw); a result has its input's channels.
 
-    Groups by input bucket, pads to the bucket canvas, runs one device
-    call per bucket for the colour planes and one more, through the same
+    Groups by input bucket, writes each image and the filter's margin
+    into the bucket's kept canvas (`_pack_one`), runs one device call
+    per bucket for the colour planes and one more, through the same
     program at one plane, for the alpha of those images that have it;
-    crops on host. Returns resized uint8 arrays in input order. Images
-    too large for any bucket or with th/tw beyond the output canvas
-    must be filtered by the caller beforehand.
+    crops on host. A portrait goes in as it stands where its bucket and
+    `OUT_CANVAS_HW` take it that way (a clip's 1920 × 1080 frame, bound
+    to 256 × 144), transposed where they do not (a photo: its target is
+    over 512 high); the two ways run the separable passes in the other
+    order, so a byte may differ by 1 where a float sum lands on .5.
+    Returns resized uint8 arrays in input order. Images too large for
+    any bucket or with th/tw beyond the output canvas must be filtered
+    by the caller beforehand.
 
     With >1 local device (or an explicit `devices` list) the batch dim
     of each bucket call dp-shards over the chip mesh — one dispatch,
@@ -380,10 +425,11 @@ def resize_batch(
     oh, ow = OUT_CANVAS_HW
     results: list[np.ndarray | None] = [None] * len(images)
     by_bucket: dict[tuple[int, int], list[int]] = {}
-    # every image rides its canvas in landscape: a portrait transposes in
-    # (cheap uint8 host view; un-transposed after the crop)
+    # a portrait that its bucket or the output canvas does not take as it
+    # stands transposes in (a view here; `_pack_one` makes the copy) and
+    # is un-transposed after the crop
     flip: list[bool] = [False] * len(images)
-    landscape: list[np.ndarray] = list(images)
+    placed: list[np.ndarray] = list(images)  # as each goes into its canvas
     canvas_targets = list(targets)
     for i, img in enumerate(images):
         if img.ndim != 3 or img.shape[2] not in (3, 4):
@@ -393,9 +439,9 @@ def resize_batch(
         if b is None:
             raise ValueError(f"image {i} ({h}x{w}) exceeds max bucket")
         th, tw = targets[i]
-        if h > w:
+        if h > w and (h > b[0] or th > oh or tw > ow):
             flip[i] = True
-            landscape[i] = np.transpose(img, (1, 0, 2))
+            placed[i] = np.transpose(img, (1, 0, 2))
             th, tw = canvas_targets[i] = (tw, th)
         if th > oh or tw > ow:
             raise ValueError(
@@ -403,14 +449,14 @@ def resize_batch(
         by_bucket.setdefault(b, []).append(i)
 
     def dispatch(members, channels, bh, bw):
-        group = [landscape[i][..., channels] for i in members]
+        group = [placed[i][..., channels] for i in members]
         want = [canvas_targets[i] for i in members]
         if devices is not None:
             return _resize_bucket(group, want, bh, bw, list(devices))
         return _resize_on_ladder(group, want, bh, bw)
 
     for (bh, bw), idxs in by_bucket.items():
-        with_alpha = [i for i in idxs if landscape[i].shape[2] == 4]
+        with_alpha = [i for i in idxs if placed[i].shape[2] == 4]
         colour = dispatch(idxs, slice(0, 3), bh, bw)
         alpha = dispatch(with_alpha, slice(3, 4), bh, bw) if with_alpha else None
         with _span("crop") as part:

@@ -141,9 +141,20 @@ THUMB_WORK_SECONDS = REGISTRY.histogram(
 THUMB_DEVICE_SECONDS = REGISTRY.counter(
     "sd_thumbnail_device_seconds",
     "the device stage of the thumbnailer by part, each blocked to its end: "
-    "pack (fill the staging canvases), put (host to device), run (dispatch "
-    "to block_until_ready), get (device to host), crop",
+    "pack (write the frames into the staging canvas), put (host to device), "
+    "run (dispatch to block_until_ready), get (device to host), crop",
     labels=("part",),  # pack | put | run | get | crop
+)
+THUMB_PACK_BYTES = REGISTRY.counter(
+    "sd_thumbnail_pack_bytes_total",
+    "bytes `pack` wrote into staging canvases: each frame and the margin "
+    "of replicated edge the filter reads, added once a bucket call",
+)
+THUMB_STAGING = REGISTRY.counter(
+    "sd_thumbnail_staging_total",
+    "bucket calls by whether the kept staging canvas was there (kept) or "
+    "one had to be allocated: first use, a wider pad, lent, evicted (mapped)",
+    labels=("result",),  # kept | mapped
 )
 THUMB_DEVICE_BYTES = REGISTRY.counter(
     "sd_thumbnail_device_bytes_total",
@@ -168,7 +179,7 @@ THUMB_VIDEO_SECONDS = REGISTRY.counter(
     "sd_thumbnail_video_seconds",
     "seconds a clip's thumbnail costs beside a still's, on the worker "
     "threads: frame (open, seek, decode one frame, to RGB), orient "
-    "(display-matrix rotation, the oversize stride, cv2's alpha plane), "
+    "(display-matrix rotation, the oversize stride, cv2's BGR to RGB copy), "
     "overlay (the film strips, after the resize)",
     labels=("part",),  # frame | orient | overlay
 )

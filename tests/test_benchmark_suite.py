@@ -18,6 +18,20 @@ FILES = sorted(os.path.relpath(p, ROOT) for p in glob.glob(
 LIMIT_S = 900
 
 
+#: cases left out, each with the tier-1 case that holds its substance
+#: instead. Only a `benchmark` PR may edit a file of the benchmark, so a
+#: case there that pins what a later PR of another kind changes in the
+#: program waits here for one (PERF.md §7).
+LEFT_OUT = {
+    # pins the clips' warm-up names to `x4`, the RGBA frame PR 32's
+    # decoder handed on; the frame is RGB since PR 33 and the name
+    # follows it: tests/test_photolib_video.py::
+    # test_a_clips_programs_are_named_by_the_channels_its_decode_hands_on
+    "benchmark/tests/test_video_kind_cpu.py":
+        ["test_programs_are_named_from_the_programs_own_tables"],
+}
+
+
 def test_there_are_files_to_hold():
     assert len(FILES) >= 13
 
@@ -28,8 +42,10 @@ def test_benchmark_tests_pass(path):
     # eight), no word of the worker that runs this case
     env = {k: v for k, v in os.environ.items()
            if k != "XLA_FLAGS" and not k.startswith("PYTEST_")}
+    left_out = [f"--deselect={path}::{case}" for case in LEFT_OUT.get(path, [])]
     done = subprocess.run(
-        [sys.executable, "-m", "pytest", path, "-q", "-p", "no:cacheprovider"],
+        [sys.executable, "-m", "pytest", path, "-q", "-p", "no:cacheprovider",
+         *left_out],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=LIMIT_S)
     assert done.returncode == 0, (done.stdout[-3000:], done.stderr[-1000:])
     assert " passed" in done.stdout and " failed" not in done.stdout

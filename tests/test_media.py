@@ -82,7 +82,7 @@ def test_video_thumbnail_via_cv2(tmp_path, monkeypatch):
         vw.write(frame)
     vw.release()
     d = process.decode_video_frame(path)
-    assert d.array.shape[2] == 4 and d.array.shape[0] > 0
+    assert d.array.shape[2] == 3 and d.array.shape[0] > 0  # RGB, no 255 plane
     webp = process.generate_one_cpu(path, "mp4")
     assert webp[:4] == b"RIFF" and webp[8:12] == b"WEBP"
 
@@ -176,9 +176,12 @@ def test_native_video_rotation_applied(tmp_path):
     not __import__("spacedrive_tpu.native", fromlist=["x"]).video_available(),
     reason="libav unavailable",
 )
-def test_native_embedded_cover_preference(tmp_path):
+@pytest.mark.parametrize("fmt,mode,channels", [
+    ("JPEG", "RGB", 3), ("PNG", "RGBA", 4)], ids=["jpeg", "png_with_alpha"])
+def test_native_embedded_cover_preference(tmp_path, fmt, mode, channels):
     """A media file with attached cover art thumbnails from the cover,
-    not a decoded frame (ref:movie_decoder.rs:352)."""
+    not a decoded frame (ref:movie_decoder.rs:352); the frontend hands
+    on alpha only where the decoded format has it."""
     import io
     import struct
 
@@ -187,9 +190,10 @@ def test_native_embedded_cover_preference(tmp_path):
     from spacedrive_tpu import native
 
     jpg = io.BytesIO()
-    Image.new("RGB", (64, 48), (250, 200, 10)).save(jpg, "JPEG")
+    Image.new(mode, (64, 48), (250, 200, 10, 77)[:len(mode)]).save(jpg, fmt)
     jpeg = jpg.getvalue()
-    apic = b"\x00" + b"image/jpeg\x00" + b"\x03" + b"cover\x00" + jpeg
+    apic = (b"\x00" + f"image/{fmt.lower()}".encode() + b"\x00" + b"\x03"
+            + b"cover\x00" + jpeg)
 
     def synchsafe(n):
         return bytes([(n >> 21) & 0x7F, (n >> 14) & 0x7F,
@@ -203,8 +207,12 @@ def test_native_embedded_cover_preference(tmp_path):
 
     arr, rotation, is_cover = native.video_frame(str(p))
     assert is_cover and rotation == 0
-    assert arr.shape[:2] == (48, 64)
+    assert arr.shape == (48, 64, channels)
     assert arr[10, 10, 0] > 200 and arr[10, 10, 2] < 80  # the yellow art
+    if channels == 4:
+        assert (arr[..., 3] == 77).all()
+        d = process.decode_video_frame(str(p))
+        assert d.array.shape == (48, 64, 4) and not d.is_video
 
 
 @pytest.mark.skipif(

@@ -1,7 +1,9 @@
 """The canvases of the thumbnailer's device stage (ISSUE 28): three
 colour planes where the image has no alpha, an alpha plane beside them
 where it has, one landscape 512 × 1024 output canvas, a kept staging
-buffer; and the contract `benchmark/warm.py` holds the stage to."""
+buffer; what `pack` writes into it (ISSUE 33): a frame once, the margin
+the filter reads, a portrait as it stands where its canvas takes it;
+and the contract `benchmark/warm.py` holds the stage to."""
 
 import io
 import sys
@@ -13,6 +15,7 @@ from PIL import Image
 
 from spacedrive_tpu.object.media.thumbnail import Thumbnailer, process
 from spacedrive_tpu.ops import thumbnail_jax as tj
+from spacedrive_tpu.telemetry import metrics as tm
 
 RNG = np.random.default_rng(28)
 
@@ -157,7 +160,8 @@ def test_second_call_through_the_kept_canvas_returns_nothing_of_the_first(
     tj.resize_batch(bright, _targets(bright))
     key = (1024, 1024, 3)
     kept = staging[key]
-    assert kept.shape[0] >= 4 and (kept[:4] == 255).all()
+    assert kept.shape[0] >= 4 and (kept[:4, :700, :1000] == 255).all()
+    kept[...] = 255  # the most a call could have left there
     dark = [np.zeros((520, 640, 3), np.uint8)]
     out = tj.resize_batch(dark, _targets(dark))[0]
     assert out.shape == (*_targets(dark)[0], 3)
@@ -165,6 +169,35 @@ def test_second_call_through_the_kept_canvas_returns_nothing_of_the_first(
     # one canvas per (bucket, planes), the same one, and never the result
     assert staging[key] is kept and list(staging) == [key]
     assert not np.shares_memory(out, kept)
+
+
+def _staging_counts() -> dict:
+    return {r: tm.THUMB_STAGING.value(result=r) for r in ("kept", "mapped")}
+
+
+def test_second_call_of_a_shape_gets_the_canvas_the_first_left(staging):
+    frames = [_photo(180, 320, 3), _photo(320, 180, 3), _photo(200, 300)]
+    start, wrote = _staging_counts(), tm.THUMB_PACK_BYTES.value()
+    first = tj.resize_batch(frames, _targets(frames))
+    mapped = _staging_counts()
+    # one bucket: a colour canvas for the three, an alpha canvas for one
+    assert mapped == {"kept": start["kept"], "mapped": start["mapped"] + 2}
+    assert set(staging) == {(512, 512, 3), (512, 512, 1)}
+    canvases = {k: v.ctypes.data for k, v in staging.items()}
+    # a frame and its margin of ceil(1/s) + 1 = 2 rows and columns, once
+    assert tm.THUMB_PACK_BYTES.value() - wrote == \
+        2 * 182 * 322 * 3 + 202 * 302 * 3 + 202 * 302 * 1
+    again = tj.resize_batch(frames[::-1], _targets(frames[::-1]))
+    assert _staging_counts() == {"kept": mapped["kept"] + 2,
+                                 "mapped": mapped["mapped"]}
+    assert {k: v.ctypes.data for k, v in staging.items()} == canvases
+    for a, b in zip(first, again[::-1]):
+        assert np.array_equal(a, b)
+    # a wider pad than the kept canvas has maps a new one, and keeps that
+    tj.resize_batch(frames[:2] * 3, _targets(frames[:2] * 3))
+    assert _staging_counts() == {"kept": mapped["kept"] + 2,
+                                 "mapped": mapped["mapped"] + 1}
+    assert staging[(512, 512, 3)].shape[0] == 8
 
 
 def test_kept_canvases_are_bounded_least_recently_used_first(
@@ -210,6 +243,101 @@ def test_two_threads_resizing_at_once_get_a_canvas_each(staging):
         sys.setswitchinterval(interval)
     assert not failures
     assert list(staging) == [(512, 512, 3)]
+
+
+# (e) what `pack` writes: the frame and the filter's margin are enough
+
+
+def _fill_edges(h: int, w: int, bh: int, bw: int, img: np.ndarray):
+    """The whole canvas as the program before ISSUE 33 filled it."""
+    return np.pad(img, ((0, bh - h), (0, bw - w), (0, 0)), mode="edge")
+
+
+def _noise(shape):
+    return np.random.default_rng(33).integers(0, 256, shape, dtype=np.uint8)
+
+
+FILLS = {"ff": lambda shape: np.full(shape, 0xFF, np.uint8),
+         "00": lambda shape: np.zeros(shape, np.uint8),
+         "noise": _noise}
+
+
+@pytest.mark.parametrize("scale", [0.1, 0.23, 0.5, 0.77, 1.0])
+@pytest.mark.parametrize("channels", [3, 4])
+@pytest.mark.parametrize("bucket,shapes", [
+    ((256, 256), [(131, 233), (250, 256)]),   # landscape in a square
+    ((256, 256), [(233, 131), (256, 250)]),   # portrait, as it stands
+    ((512, 1024), [(300, 900), (505, 1021)]),  # landscape in a half
+    ((512, 1024), [(900, 300), (1021, 505)]),  # portrait, transposed in
+], ids=["square_landscape", "square_portrait", "half_landscape",
+        "half_portrait"])
+def test_nothing_unwritten_in_a_canvas_reaches_a_result(
+        staging, bucket, shapes, channels, scale):
+    """Whatever the canvas held before the call (0xFF, 0x00, noise), the
+    result is byte for byte what a canvas filled with replicated edges
+    to its last byte gives: the margin is all the filter reads."""
+    bh, bw = bucket
+    images = [_photo(h, w, channels) for h, w in shapes]
+    targets = [(max(1, round(h * scale)), max(1, round(w * scale)))
+               for h, w in shapes]
+    for h, w in shapes:
+        assert tj.bucket_for(h, w) == bucket
+    results = {}
+    for name, fill in FILLS.items():
+        staging.clear()
+        for planes in (3, 1):
+            staging[(bh, bw, planes)] = fill((2, bh, bw, planes))
+        results[name] = tj.resize_batch(images, targets)
+    # the parent's canvas: every byte an edge of the image it holds
+    staging.clear()
+    as_packed = [img if (h <= bh and th <= tj.OUT_CANVAS_HW[0])
+                 else np.transpose(img, (1, 0, 2))
+                 for img, (h, _w), (th, _tw) in zip(images, shapes, targets)]
+    for planes, chans in ((3, slice(0, 3)), (1, slice(3, 4))):
+        if planes == 1 and channels == 3:
+            continue
+        staging[(bh, bw, planes)] = np.stack([
+            _fill_edges(*a.shape[:2], bh, bw, a[..., chans])
+            for a in as_packed])
+    results["edges"] = tj.resize_batch(images, targets)
+    for name, outs in results.items():
+        for out, want, t in zip(outs, results["edges"], targets):
+            assert out.shape == (*t, channels)
+            assert np.array_equal(out, want), name
+
+
+@pytest.mark.parametrize("h,w,target", [
+    (240, 135, (256, 144)),     # as a clip's frame: 1920 x 1080 by 8
+    (233, 131, (58, 33)), (256, 250, (201, 196)), (200, 64, (100, 32)),
+], ids=lambda v: str(v))
+def test_a_portrait_as_it_stands_is_the_flipped_path_within_one(
+        h, w, target):
+    """Left as it stands, the two separable passes run in the other
+    order: a byte differs by 1 where a float sum lands on .5."""
+    th, tw = min(target[0], h), min(target[1], w)
+    img = _photo(h, w, 3)
+    stands = tj.resize_batch([img], [(th, tw)])[0]
+    # the same pixels on the path a landscape takes, turned back
+    flipped = np.transpose(tj.resize_batch(
+        [np.ascontiguousarray(np.transpose(img, (1, 0, 2)))],
+        [(tw, th)])[0], (1, 0, 2))
+    assert stands.shape == flipped.shape == (th, tw, 3)
+    gap = np.abs(stands.astype(int) - flipped.astype(int))
+    assert gap.max() <= 1
+    assert (gap != 0).mean() < 0.01, int((gap != 0).sum())
+
+
+def test_a_portrait_transposes_in_only_where_it_has_to(staging):
+    """Which canvas rows a frame filled says which way it went in."""
+    clip = _photo(240, 135, 3)      # fits (256, 256) and its target fits
+    photo = _photo(700, 600, 3)     # fits (1024, 1024); target 553 high
+    tj.resize_batch([clip], [(128, 72)])
+    assert np.array_equal(staging[(256, 256, 3)][0, :240, :135], clip)
+    out = tj.resize_batch([photo], _targets([photo]))[0]
+    assert _targets([photo])[0][0] > tj.OUT_CANVAS_HW[0]
+    assert np.array_equal(staging[(1024, 1024, 3)][0, :600, :700],
+                          np.transpose(photo, (1, 0, 2)))
+    assert out.shape == (*_targets([photo])[0], 3)
 
 
 # the warm-up contract (benchmark/warm.py:75-90, 131-135): warm.py runs ONE
