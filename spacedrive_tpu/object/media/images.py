@@ -12,6 +12,7 @@ way via cargo features).
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import ctypes.util
 import os
@@ -92,6 +93,24 @@ def _load_heif() -> ctypes.CDLL | None:
     ]
     lib.heif_image_release.argtypes = [ctypes.c_void_p]
     lib.heif_image_handle_release.argtypes = [ctypes.c_void_p]
+    # the container's own facts and metadata blocks (`heif_container`)
+    for name in ("heif_image_handle_get_ispe_width",
+                 "heif_image_handle_get_ispe_height"):
+        getattr(lib, name).restype = ctypes.c_int
+        getattr(lib, name).argtypes = [ctypes.c_void_p]
+    lib.heif_image_handle_get_list_of_metadata_block_IDs.restype = ctypes.c_int
+    lib.heif_image_handle_get_list_of_metadata_block_IDs.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_uint32), ctypes.c_int,
+    ]
+    lib.heif_image_handle_get_metadata_size.restype = ctypes.c_size_t
+    lib.heif_image_handle_get_metadata_size.argtypes = [
+        ctypes.c_void_p, ctypes.c_uint32,
+    ]
+    lib.heif_image_handle_get_metadata.restype = _HeifError
+    lib.heif_image_handle_get_metadata.argtypes = [
+        ctypes.c_void_p, ctypes.c_uint32, ctypes.c_void_p,
+    ]
     lib.heif_context_free.argtypes = [ctypes.c_void_p]
     _heif = lib
     return lib
@@ -101,59 +120,99 @@ def heif_available() -> bool:
     return _load_heif() is not None
 
 
-def decode_heif(path: str) -> np.ndarray:
-    """HEIC/HEIF/AVIF → RGBA uint8 via the system libheif (the same C
-    library the reference links, ref:crates/images/Cargo.toml:13,32)."""
+def _heif_check(err: _HeifError, stage: str) -> None:
+    if err.code != 0:
+        msg = err.message.decode() if err.message else "?"
+        raise ImageHandlerError(f"libheif {stage}: {msg} (code {err.code})")
+
+
+@contextlib.contextmanager
+def _heif_primary(path: str):
+    """Open a HEIF container and lend (lib, handle of its primary
+    image); both are released when the caller is done."""
     lib = _load_heif()
     if lib is None:
         raise UnsupportedImage("libheif not available")
-
-    def check(err: _HeifError, stage: str) -> None:
-        if err.code != 0:
-            msg = err.message.decode() if err.message else "?"
-            raise ImageHandlerError(f"libheif {stage}: {msg} (code {err.code})")
-
     ctx = lib.heif_context_alloc()
     if not ctx:
         raise ImageHandlerError("heif_context_alloc failed")
     handle = ctypes.c_void_p()
-    img = ctypes.c_void_p()
     try:
-        check(
+        _heif_check(
             lib.heif_context_read_from_file(ctx, os.fsencode(path), None), "read"
         )
-        check(
+        _heif_check(
             lib.heif_context_get_primary_image_handle(
                 ctx, ctypes.byref(handle)
             ),
             "primary handle",
         )
-        check(
-            lib.heif_decode_image(
-                handle,
-                ctypes.byref(img),
-                _HEIF_COLORSPACE_RGB,
-                _HEIF_CHROMA_INTERLEAVED_RGBA,
-                None,
-            ),
-            "decode",
-        )
-        width = lib.heif_image_handle_get_width(handle)
-        height = lib.heif_image_handle_get_height(handle)
-        stride = ctypes.c_int()
-        plane = lib.heif_image_get_plane_readonly(
-            img, _HEIF_CHANNEL_INTERLEAVED, ctypes.byref(stride)
-        )
-        if not plane:
-            raise ImageHandlerError("heif: no interleaved plane")
-        buf = np.ctypeslib.as_array(plane, shape=(height, stride.value))
-        return buf[:, : width * 4].reshape(height, width, 4).copy()
+        yield lib, handle
     finally:
-        if img:
-            lib.heif_image_release(img)
         if handle:
             lib.heif_image_handle_release(handle)
         lib.heif_context_free(ctx)
+
+
+def heif_container(path: str) -> tuple[tuple[int, int], bytes | None]:
+    """What a HEIC/HEIF/AVIF container says of its primary image
+    without decoding it: → ((width, height), EXIF). The size is the
+    item's `ispe` (the handle's own, transforms applied, where there is
+    none). The EXIF block is the first metadata item of the type `Exif`:
+    its first four bytes are the big-endian offset from their end to
+    the TIFF header (ISO 23008-12 A.2.1; a phone's `Exif\\0\\0`
+    prefix makes it 6), and what is handed on starts at that header, as
+    `PIL.Image.Exif.load` takes it; None where the item is absent or
+    too short to hold a header."""
+    with _heif_primary(path) as (lib, handle):
+        size = (lib.heif_image_handle_get_ispe_width(handle),
+                lib.heif_image_handle_get_ispe_height(handle))
+        if min(size) <= 0:
+            size = (lib.heif_image_handle_get_width(handle),
+                    lib.heif_image_handle_get_height(handle))
+        block_id = ctypes.c_uint32()
+        if lib.heif_image_handle_get_list_of_metadata_block_IDs(
+                handle, b"Exif", ctypes.byref(block_id), 1) != 1:
+            return size, None
+        n = lib.heif_image_handle_get_metadata_size(handle, block_id)
+        buf = ctypes.create_string_buffer(n)
+        _heif_check(
+            lib.heif_image_handle_get_metadata(handle, block_id, buf), "exif"
+        )
+        raw = buf.raw
+        start = 4 + int.from_bytes(raw[:4], "big")
+        return size, raw[start:] if n >= 4 and start + 8 <= n else None
+
+
+def decode_heif(path: str) -> np.ndarray:
+    """HEIC/HEIF/AVIF → RGBA uint8 via the system libheif (the same C
+    library the reference links, ref:crates/images/Cargo.toml:13,32)."""
+    with _heif_primary(path) as (lib, handle):
+        img = ctypes.c_void_p()
+        try:
+            _heif_check(
+                lib.heif_decode_image(
+                    handle,
+                    ctypes.byref(img),
+                    _HEIF_COLORSPACE_RGB,
+                    _HEIF_CHROMA_INTERLEAVED_RGBA,
+                    None,
+                ),
+                "decode",
+            )
+            width = lib.heif_image_handle_get_width(handle)
+            height = lib.heif_image_handle_get_height(handle)
+            stride = ctypes.c_int()
+            plane = lib.heif_image_get_plane_readonly(
+                img, _HEIF_CHANNEL_INTERLEAVED, ctypes.byref(stride)
+            )
+            if not plane:
+                raise ImageHandlerError("heif: no interleaved plane")
+            buf = np.ctypeslib.as_array(plane, shape=(height, stride.value))
+            return buf[:, : width * 4].reshape(height, width, 4).copy()
+        finally:
+            if img:
+                lib.heif_image_release(img)
 
 
 # --- generic + dispatch ---------------------------------------------------

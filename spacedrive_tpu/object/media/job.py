@@ -42,6 +42,7 @@ def _media_digest(cols: dict) -> str:
 # extensions we can thumbnail / extract exif from (decodable subset of
 # the reference's FILTERED_{IMAGE,VIDEO}_EXTENSIONS; videos get a
 # keyframe thumb, ref:media_processor/job.rs + thumbnail/process.rs:463)
+from .images import HEIF_EXTENSIONS, heif_available
 from .thumbnail.process import (
     DOC_EXTENSIONS,
     IMAGE_EXTENSIONS,
@@ -51,7 +52,10 @@ from .thumbnail.process import (
 THUMBNAILABLE_EXTENSIONS = (
     tuple(IMAGE_EXTENSIONS) + tuple(VIDEO_EXTENSIONS) + tuple(DOC_EXTENSIONS)
 )
-EXIF_EXTENSIONS = ("jpg", "jpeg", "png", "tiff", "webp")
+# PIL reads the first five; the EXIF item of a HEIF container is read
+# through libheif (`images.heif_container`), so those only where it loads
+EXIF_EXTENSIONS = ("jpg", "jpeg", "png", "tiff", "webp") + (
+    tuple(sorted(HEIF_EXTENSIONS)) if heif_available() else ())
 # media_data rows extract for EXIF-bearing images AND videos
 # (ref:media_data_extractor.rs images; video facts via the decoder)
 MEDIA_DATA_EXTENSIONS = EXIF_EXTENSIONS + tuple(VIDEO_EXTENSIONS)
@@ -351,6 +355,10 @@ class MediaProcessorJob(StatefulJob):
                     meta = VideoMetadata.from_path(full)
                 _tm.MEDIA_EXTRACT_SECONDS.observe(
                     probe.duration, kind="video")
+            elif ext in HEIF_EXTENSIONS:
+                with span("heif") as read:
+                    meta = ImageMetadata.from_path(full)
+                _tm.MEDIA_EXTRACT_SECONDS.observe(read.duration, kind="heif")
             else:
                 t0 = time.perf_counter()
                 meta = ImageMetadata.from_path(full)

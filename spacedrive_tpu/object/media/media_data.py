@@ -66,56 +66,88 @@ class ImageMetadata:
 
     @classmethod
     def from_path(cls, path: str | os.PathLike) -> "ImageMetadata | None":
+        """PIL opens the file and hands on its EXIF; a HEIF container
+        (HEIC, HEIF, AVIF), which PIL does not open, is read through
+        libheif (`images.heif_container`: the size and the EXIF item,
+        no decode). ref: the media-data extractor takes HEIF, HEIC and
+        AVIF as it takes JPEG."""
         try:
             from PIL import ExifTags, Image
 
+            from .images import HEIF_EXTENSIONS, heif_container
+
+            ext = os.path.splitext(os.fspath(path))[1].lstrip(".").lower()
+            if ext in HEIF_EXTENSIONS:
+                size, block = heif_container(os.fspath(path))
+                meta = cls(resolution=size)
+                if block is None:
+                    return meta
+                exif = Image.Exif()
+                exif.load(block)
+                meta._read_exif(exif)
+                # the EXIF's pixel dimensions are the sensor's; the
+                # container's size may be the stored or the displayed
+                # picture's, by who wrote it (libheif 1.15 writes a
+                # quarter-turned item's `ispe` as displayed)
+                sub = exif.get_ifd(ExifTags.IFD.Exif)
+                w = sub.get(ExifTags.Base.ExifImageWidth)
+                h = sub.get(ExifTags.Base.ExifImageHeight)
+                if isinstance(w, int) and isinstance(h, int) and min(w, h) > 0:
+                    meta.resolution = (w, h)
+                return meta
             with Image.open(path) as im:
                 meta = cls(resolution=(im.width, im.height))
                 exif = im.getexif()
-                if not exif:
-                    return meta
-                tags = {ExifTags.TAGS.get(k, k): v for k, v in exif.items()}
-                ifd = {}
-                try:
-                    raw_ifd = exif.get_ifd(ExifTags.IFD.Exif)
-                    ifd = {ExifTags.TAGS.get(k, k): v for k, v in raw_ifd.items()}
-                except Exception:  # noqa: BLE001
-                    pass
-
-                dt = ifd.get("DateTimeOriginal") or tags.get("DateTime")
-                if isinstance(dt, str):
-                    meta.date_taken = dt
-                    try:
-                        parsed = _dt.datetime.strptime(dt, "%Y:%m:%d %H:%M:%S")
-                        meta.epoch_time = int(parsed.timestamp())
-                    except ValueError:
-                        pass
-                meta.artist = _s(tags.get("Artist"))
-                meta.description = _s(tags.get("ImageDescription"))
-                meta.copyright = _s(tags.get("Copyright"))
-                ev = ifd.get("ExifVersion")
-                if isinstance(ev, bytes):
-                    meta.exif_version = ev.decode("ascii", "ignore")
-                cam = meta.camera_data
-                cam.device_make = _s(tags.get("Make"))
-                cam.device_model = _s(tags.get("Model"))
-                cam.orientation = int(tags.get("Orientation") or ORIENTATION_NORMAL)
-                cam.lens_make = _s(ifd.get("LensMake"))
-                cam.lens_model = _s(ifd.get("LensModel"))
-                fl = ifd.get("FocalLength")
-                cam.focal_length = float(fl) if fl is not None else None
-                ap = ifd.get("FNumber")
-                cam.aperture = float(ap) if ap is not None else None
-                iso = ifd.get("ISOSpeedRatings")
-                cam.iso = int(iso) if isinstance(iso, (int, float)) else None
-                fl_ = ifd.get("Flash")
-                cam.flash = bool(int(fl_) & 1) if isinstance(fl_, (int, float)) else None
-
-                meta.location = _gps(exif)
+                if exif:
+                    meta._read_exif(exif)
                 return meta
         except Exception as e:  # noqa: BLE001 - any decode failure = no metadata
             logger.debug("exif extraction failed for %s: %s", path, e)
             return None
+
+    def _read_exif(self, exif) -> None:
+        """Fill the fields from a `PIL.Image.Exif`, wherever it came
+        from (a file PIL opened, a HEIF container's item)."""
+        from PIL import ExifTags
+
+        tags = {ExifTags.TAGS.get(k, k): v for k, v in exif.items()}
+        ifd = {}
+        try:
+            raw_ifd = exif.get_ifd(ExifTags.IFD.Exif)
+            ifd = {ExifTags.TAGS.get(k, k): v for k, v in raw_ifd.items()}
+        except Exception:  # noqa: BLE001
+            pass
+
+        dt = ifd.get("DateTimeOriginal") or tags.get("DateTime")
+        if isinstance(dt, str):
+            self.date_taken = dt
+            try:
+                parsed = _dt.datetime.strptime(dt, "%Y:%m:%d %H:%M:%S")
+                self.epoch_time = int(parsed.timestamp())
+            except ValueError:
+                pass
+        self.artist = _s(tags.get("Artist"))
+        self.description = _s(tags.get("ImageDescription"))
+        self.copyright = _s(tags.get("Copyright"))
+        ev = ifd.get("ExifVersion")
+        if isinstance(ev, bytes):
+            self.exif_version = ev.decode("ascii", "ignore")
+        cam = self.camera_data
+        cam.device_make = _s(tags.get("Make"))
+        cam.device_model = _s(tags.get("Model"))
+        cam.orientation = int(tags.get("Orientation") or ORIENTATION_NORMAL)
+        cam.lens_make = _s(ifd.get("LensMake"))
+        cam.lens_model = _s(ifd.get("LensModel"))
+        fl = ifd.get("FocalLength")
+        cam.focal_length = float(fl) if fl is not None else None
+        ap = ifd.get("FNumber")
+        cam.aperture = float(ap) if ap is not None else None
+        iso = ifd.get("ISOSpeedRatings")
+        cam.iso = int(iso) if isinstance(iso, (int, float)) else None
+        fl_ = ifd.get("Flash")
+        cam.flash = bool(int(fl_) & 1) if isinstance(fl_, (int, float)) else None
+
+        self.location = _gps(exif)
 
     # --- persistence into media_data (ref:schema.prisma:281-310) ---
 

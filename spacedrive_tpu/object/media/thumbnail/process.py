@@ -47,8 +47,10 @@ def shrink_to_max_dim(arr: "np.ndarray") -> "np.ndarray":
 # Decodable subsets of the taxonomy (the taxonomy stays the single
 # source of truth, ref:crates/file-ext; the reference fans out to the
 # `image` crate / libheif / resvg / pdfium by extension,
-# ref:crates/images/src/handler.rs:18-60 — HEIF/PDF need their own
-# decoders and are gated out here until a native frontend lands).
+# ref:crates/images/src/handler.rs:18-60 — here PIL takes the generic
+# formats, HEIF goes through the ctypes binding over the system's
+# libheif (`images.decode_heif`) and is listed only where that library
+# loads, SVG and PDF ride their own renderers below).
 from ....files.extensions import all_extensions as _all_extensions
 
 _PIL_DECODABLE = {
@@ -243,13 +245,27 @@ def decode_video_frame(path: str) -> Decoded:
 def decode_heif_image(path: str, extension: str,
                       tap: FrameTap | None = None) -> Decoded:
     """HEIC/HEIF/AVIF through the libheif dispatch (ref:crates/images
-    HEIF handler); orientation is baked in by libheif's transforms."""
-    arr = format_image(path, extension)
+    HEIF handler), RGBA at the picture's full size: libheif scales
+    nothing on its way out. Orientation is baked in by libheif's
+    transforms (the container's `irot`/`imir`), so the EXIF tag, which
+    says the same, is not applied again. A file libheif does not take
+    is a ThumbError: it costs its own thumbnail, never its batch's."""
+    try:
+        with span("heif.decode") as call:
+            arr = format_image(path, extension)
+    except Exception as exc:
+        _tm.THUMB_HEIF_FRAMES.inc(result="error")
+        raise ThumbError(f"heif decode failed ({path}): {exc}")
+    _tm.THUMB_HEIF_SECONDS.inc(call.duration, part="decode")
     if tap is not None:
+        t0 = time.perf_counter()
         tap(arr, 1)
+        _tm.THUMB_HEIF_SECONDS.inc(time.perf_counter() - t0, part="plane")
     arr = shrink_to_max_dim(arr)
     h, w = arr.shape[:2]
     tw, th = tj.scale_dimensions(w, h)
+    _tm.THUMB_HEIF_FRAMES.inc(result="ok")
+    _tm.THUMB_HEIF_BYTES.inc(arr.nbytes)
     return Decoded(array=arr, target=(th, tw))
 
 

@@ -187,11 +187,30 @@ THUMB_VIDEO_BYTES = REGISTRY.counter(
     "sd_thumbnail_video_bytes_total",
     "nbytes of the video frames handed to the resize",
 )
+THUMB_HEIF_FRAMES = REGISTRY.counter(
+    "sd_thumbnail_heif_frames_total",
+    "HEIC/HEIF/AVIF files the thumbnailer asked libheif to decode, by "
+    "outcome",
+    labels=("result",),  # ok | error
+)
+THUMB_HEIF_SECONDS = REGISTRY.counter(
+    "sd_thumbnail_heif_seconds",
+    "seconds a HEIF still costs on the decode workers: decode (the libheif "
+    "call: read the container, decode the primary item at full size, the "
+    "container's transforms, to RGBA), plane (the submitter's tap on the "
+    "frame: the embedder's 32 x 32 plane from the full-size array)",
+    labels=("part",),  # decode | plane
+)
+THUMB_HEIF_BYTES = REGISTRY.counter(
+    "sd_thumbnail_heif_bytes_total",
+    "nbytes of the HEIF frames handed to the resize",
+)
 MEDIA_EXTRACT_SECONDS = REGISTRY.histogram(
     "sd_media_extract_seconds",
-    "the media job's metadata step, per file: EXIF of an image, the "
+    "the media job's metadata step, per file: EXIF of an image PIL opens, "
+    "the EXIF item read out of a HEIF container through libheif, the "
     "container probe of a clip (a second open, after the thumbnailer's)",
-    labels=("kind",),  # image | video
+    labels=("kind",),  # image | heif | video
 )
 
 # --- semantic search (models/embedder.py, object/search/index.py) -----------
