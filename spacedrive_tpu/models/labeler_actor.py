@@ -391,8 +391,8 @@ class ImageLabeler:
         out: list[np.ndarray | None] = []
         for entry in chunk:
             try:
-                rgba = format_image(entry["path"])
-                img = Image.fromarray(rgba).convert("RGB").resize(
+                frame = format_image(entry["path"])  # RGB or RGBA
+                img = Image.fromarray(frame).convert("RGB").resize(
                     (self.image_size, self.image_size)
                 )
                 out.append(np.asarray(img, np.float32) / 255.0)

@@ -54,6 +54,7 @@ class _HeifError(ctypes.Structure):
 
 
 _HEIF_COLORSPACE_RGB = 1
+_HEIF_CHROMA_INTERLEAVED_RGB = 10
 _HEIF_CHROMA_INTERLEAVED_RGBA = 11
 _HEIF_CHANNEL_INTERLEAVED = 10
 
@@ -87,6 +88,8 @@ def _load_heif() -> ctypes.CDLL | None:
     lib.heif_image_handle_get_width.argtypes = [ctypes.c_void_p]
     lib.heif_image_handle_get_height.restype = ctypes.c_int
     lib.heif_image_handle_get_height.argtypes = [ctypes.c_void_p]
+    lib.heif_image_handle_has_alpha_channel.restype = ctypes.c_int
+    lib.heif_image_handle_has_alpha_channel.argtypes = [ctypes.c_void_p]
     lib.heif_image_get_plane_readonly.restype = ctypes.POINTER(ctypes.c_uint8)
     lib.heif_image_get_plane_readonly.argtypes = [
         ctypes.c_void_p, ctypes.c_int, ctypes.POINTER(ctypes.c_int),
@@ -185,9 +188,18 @@ def heif_container(path: str) -> tuple[tuple[int, int], bytes | None]:
 
 
 def decode_heif(path: str) -> np.ndarray:
-    """HEIC/HEIF/AVIF → RGBA uint8 via the system libheif (the same C
-    library the reference links, ref:crates/images/Cargo.toml:13,32)."""
+    """HEIC/HEIF/AVIF → uint8 via the system libheif (the same C
+    library the reference links, ref:crates/images/Cargo.toml:13,32),
+    with the channels the file holds: a tight [h, w, 3] RGB array where
+    the handle reports no alpha channel (a camera's photo), [h, w, 4]
+    RGBA where it reports one (a sticker, a cut-out). The resize, the
+    webp and the embedder's plane all go by the array's channels, so a
+    photo pays for no constant fourth byte downstream."""
     with _heif_primary(path) as (lib, handle):
+        if lib.heif_image_handle_has_alpha_channel(handle):
+            chroma, channels = _HEIF_CHROMA_INTERLEAVED_RGBA, 4
+        else:
+            chroma, channels = _HEIF_CHROMA_INTERLEAVED_RGB, 3
         img = ctypes.c_void_p()
         try:
             _heif_check(
@@ -195,7 +207,7 @@ def decode_heif(path: str) -> np.ndarray:
                     handle,
                     ctypes.byref(img),
                     _HEIF_COLORSPACE_RGB,
-                    _HEIF_CHROMA_INTERLEAVED_RGBA,
+                    chroma,
                     None,
                 ),
                 "decode",
@@ -209,7 +221,8 @@ def decode_heif(path: str) -> np.ndarray:
             if not plane:
                 raise ImageHandlerError("heif: no interleaved plane")
             buf = np.ctypeslib.as_array(plane, shape=(height, stride.value))
-            return buf[:, : width * 4].reshape(height, width, 4).copy()
+            return buf[:, : width * channels].reshape(
+                height, width, channels).copy()
         finally:
             if img:
                 lib.heif_image_release(img)
@@ -270,7 +283,9 @@ def decode_pdf(path: str) -> np.ndarray:
 
 
 def format_image(path: str, extension: str | None = None) -> np.ndarray:
-    """Decode any supported still image/document to RGBA uint8
+    """Decode any supported still image/document to uint8, [h, w, 4]
+    RGBA (PIL's formats, SVG, PDF, a HEIF with an alpha channel) or
+    [h, w, 3] RGB (a HEIF without one: `decode_heif`)
     (ref:handler.rs:18-60 `format_image` — the single dispatch)."""
     if os.path.getsize(path) > MAXIMUM_FILE_SIZE:
         raise ImageHandlerError(f"file over {MAXIMUM_FILE_SIZE} bytes")

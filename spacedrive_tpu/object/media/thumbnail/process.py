@@ -245,11 +245,14 @@ def decode_video_frame(path: str) -> Decoded:
 def decode_heif_image(path: str, extension: str,
                       tap: FrameTap | None = None) -> Decoded:
     """HEIC/HEIF/AVIF through the libheif dispatch (ref:crates/images
-    HEIF handler), RGBA at the picture's full size: libheif scales
-    nothing on its way out. Orientation is baked in by libheif's
-    transforms (the container's `irot`/`imir`), so the EXIF tag, which
-    says the same, is not applied again. A file libheif does not take
-    is a ThumbError: it costs its own thumbnail, never its batch's."""
+    HEIF handler), at the picture's full size (libheif scales nothing
+    on its way out) and with the channels the file holds: RGB for a
+    photo, so it rides the device resize as three planes in one call,
+    RGBA only where the handle reports an alpha channel. Orientation
+    is baked in by libheif's transforms (the container's `irot`/`imir`),
+    so the EXIF tag, which says the same, is not applied again. A file
+    libheif does not take is a ThumbError: it costs its own thumbnail,
+    never its batch's."""
     try:
         with span("heif.decode") as call:
             arr = format_image(path, extension)

@@ -197,8 +197,9 @@ THUMB_HEIF_SECONDS = REGISTRY.counter(
     "sd_thumbnail_heif_seconds",
     "seconds a HEIF still costs on the decode workers: decode (the libheif "
     "call: read the container, decode the primary item at full size, the "
-    "container's transforms, to RGBA), plane (the submitter's tap on the "
-    "frame: the embedder's 32 x 32 plane from the full-size array)",
+    "container's transforms, to RGB, or RGBA where the file has alpha), "
+    "plane (the submitter's tap on the frame: the embedder's 32 x 32 plane "
+    "from the full-size array)",
     labels=("part",),  # decode | plane
 )
 THUMB_HEIF_BYTES = REGISTRY.counter(

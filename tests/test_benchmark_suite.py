@@ -29,6 +29,13 @@ LEFT_OUT = {
     # test_a_clips_programs_are_named_by_the_channels_its_decode_hands_on
     "benchmark/tests/test_video_kind_cpu.py":
         ["test_programs_are_named_from_the_programs_own_tables"],
+    # pins four channels for the frame `images.decode_heif` hands on; a
+    # photo's is RGB since PR 35 (the handle reports no alpha channel):
+    # tests/test_photolib_heic.py::
+    # test_the_decoder_hands_on_the_displayed_picture holds the boxes
+    # and the decode, test_exif_comes_back_field_for_field the EXIF
+    "benchmark/tests/test_heic_kind_cpu.py":
+        ["test_written_photos_are_what_the_plan_says"],
 }
 
 

@@ -219,27 +219,31 @@ def test_exif_orientation_changes_nothing(tmp_path, w, h):
     assert np.array_equal(planes[0], planes[1])
 
 
-def test_handler_formats_keep_format_image(tmp_path, monkeypatch):
+@pytest.mark.parametrize("mode", ["RGBA", "RGB"])
+def test_handler_formats_keep_format_image(tmp_path, monkeypatch, mode):
     """HEIF, SVG and PDF do not come from PIL: `format_image` decodes
-    them, and its RGBA array goes through the same RGB resize."""
+    them, and its array, RGBA or the three channels a HEIF without an
+    alpha channel comes as, goes through the same RGB resize."""
     from spacedrive_tpu.object.media import images
 
-    rgba = np.asarray(_field(29, 96, 64).convert("RGBA"))
+    frame = np.asarray(_field(29, 96, 64).convert(mode))
+    assert frame.shape == (64, 96, len(mode))
     seen = []
 
     def fake_format_image(path, extension=None):
         seen.append(path)
-        return rgba
+        return frame
 
     monkeypatch.setattr(images, "format_image", fake_format_image)
-    want = None
+    # the same plane whichever way the frame came
+    want = embedder.input_plane(np.asarray(
+        _field(29, 96, 64).convert("RGB").resize((32, 32))))
     for ext in ("heic", "svg", "pdf"):
         path = str(tmp_path / f"doc.{ext}")
         with open(path, "wb") as f:
             f.write(b"opaque")
         plane, label = _decode_counting(path)
         assert label == "1" and plane.shape == (32, 32, 3)
-        want = plane if want is None else want
         assert np.array_equal(plane, want)
     assert len(seen) == 3
 

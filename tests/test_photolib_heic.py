@@ -2,11 +2,17 @@
 phone writes it, HEICs of 640 x 480 written by `benchmark/kinds/heic.py`
 (HEVC in HEIF through libheif, the container's `irot`, an EXIF block)
 through the program's own path (`process.decode` by libheif, the device
-resize with its alpha dispatch, `finish`, `ImageMetadata` from the
-container, a whole `cli.index_location`) and held against
-`benchmark/reference/heic.py`, which imports nothing of the program."""
+resize, `finish`, `ImageMetadata` from the container, a whole
+`cli.index_location`) and held against `benchmark/reference/heic.py`,
+which imports nothing of the program. Since ISSUE 35 the frame follows
+the file: RGB, three planes in one dispatch, for a camera's photo, and
+RGBA with the alpha dispatch only where the handle reports an alpha
+channel."""
+
+import ctypes
 
 import asyncio
+import io
 import json
 import os
 
@@ -57,31 +63,42 @@ def location(tmp_path_factory, kind):
     return root, manifest, common.entries_of(manifest, "heic")
 
 
+def _photo(location, orientation) -> tuple[str, dict]:
+    """(path, entry) of the location's first photo turned so."""
+    root, _manifest, photos = location
+    e = next(p for p in photos if p["heic"]["orientation"] == orientation)
+    return os.path.join(root, e["rel"]), e
+
+
 # --- one photo through the thumbnailer's stages ------------------------------
 
 
 @pytest.mark.parametrize("orientation", [1, 6], ids=["640x480", "turned"])
 def test_thumbnail_is_within_the_references_gap(location, kind, orientation):
     """A 640 x 480 HEIC and one the container turns to 480 x 640 through
-    `process.decode` → `resize_batch` → `finish`: RGBA with alpha 255,
-    the alpha dispatch, the picture as displayed, once."""
-    root, _manifest, photos = location
-    e = next(p for p in photos if p["heic"]["orientation"] == orientation)
-    path = os.path.join(root, e["rel"])
+    `process.decode` → `resize_batch` → `finish`: a tight RGB frame (a
+    camera's photo has no alpha channel), the colour dispatch alone, the
+    picture as displayed, once."""
+    path, e = _photo(location, orientation)
     alpha_before = tm.THUMB_RESIZE_IMAGES.value(alpha="1")
+    plain_before = tm.THUMB_RESIZE_IMAGES.value(alpha="0")
     frames_before = tm.THUMB_HEIF_FRAMES.value(result="ok")
     tapped = []
     d = process.decode(path, "HEIC", lambda frame, scale: tapped.append(
         (frame.shape, scale)))
     w, h = (480, 640) if orientation == 6 else (640, 480)
-    assert d.array.shape == (h, w, 4) and d.array.dtype == np.uint8
-    assert (d.array[..., 3] == 255).all()
+    assert d.array.shape == (h, w, 3) and d.array.dtype == np.uint8
+    assert d.array.strides == (w * 3, 3, 1)  # one copy into its canvas
     assert d.orientation == 1 and not d.is_video  # EXIF is not applied again
-    assert tapped == [((h, w, 4), 1)]
+    assert tapped == [((h, w, 3), 1)]
     tw, th = ref.thumbnail_size(640, 480, orientation, TARGET)
     assert d.target == (th, tw)
     webp = process.finish(d, process.resize_decoded([d])[0])
-    assert tm.THUMB_RESIZE_IMAGES.value(alpha="1") == alpha_before + 1
+    assert tm.THUMB_RESIZE_IMAGES.value(alpha="0") == plain_before + 1
+    assert tm.THUMB_RESIZE_IMAGES.value(alpha="1") == alpha_before
+    from PIL import Image
+
+    assert Image.open(io.BytesIO(webp)).mode == "RGB"
     assert tm.THUMB_HEIF_FRAMES.value(result="ok") == frames_before + 1
     rgb = kind.picture(e)
     want = ref.thumbnail_pixels(rgb, orientation, TARGET)
@@ -97,15 +114,174 @@ def test_thumbnail_is_within_the_references_gap(location, kind, orientation):
 
 
 def test_a_half_turn_is_applied_once(location, kind):
-    root, _manifest, photos = location
-    e = next(p for p in photos if p["heic"]["orientation"] == 3)
-    d = process.decode(os.path.join(root, e["rel"]), "heic")
+    path, e = _photo(location, 3)
+    d = process.decode(path, "heic")
     webp = process.finish(d, process.resize_decoded([d])[0])
     rgb = kind.picture(e)
     assert ref_media.thumbnail_gap(
         webp, ref.thumbnail_pixels(rgb, 3, TARGET)) < kind.PIXEL_GAP_LIMIT
     assert ref_media.thumbnail_gap(
         webp, ref.thumbnail_pixels(rgb, 1, TARGET)) > 2 * kind.PIXEL_GAP_LIMIT
+
+
+# --- the frame's channels follow the file (ISSUE 35) -------------------------
+
+
+def _says_it_has_alpha(monkeypatch):
+    """Every handle reports an alpha channel, so `decode_heif` asks
+    libheif for `heif_chroma_interleaved_RGBA` as it did for every file
+    before ISSUE 35; libheif fills the plane a photo lacks with 255."""
+    monkeypatch.setattr(images._load_heif(),
+                        "heif_image_handle_has_alpha_channel",
+                        lambda handle: 1)
+
+
+@pytest.mark.parametrize("orientation", [1, 6], ids=["640x480", "turned"])
+def test_the_rgb_frame_is_the_rgba_frames_colour(location, monkeypatch,
+                                                 orientation):
+    path, _e = _photo(location, orientation)
+    rgb = images.decode_heif(path)
+    _says_it_has_alpha(monkeypatch)
+    rgba = images.decode_heif(path)
+    h, w = (640, 480) if orientation == 6 else (480, 640)
+    assert rgb.shape == (h, w, 3) and rgba.shape == (h, w, 4)
+    assert rgb.flags.c_contiguous and (rgba[..., 3] == 255).all()
+    assert np.array_equal(rgb, rgba[..., :3])
+
+
+@pytest.mark.parametrize("orientation", [1, 6], ids=["640x480", "turned"])
+def test_three_planes_give_the_thumbnail_and_the_plane_four_gave(
+        location, orientation):
+    """The stored colour and the embedder's plane are what they were
+    when the frame carried a plane of 255s: byte for byte."""
+    from spacedrive_tpu.models import embedder
+    from spacedrive_tpu.ops import thumbnail_jax as tj
+
+    path, _e = _photo(location, orientation)
+    rgb = images.decode_heif(path)
+    rgba = np.concatenate(
+        [rgb, np.full((*rgb.shape[:2], 1), 255, np.uint8)], axis=-1)
+    tw, th = tj.scale_dimensions(rgb.shape[1], rgb.shape[0])
+    small, = tj.resize_batch([rgb], [(th, tw)])
+    small_a, = tj.resize_batch([rgba], [(th, tw)])
+    assert small.shape == (th, tw, 3) and small_a.shape == (th, tw, 4)
+    assert np.array_equal(small, small_a[..., :3])
+    assert (small_a[..., 3] == 255).all()
+    assert np.array_equal(embedder.plane_from_frame(rgb),
+                          embedder.plane_from_frame(rgba))
+
+
+def _write_with_alpha(kind, path: str, rgba: np.ndarray) -> bool:
+    """`rgba` as one HEVC item with its alpha as an auxiliary image,
+    through the kind's own binding of the writer's side of libheif;
+    False where this machine's encoder takes no alpha plane."""
+    lib = kind._libheif()
+    h, w = rgba.shape[:2]
+    ctx = lib.heif_context_alloc()
+    encoder, image, handle = (ctypes.c_void_p() for _ in range(3))
+    try:
+        steps = (
+            lambda: lib.heif_context_get_encoder_for_format(
+                ctx, 1, ctypes.byref(encoder)),
+            lambda: lib.heif_encoder_set_lossy_quality(encoder, 80),
+            lambda: lib.heif_encoder_set_parameter_string(
+                encoder, b"preset", b"ultrafast"),
+            # heif_colorspace_RGB, heif_chroma_interleaved_RGBA
+            lambda: lib.heif_image_create(w, h, 1, 11, ctypes.byref(image)),
+            lambda: lib.heif_image_add_plane(image, 10, w, h, 8))
+        if any(step().code for step in steps):
+            return False
+        stride = ctypes.c_int()
+        plane = lib.heif_image_get_plane(image, 10, ctypes.byref(stride))
+        np.ctypeslib.as_array(plane, shape=(h, stride.value))[:, :w * 4] = \
+            rgba.reshape(h, w * 4)
+        return not (
+            lib.heif_context_encode_image(
+                ctx, image, encoder, None, ctypes.byref(handle)).code
+            or lib.heif_context_write_to_file(ctx, os.fsencode(path)).code)
+    finally:
+        if handle:
+            lib.heif_image_handle_release(handle)
+        if image:
+            lib.heif_image_release(image)
+        if encoder:
+            lib.heif_encoder_release(encoder)
+        lib.heif_context_free(ctx)
+
+
+def _is_rgba_with_the_alpha_dispatch(path: str, h: int, w: int):
+    """→ (frame, webp): four channels from the decode, through the
+    alpha dispatch, to the stored bytes."""
+    alpha_before = tm.THUMB_RESIZE_IMAGES.value(alpha="1")
+    plain_before = tm.THUMB_RESIZE_IMAGES.value(alpha="0")
+    tapped = []
+    d = process.decode(path, "heic",
+                       lambda frame, scale: tapped.append(frame.shape))
+    assert d.array.shape == (h, w, 4) and d.array.dtype == np.uint8
+    assert tapped == [(h, w, 4)]
+    webp = process.finish(d, process.resize_decoded([d])[0])
+    assert tm.THUMB_RESIZE_IMAGES.value(alpha="1") == alpha_before + 1
+    assert tm.THUMB_RESIZE_IMAGES.value(alpha="0") == plain_before
+    return d.array, webp
+
+
+def test_a_file_with_an_alpha_channel_keeps_it(kind, tmp_path):
+    """A cut-out: the lower half opaque, the upper half clear."""
+    rgba = np.empty((480, 640, 4), np.uint8)
+    rgba[..., :3] = kind.picture(
+        {"heic": {"w": 640, "h": 480}, "content": [11, 35]})
+    rgba[..., 3] = 255
+    rgba[:240, :, 3] = 0
+    path = str(tmp_path / "cutout.heic")
+    if not _write_with_alpha(kind, path, rgba):
+        pytest.skip("this libheif's encoder takes no alpha plane")
+    from PIL import Image
+
+    frame, webp = _is_rgba_with_the_alpha_dispatch(path, 480, 640)
+    assert (frame[:232, :, 3] < 16).all() and (frame[248:, :, 3] > 239).all()
+    stored = Image.open(io.BytesIO(webp))
+    assert stored.mode == "RGBA"
+    stored = np.asarray(stored)
+    th = stored.shape[0]
+    assert (stored[:th // 2 - 8, :, 3] < 16).all()
+    assert (stored[th // 2 + 8:, :, 3] > 239).all()
+    assert np.abs(frame[248:, :, :3].astype(np.int16)
+                  - rgba[248:, :, :3]).mean() < 3
+
+
+def test_a_handle_that_reports_alpha_takes_the_alpha_dispatch(
+        location, monkeypatch):
+    """The flag alone decides: a photo whose handle says it has an
+    alpha channel goes the way every HEIC went before ISSUE 35, and
+    what is stored is the same bytes either way: libwebp writes no
+    alpha chunk for a plane of 255s."""
+    path, _e = _photo(location, 6)
+    d = process.decode(path, "heic")
+    plain = process.finish(d, process.resize_decoded([d])[0])
+    _says_it_has_alpha(monkeypatch)
+    frame, webp = _is_rgba_with_the_alpha_dispatch(path, 640, 480)
+    assert (frame[..., 3] == 255).all()
+    assert webp == plain
+
+
+def test_the_decoder_hands_on_the_displayed_picture(location, kind):
+    """What `benchmark/tests/test_heic_kind_cpu.py::
+    test_written_photos_are_what_the_plan_says` holds of the program's
+    decode, with the channels the decode hands on now (that case pins
+    four and waits for a `benchmark` PR: tests/test_benchmark_suite.py)."""
+    root, _manifest, photos = location
+    for e in photos:
+        path = os.path.join(root, e["rel"])
+        assert e["size"] == os.path.getsize(path) > 0
+        with open(path, "rb") as f:
+            head = f.read(4096)
+        assert head[4:12] == b"ftypheic" and b"hvcC" in head
+        # the turn is the container's own
+        assert (b"irot" in head) == (e["heic"]["orientation"] != 1)
+        shown = images.decode_heif(path)
+        want = ref.displayed(kind.picture(e), e["heic"]["orientation"])
+        assert shown.shape == want.shape and shown.dtype == np.uint8
+        assert np.abs(shown.astype(np.int16) - want).mean() < 3
 
 
 # --- the container's EXIF ----------------------------------------------------
@@ -314,7 +490,7 @@ def test_the_new_counters_move(indexed):
     c = indexed["counters"]
     assert c["sd_thumbnail_heif_frames_total{result=ok}"] == 6
     assert not c.get("sd_thumbnail_heif_frames_total{result=error}")
-    assert c["sd_thumbnail_heif_bytes_total"] == 6 * 640 * 480 * 4
+    assert c["sd_thumbnail_heif_bytes_total"] == 6 * 640 * 480 * 3
     assert c["sd_thumbnail_heif_seconds{part=decode}"] > 0
     assert c["sd_thumbnail_heif_seconds{part=plane}"] > 0
     assert c["sd_media_extract_seconds{kind=heif}.count"] == 6
@@ -327,12 +503,14 @@ def test_the_new_counters_move(indexed):
     assert "sd_span_seconds{stage=thumbnail.decode.heif.decode}.count" in spans
     assert "sd_span_seconds{stage=media.extract.heif}.count" in spans
     if indexed["backend"] == "tpu":
-        # every HEIC went with its alpha plane beside the colour planes
-        assert c["sd_thumbnail_resize_images_total{alpha=1}"] >= 6
+        # every HEIC went as three colour planes, none with an alpha
+        # plane beside them
+        assert c["sd_thumbnail_resize_images_total{alpha=0}"] >= 6
+        assert not c.get("sd_thumbnail_resize_images_total{alpha=1}")
     # the readers the benchmark adds print a number from these
     bench = harness.Bench(ROOT)
     ctx = {"counters": c}
-    assert bench.reader("heif_frame_bytes_per_image")(ctx) == 640 * 480 * 4
+    assert bench.reader("heif_frame_bytes_per_image")(ctx) == 640 * 480 * 3
     for name in ("heif_decode_ms_per_image", "heif_plane_ms_per_image",
                  "heif_exif_ms_per_image"):
         assert bench.reader(name)(ctx) > 0
@@ -351,9 +529,8 @@ def test_the_embedding_is_of_the_displayed_picture(location, kind):
     the encoder, turned the same way."""
     from spacedrive_tpu.models import embedder
 
-    root, _manifest, photos = location
-    e = next(p for p in photos if p["heic"]["orientation"] == 6)
-    frame = images.decode_heif(os.path.join(root, e["rel"]))
+    path, e = _photo(location, 6)
+    frame = images.decode_heif(path)
     plane = embedder.input_plane(embedder.plane_from_frame(frame))
     want = ref.embedding(kind.picture(e), 6)
     got = ref_media.embed_forward(plane[None])[0]
