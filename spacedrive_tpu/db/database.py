@@ -14,6 +14,7 @@ import datetime as _dt
 import os
 import sqlite3
 import threading
+import time
 import uuid
 from typing import Any, Iterable, Iterator, Sequence
 
@@ -65,6 +66,9 @@ class LibraryDb:
         self._lock = threading.RLock()
         # guarded by _lock: only the outermost committing call is timed
         self._in_txn, self._txn_wrote = False, False
+        self._commit_s = 0.0  # of the open outermost block, nested ones' too
+        # reads since the last flush into sd_db_reads_total / _read_seconds
+        self._reads, self._read_s = 0, 0.0
         with self._lock:
             self._conn.execute("PRAGMA journal_mode=WAL")
             self._conn.execute("PRAGMA foreign_keys=ON")
@@ -87,6 +91,7 @@ class LibraryDb:
 
     def close(self) -> None:
         with self._lock:
+            self._flush_reads()
             self._conn.close()
 
     # --- core access ---------------------------------------------------------
@@ -98,27 +103,64 @@ class LibraryDb:
         ref:core/crates/sync/src/manager.rs:70-93), and the one door
         every commit goes through: a `db.txn` span under whatever span
         encloses it (so the profile shows which stage waited for
-        SQLite) and, if anything was written, one observation of
-        `sd_db_txn_seconds`. One per commit, never one per row; a block
-        nested inside another's rides the outer one's span."""
+        SQLite), its `COMMIT` alone a child span `commit`, and, if
+        anything was written, one observation each of
+        `sd_db_txn_seconds` (the block) and `sd_db_commit_seconds` (the
+        commits in it) and the rows changed on `sd_db_changes_total`.
+        One per commit, never one per row; a block nested inside
+        another's rides the outer one's span. Either block ends as
+        sqlite3's own connection block does: commit on a clean exit,
+        rollback on an exception."""
         with self._lock:
             if self._in_txn:
-                # sqlite3's connection block commits on exit, so the
-                # inner one commits what the outer has written so far
-                with self._conn:
+                # as sqlite3's connection block: the inner one commits
+                # what the outer has written so far
+                try:
                     yield self._conn
                     self._txn_wrote |= self._conn.in_transaction
+                except BaseException:
+                    self._conn.rollback()
+                    raise
+                self._commit_s += self._commit()
                 return
-            self._in_txn, self._txn_wrote = True, False
+            self._in_txn, self._txn_wrote, self._commit_s = True, False, 0.0
+            changes = self._conn.total_changes
             try:
                 with span("db.txn") as txn:
-                    with self._conn:
+                    try:
                         yield self._conn
                         self._txn_wrote |= self._conn.in_transaction
+                    except BaseException:
+                        self._conn.rollback()
+                        raise
+                    with span("commit"):
+                        self._commit_s += self._commit()
             finally:
                 self._in_txn = False
+                self._flush_reads()
             if self._txn_wrote:
                 _tm.DB_TXN_SECONDS.observe(txn.duration)
+                _tm.DB_COMMIT_SECONDS.observe(self._commit_s)
+                _tm.DB_CHANGES.inc(self._conn.total_changes - changes)
+
+    def _commit(self) -> float:
+        """`COMMIT`, and the seconds it took. A commit that fails rolls
+        back, so that the database is not left locked."""
+        t0 = time.perf_counter()
+        try:
+            self._conn.commit()
+        except BaseException:
+            self._conn.rollback()
+            raise
+        return time.perf_counter() - t0
+
+    def _flush_reads(self) -> None:
+        """Hands the reads counted on the connection to the registry: two
+        calls a transaction, not two a read. Under `_lock`."""
+        if self._reads:
+            _tm.DB_READS.inc(self._reads)
+            _tm.DB_READ_SECONDS.inc(self._read_s)
+            self._reads, self._read_s = 0, 0.0
 
     def execute(self, sql: str, params: Sequence | dict = ()) -> sqlite3.Cursor:
         with self.transaction() as conn:
@@ -138,19 +180,27 @@ class LibraryDb:
 
         spec = _faults.hit("db.slow")
         if spec is not None:
-            import time
-
             time.sleep(spec.delay_s)
 
     def query(self, sql: str, params: Sequence | dict = ()) -> list[dict[str, Any]]:
         self._maybe_slow()
         with self._lock:
-            return self._conn.execute(sql, params).fetchall()
+            t0 = time.perf_counter()
+            try:
+                return self._conn.execute(sql, params).fetchall()
+            finally:
+                self._reads += 1
+                self._read_s += time.perf_counter() - t0
 
     def query_one(self, sql: str, params: Sequence | dict = ()) -> dict[str, Any] | None:
         self._maybe_slow()
         with self._lock:
-            return self._conn.execute(sql, params).fetchone()
+            t0 = time.perf_counter()
+            try:
+                return self._conn.execute(sql, params).fetchone()
+            finally:
+                self._reads += 1
+                self._read_s += time.perf_counter() - t0
 
     # --- typed helpers -------------------------------------------------------
 

@@ -110,10 +110,11 @@ class IndexerJob(StatefulJob):
         )
         if self.init.get("shallow"):
             rules, iso_factory, fetcher, remover, jcheck = self._walk_env(ctx)
-            result = walk_single_dir(
-                root, rules, iso_factory, fetcher, remover,
-                journal_check=jcheck,
-            )
+            with span("walk"):  # the walker's parts sit under it, as below
+                result = walk_single_dir(
+                    root, rules, iso_factory, fetcher, remover,
+                    journal_check=jcheck,
+                )
             self.steps.extend(self._steps_from_result(result))
         else:
             self.steps.extend(self._run_walk(ctx, root, None))

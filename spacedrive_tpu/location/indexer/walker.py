@@ -7,18 +7,24 @@ accept-by-children state machine (:476-586), ancestor backfill (:616-
 (:334-430), and per-directory to_remove fetching (:664-680).
 
 The DB is injected as plain callables (exactly the reference's
-generics-based design) so the walker unit-tests hermetically.
+generics-based design) so the walker unit-tests hermetically. The
+walker times them all the same: a walk call's seconds are split six
+ways on `sd_indexer_walk_seconds{part}` (`_WalkClock`), four of them
+also child spans of whatever span the caller holds open (`walk`).
 """
 
 from __future__ import annotations
 
 import logging
 import os
+import time
 import uuid
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable
 
 from ...files.isolated_path import FilePathMetadata, IsolatedFilePathData
+from ...telemetry import metrics as _tm
+from ...telemetry import span
 from .rules import IndexerRule, RuleKind
 
 logger = logging.getLogger(__name__)
@@ -74,6 +80,32 @@ ToRemoveFetcher = Callable[[IsolatedFilePathData, list[IsolatedFilePathData]], l
 JournalCheck = Callable[[IsolatedFilePathData, FilePathMetadata], str]
 
 
+class _WalkClock:
+    """One walk call's seconds by part. `scan`, `journal`, `fetch` and
+    `diff` are their spans' durations; `rules` and `remove_query` are
+    clock pairs round every `IndexerRule.apply_all` and every
+    `to_remove_db_fetcher` inside `scan`, summed here and never spans
+    (one an entry, one a directory). Observed once, at the call's end,
+    `scan` less the two it holds, so the six add up to the call."""
+
+    __slots__ = ("scan", "rules", "remove_query", "journal", "fetch", "diff")
+
+    def __init__(self) -> None:
+        self.scan = self.rules = self.remove_query = 0.0
+        self.journal = self.fetch = self.diff = 0.0
+
+    def observe(self) -> None:
+        # literal labels at the site (sdlint SD007)
+        seconds = _tm.INDEXER_WALK_SECONDS
+        seconds.observe(self.scan - self.rules - self.remove_query,
+                        part="scan")
+        seconds.observe(self.rules, part="rules")
+        seconds.observe(self.remove_query, part="remove_query")
+        seconds.observe(self.journal, part="journal")
+        seconds.observe(self.fetch, part="fetch")
+        seconds.observe(self.diff, part="diff")
+
+
 def walk(
     root: str | os.PathLike,
     indexer_rules: list[IndexerRule],
@@ -94,26 +126,30 @@ def walk(
     errors: list[Exception] = []
     paths_and_sizes: dict[str, int] = {}
     to_remove: list[dict] = []
+    clock = _WalkClock()
 
-    while to_walk:
-        entry = to_walk.pop(0)
-        entry_size, removed = _inner_walk_single_dir(
-            root, entry, indexer_rules, iso_file_path_factory,
-            to_remove_db_fetcher, indexed_paths, to_walk, errors,
-            update_notifier,
-        )
-        to_remove.extend(removed)
-        paths_and_sizes[entry.path] = paths_and_sizes.get(entry.path, 0) + entry_size
-        if entry.maybe_parent is not None:
-            paths_and_sizes[entry.maybe_parent] = (
-                paths_and_sizes.get(entry.maybe_parent, 0) + entry_size
+    with span("scan") as scan:
+        while to_walk:
+            entry = to_walk.pop(0)
+            entry_size, removed = _inner_walk_single_dir(
+                root, entry, indexer_rules, iso_file_path_factory,
+                to_remove_db_fetcher, indexed_paths, to_walk, errors,
+                update_notifier, clock,
             )
-        if len(indexed_paths) >= limit:
-            break
+            to_remove.extend(removed)
+            paths_and_sizes[entry.path] = paths_and_sizes.get(entry.path, 0) + entry_size
+            if entry.maybe_parent is not None:
+                paths_and_sizes[entry.maybe_parent] = (
+                    paths_and_sizes.get(entry.maybe_parent, 0) + entry_size
+                )
+            if len(indexed_paths) >= limit:
+                break
+    clock.scan = scan.duration
 
     walked, to_update = _filter_existing_paths(
-        indexed_paths, file_paths_db_fetcher, journal_check
+        indexed_paths, file_paths_db_fetcher, journal_check, clock
     )
+    clock.observe()
     return WalkResult(walked, to_update, to_walk, to_remove, errors, paths_and_sizes)
 
 
@@ -130,13 +166,17 @@ def walk_single_dir(
     root = os.fspath(root)
     indexed_paths: dict[IsolatedFilePathData, WalkedEntry] = {}
     errors: list[Exception] = []
-    size, removed = _inner_walk_single_dir(
-        root, ToWalkEntry(root), indexer_rules, iso_file_path_factory,
-        to_remove_db_fetcher, indexed_paths, None, errors, None,
-    )
+    clock = _WalkClock()
+    with span("scan") as scan:
+        size, removed = _inner_walk_single_dir(
+            root, ToWalkEntry(root), indexer_rules, iso_file_path_factory,
+            to_remove_db_fetcher, indexed_paths, None, errors, None, clock,
+        )
+    clock.scan = scan.duration
     walked, to_update = _filter_existing_paths(
-        indexed_paths, file_paths_db_fetcher, journal_check
+        indexed_paths, file_paths_db_fetcher, journal_check, clock
     )
+    clock.observe()
     return WalkResult(walked, to_update, [], removed, errors, {root: size})
 
 
@@ -150,6 +190,7 @@ def _inner_walk_single_dir(
     maybe_to_walk: list[ToWalkEntry] | None,
     errors: list[Exception],
     update_notifier: Callable[[str, int], None] | None,
+    clock: _WalkClock,
 ) -> tuple[int, list[dict]]:
     path = entry.path
     try:
@@ -172,7 +213,9 @@ def _inner_walk_single_dir(
         if update_notifier is not None:
             update_notifier(current_path, len(indexed_paths) + len(paths_buffer))
 
+        t_rules = time.perf_counter()
         rules_per_kind = IndexerRule.apply_all(indexer_rules, current_path)
+        clock.rules += time.perf_counter() - t_rules
 
         # rejected by any reject-glob (ref:walk.rs:519-527)
         if any(not ok for ok in rules_per_kind.get(RuleKind.REJECT_FILES_BY_GLOB, [])):
@@ -242,11 +285,13 @@ def _inner_walk_single_dir(
                 paths_buffer[aiso] = WalkedEntry(aiso, ameta)
                 ancestor = os.path.dirname(ancestor)
 
+    t_remove = time.perf_counter()
     try:
         to_remove = to_remove_db_fetcher(iso_to_walk, list(paths_buffer.keys()))
     except Exception as e:  # noqa: BLE001
         errors.append(e)
         to_remove = []
+    clock.remove_query += time.perf_counter() - t_remove
 
     entry_size = sum(
         w.metadata.size_in_bytes for w in paths_buffer.values() if w.metadata
@@ -258,62 +303,71 @@ def _inner_walk_single_dir(
 def _filter_existing_paths(
     indexed_paths: dict[IsolatedFilePathData, WalkedEntry],
     file_paths_db_fetcher: FilePathsFetcher,
-    journal_check: JournalCheck | None = None,
+    journal_check: JournalCheck | None,
+    clock: _WalkClock,
 ) -> tuple[list[WalkedEntry], list[WalkedEntry]]:
     """Split into (to_create, to_update) against existing DB rows
     (ref:walk.rs:334-430): an existing row updates when inode, mtime
     (±1 ms) or hidden changed — directory sizes are ignored. Every FILE
     entry additionally gets its index-journal verdict (the per-file
-    hit/miss/invalidated stream a warm pass is measured by)."""
+    hit/miss/invalidated stream a warm pass is measured by). Three
+    spans, their seconds left on `clock`: the consults, the lookup,
+    the comparison."""
     if not indexed_paths:
         return [], []
     if journal_check is not None:
-        for iso, entry in indexed_paths.items():
-            if not iso.is_dir and entry.metadata is not None:
-                try:
-                    entry.journal_verdict = journal_check(iso, entry.metadata)
-                except Exception:  # noqa: BLE001 - journal must not kill walks
-                    logger.exception("journal_check failed")
-                    entry.journal_verdict = None
-    try:
-        rows = file_paths_db_fetcher(list(indexed_paths.keys()))
-    except Exception:  # noqa: BLE001 - treat fetch failure as "no rows"
-        logger.exception("file_paths_db_fetcher failed; treating all as new")
-        rows = []
+        with span("journal") as consults:
+            for iso, entry in indexed_paths.items():
+                if not iso.is_dir and entry.metadata is not None:
+                    try:
+                        entry.journal_verdict = journal_check(iso, entry.metadata)
+                    except Exception:  # noqa: BLE001 - journal must not kill walks
+                        logger.exception("journal_check failed")
+                        entry.journal_verdict = None
+        clock.journal = consults.duration
+    with span("fetch") as lookup:
+        try:
+            rows = file_paths_db_fetcher(list(indexed_paths.keys()))
+        except Exception:  # noqa: BLE001 - treat fetch failure as "no rows"
+            logger.exception("file_paths_db_fetcher failed; treating all as new")
+            rows = []
+    clock.fetch = lookup.duration
 
     from ...db.database import blob_u64
 
-    in_db: dict[IsolatedFilePathData, dict] = {}
-    for row in rows:
-        iso = IsolatedFilePathData.from_db_row(
-            row.get("location_id", 0),
-            row["materialized_path"],
-            row["name"],
-            row["extension"],
-            bool(row["is_dir"]),
-        )
-        in_db[iso] = row
+    with span("diff") as comparison:
+        in_db: dict[IsolatedFilePathData, dict] = {}
+        for row in rows:
+            iso = IsolatedFilePathData.from_db_row(
+                row.get("location_id", 0),
+                row["materialized_path"],
+                row["name"],
+                row["extension"],
+                bool(row["is_dir"]),
+            )
+            in_db[iso] = row
 
-    to_create: list[WalkedEntry] = []
-    to_update: list[WalkedEntry] = []
-    for iso, entry in indexed_paths.items():
-        row = in_db.get(iso)
-        if row is None:
-            to_create.append(entry)
-            continue
-        meta = entry.metadata
-        if meta is None or row.get("inode") is None:
-            continue
-        changed = (
-            blob_u64(row["inode"]) != meta.inode
-            or _mtime_differs(row.get("date_modified"), meta)
-            or row.get("hidden") is None
-            or bool(row["hidden"]) != meta.hidden
-        )
-        if changed:
-            entry.pub_id = row["pub_id"]
-            entry.object_id = row.get("object_id")
-            to_update.append(entry)
+        to_create: list[WalkedEntry] = []
+        to_update: list[WalkedEntry] = []
+        for iso, entry in indexed_paths.items():
+            row = in_db.get(iso)
+            if row is None:
+                to_create.append(entry)
+                continue
+            meta = entry.metadata
+            if meta is None or row.get("inode") is None:
+                continue
+            changed = (
+                blob_u64(row["inode"]) != meta.inode
+                or _mtime_differs(row.get("date_modified"), meta)
+                or row.get("hidden") is None
+                or bool(row["hidden"]) != meta.hidden
+            )
+            if changed:
+                entry.pub_id = row["pub_id"]
+                entry.object_id = row.get("object_id")
+                to_update.append(entry)
+    clock.diff = comparison.duration
     return to_create, to_update
 
 

@@ -155,36 +155,42 @@ class FileIdentifierJob(StatefulJob):
         # re-record (mtime-only touch) keep its thumb/media/phash vouches
         to_record: dict[int, tuple] = {}
         jstats = {"hit": 0, "dirty": 0, "dirty_chunks": 0}
-        # the row loop is one span per window; the sampled reads inside it
-        # are timed per file into a local and observed once per window
-        read_s = chunk_cache_s = 0.0
+        # the row loop is one span per window; what it does per file is
+        # timed into locals and observed once per window: the span less
+        # these five is the loop's own Python
+        read_s = chunk_cache_s = stat_s = journal_s = rehash_s = 0.0
         n_sampled = 0  # messages in the sampled layout (file over 100 KiB)
         with span("identify.rows"):
             for row in rows:
                 full = _row_full_path(loc_path, row)
                 size = blob_u64(row["size_in_bytes_bytes"]) or 0
                 key = _journal.key_of(row)
+                t_stat = time.perf_counter()
+                ident = _journal.stat_identity(full)
+                stat_s += time.perf_counter() - t_stat
                 if size == 0:
                     metas.append({"row": row, "cas_id": None})
                     # journal the empty file (cas sentinel "") so warm-pass
                     # walks get a `hit` instead of an eternal miss
-                    ident = _journal.stat_identity(full)
                     if ident is not None:
                         to_record[row["id"]] = (key, ident, "", None, None)
                     continue
-                ident = _journal.stat_identity(full)
                 entry = None
                 if ident is not None:
                     # the walker already counted this file's verdict this
                     # pass — don't double-count the invalidation here
+                    t_journal = time.perf_counter()
                     verdict, entry = journal.lookup(
                         loc_id, key, ident, count_invalidated=False
                     )
-                    if verdict == _journal.HIT and entry.cas_id:
-                        # vouched: skip the read, the hash, and the transfer
-                        resolved[row["id"]] = entry.cas_id
+                    vouched = verdict == _journal.HIT and entry.cas_id
+                    if vouched:
                         journal.bytes_saved(cas.message_len(size),
                                             location_id=loc_id)
+                    journal_s += time.perf_counter() - t_journal
+                    if vouched:
+                        # vouched: skip the read, the hash, and the transfer
+                        resolved[row["id"]] = entry.cas_id
                         jstats["hit"] += 1
                         metas.append({"row": row, "cas_id": "journal"})
                         continue
@@ -204,13 +210,14 @@ class FileIdentifierJob(StatefulJob):
                     and entry.chunks.msg_len == len(msg)
                     and len(msg) > cas.CHUNK_LEN
                 ):
+                    t_rehash = time.perf_counter()
                     try:
-                        cas_id, cache, n_dirty, hashed = cas.dirty_range_rehash(
-                            msg, entry.chunks
-                        )
+                        rehashed = cas.dirty_range_rehash(msg, entry.chunks)
                     except ValueError:
-                        cache = None
-                    else:
+                        rehashed = None
+                    rehash_s += time.perf_counter() - t_rehash
+                    if rehashed is not None:
+                        cas_id, cache, n_dirty, hashed = rehashed
                         resolved[row["id"]] = cas_id
                         to_record[row["id"]] = (key, ident, cas_id, cache, entry)
                         journal.bytes_saved(len(msg) - hashed,
@@ -234,6 +241,9 @@ class FileIdentifierJob(StatefulJob):
         _tm.IDENTIFIER_STAGE_SECONDS.observe(read_s, stage="read")
         _tm.IDENTIFIER_STAGE_SECONDS.observe(chunk_cache_s,
                                              stage="chunk_cache")
+        _tm.IDENTIFIER_STAGE_SECONDS.observe(stat_s, stage="stat")
+        _tm.IDENTIFIER_STAGE_SECONDS.observe(journal_s, stage="journal")
+        _tm.IDENTIFIER_STAGE_SECONDS.observe(rehash_s, stage="rehash")
         _tm.IDENTIFIER_MESSAGES.inc(n_sampled, layout="sampled")
         _tm.IDENTIFIER_MESSAGES.inc(len(messages) - n_sampled, layout="whole")
         backend = d["backend"]

@@ -56,7 +56,7 @@ def render() -> str:
 
 def reset() -> None:
     """Test/bench isolation: zero every metric series AND clear the
-    span ring, the trace ring, every flight-recorder ring, the
+    span ring (`trace`'s, the one there is), every flight-recorder ring, the
     attribution report cache + pass markers, SLO evaluation state, the
     host profiler's accumulators + capture-window ring + trigger
     state, the resource sampler's last-sample state + planted test
@@ -64,7 +64,6 @@ def reset() -> None:
     history writer's in-memory tail (durable history
     segments are data-dir state and deliberately survive)."""
     REGISTRY.reset()
-    clear_recent()
     trace.clear()
     events.clear_all()
     attrib.reset()

@@ -86,9 +86,13 @@ IDENTIFIER_STAGE_SECONDS = REGISTRY.histogram(
     "sampled reads of the row loop (read), bucketing and packing the "
     "batch (pack) and handing it to the device (dispatch), and, inside "
     "the row loop beside the reads, the per-chunk digests of the "
-    "journal's chunk cache (chunk_cache). Nothing sums the labels: the "
-    "two sides overlap in time",
-    labels=("stage",),  # hash | db | read | pack | dispatch | chunk_cache
+    "journal's chunk cache (chunk_cache), the stat of every row (stat), "
+    "the journal consult with its bytes-saved count (journal) and the "
+    "dirty-range rehash of a changed file (rehash); the identify.rows "
+    "span less these five is the loop's own Python. Nothing sums the "
+    "labels: the two sides overlap in time",
+    # hash | db | read | pack | dispatch | chunk_cache | stat | journal | rehash
+    labels=("stage",),
 )
 IDENTIFIER_MESSAGES = REGISTRY.counter(
     "sd_identifier_messages_total",
@@ -310,6 +314,18 @@ DEVICE_DISPATCH_OCCUPANCY = REGISTRY.histogram(
 CAS_BACKEND_FALLBACK = REGISTRY.counter(
     "sd_cas_backend_fallback_total",
     "cas_ids('auto') device failures that degraded to the host backend",
+)
+
+# --- indexer walk (location/indexer/walker.py) ------------------------------
+
+INDEXER_WALK_SECONDS = REGISTRY.histogram(
+    "sd_indexer_walk_seconds",
+    "one walk call split six ways, each part observed once a call and "
+    "the six adding up to it: the directory loop (scan) less its rule "
+    "matching (rules) and its one file_path query a directory "
+    "(remove_query), the journal consult of every file (journal), the "
+    "file_path lookup of every entry (fetch), rows against entries (diff)",
+    labels=("part",),  # scan | rules | remove_query | journal | fetch | diff
 )
 
 # --- index journal (location/indexer/journal.py) ----------------------------
@@ -730,9 +746,31 @@ EVENT_LOOP_LAG = REGISTRY.gauge(
 
 DB_TXN_SECONDS = REGISTRY.histogram(
     "sd_db_txn_seconds",
-    "one observation per committed write: a transaction() block (every "
-    "sync.write_ops), a writing execute(), an executemany(); reads "
-    "never count",
+    "one observation per committed write, statements and COMMIT: a "
+    "transaction() block (every sync.write_ops), a writing execute(), an "
+    "executemany(). Reads are counted apart (sd_db_reads_total)",
+)
+DB_COMMIT_SECONDS = REGISTRY.histogram(
+    "sd_db_commit_seconds",
+    "beside every observation of sd_db_txn_seconds, the part of it inside "
+    "COMMIT: the block's own and those of the blocks nested in it. The "
+    "rest is the body: the statements and the Python that builds them",
+)
+DB_CHANGES = REGISTRY.counter(
+    "sd_db_changes_total",
+    "rows inserted, updated or deleted by the committed writes "
+    "(the connection's total_changes over each outermost block)",
+)
+DB_READS = REGISTRY.counter(
+    "sd_db_reads_total",
+    "query() and query_one() calls (so find, find_one, count), added up "
+    "on the connection and flushed here when an outermost transaction() "
+    "ends and on close()",
+)
+DB_READ_SECONDS = REGISTRY.counter(
+    "sd_db_read_seconds_total",
+    "seconds inside execute().fetch*() of the reads sd_db_reads_total "
+    "counts, flushed with it",
 )
 
 # --- spans (telemetry/spans.py) ---------------------------------------------

@@ -7,9 +7,10 @@ cross-contaminate parentage) that on exit
 
 - observes ``sd_span_seconds{stage=…}`` and, when bytes were attached,
   ``sd_span_bytes_total{stage=…}``;
-- appends a record to a bounded in-memory ring the ``telemetry.
-  snapshot`` procedure exposes, so the explorer can show "where did the
-  last index pass spend its time" without a scrape pipeline;
+- appends one record to the trace ring (``telemetry.trace``): the
+  Chrome-trace export reads all of it, ``telemetry.snapshot`` its
+  newest ``RECENT_SPANS`` records, so the explorer can show "where did
+  the last index pass spend its time" without a scrape pipeline;
 - debug-logs through the `utils.tracing` logging tree (target
   ``spacedrive_tpu.telemetry``), honoring SD_LOG filters.
 
@@ -31,8 +32,7 @@ Every span also carries distributed-trace identity (``trace_id``/
 ``span_id``/``parent_id``, see ``telemetry.trace``): a nested span
 inherits its parent's trace; a root span adopts the ambient
 ``trace.current()`` context installed by a boundary (task dispatch, job
-resume, a P2P header) or mints a fresh trace. Completed spans land in
-the trace ring for Chrome-trace export, and spans slower than
+resume, a P2P header) or mints a fresh trace. Spans slower than
 ``events.SLOW_OP_SECONDS`` fire the slow-op watchdog ring.
 """
 
@@ -41,9 +41,7 @@ from __future__ import annotations
 import contextvars
 import logging
 import sys
-import threading
 import time
-from collections import deque
 from typing import Any
 
 from . import events as _events
@@ -52,13 +50,11 @@ from . import trace as _trace
 
 logger = logging.getLogger(__name__)
 
-RECENT_SPANS = 256
+RECENT_SPANS = 256  # how many of the trace ring's records a snapshot shows
 
 _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
     "sd_current_span", default=None
 )
-_recent: deque[dict[str, Any]] = deque(maxlen=RECENT_SPANS)
-_recent_lock = threading.Lock()
 
 #: prefix of every span's name on the profiler's host lines
 ANNOTATION_PREFIX = "sd."
@@ -188,12 +184,11 @@ class Span:
             "trace_id": self.trace_id,
             "span_id": self.span_id,
             "parent_id": self.parent_id,
+            "t0": self._t0_wall,
         }
         if self.fields:
             rec["fields"] = dict(self.fields)
-        with _recent_lock:
-            _recent.append(rec)
-        _trace.record_span({**rec, "t0": self._t0_wall})
+        _trace.record_span(rec)
         if self.duration >= _events.SLOW_OP_SECONDS:
             _events.watchdog_slow_op(self.path, self.duration)
         logger.debug("span %s: %.3fms%s", self.path, self.duration * 1e3,
@@ -217,11 +212,11 @@ def current_span() -> Span | None:
 
 
 def recent_spans() -> list[dict[str, Any]]:
-    """Most-recent-last completed spans (bounded ring)."""
-    with _recent_lock:
-        return list(_recent)
+    """Most-recent-last completed spans: the newest `RECENT_SPANS`
+    records of the trace ring."""
+    return _trace.recent()[-RECENT_SPANS:]
 
 
 def clear_recent() -> None:
-    with _recent_lock:
-        _recent.clear()
+    """Clears the trace ring: spans have no other."""
+    _trace.clear()

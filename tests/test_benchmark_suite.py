@@ -40,7 +40,7 @@ LEFT_OUT = {
 
 
 def test_there_are_files_to_hold():
-    assert len(FILES) >= 13
+    assert len(FILES) >= 16
 
 
 @pytest.mark.parametrize("path", FILES)

@@ -275,8 +275,10 @@ def test_db_txn_counts_one_per_commit(call, commits):
     assert after["count"] - before["count"] == commits
     assert after["sum"] >= before["sum"]
     if commits:
-        # the commit is a span under the stage that issued it
-        assert telemetry.recent_spans()[-2]["stage"] == "stage.db.txn"
+        # the block is a span under the stage that issued it, its COMMIT
+        # a leaf under the block
+        assert [r["stage"] for r in telemetry.recent_spans()[-3:]] == [
+            "stage.db.txn.commit", "stage.db.txn", "stage"]
 
 
 # --- the operator's profile covers the chain --------------------------------
@@ -387,3 +389,7 @@ async def test_job_manager_spans_ride_the_jobs_trace(tmp_path):
     assert counts["job.ingest.db.txn"] == 2
     assert counts["job.settle.job.ingest.db.txn"] == 2
     assert counts["job.settle.db.txn"] >= 2
+    # and every one of them ends in its `commit` leaf
+    for path in ("job.ingest.db.txn", "job.settle.job.ingest.db.txn",
+                 "job.settle.db.txn"):
+        assert counts[path + ".commit"] == counts[path]
