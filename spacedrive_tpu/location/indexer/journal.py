@@ -92,6 +92,16 @@ def stat_identity(path: str | os.PathLike) -> Identity | None:
         return None
 
 
+def fd_identity(fd: int) -> Identity:
+    """The identity of the file open behind `fd`: for a file the journal
+    holds no entry for there is nothing to judge before the read, so the
+    identifier takes it from the descriptor it reads through (no second
+    walk of the path, and the very inode whose bytes are hashed).
+    Field for field what :func:`stat_identity` gives for the path.
+    Raises OSError like any call on a descriptor."""
+    return Identity.from_stat(os.fstat(fd))
+
+
 # key = (materialized_path, name, extension) within one location
 Key = tuple[str, str, str]
 
@@ -186,6 +196,26 @@ def _decode_payload(blob: Any) -> dict | None:
     return obj
 
 
+def judge_row(
+    row: dict | None, identity: Identity | None,
+) -> tuple[str, "JournalEntry | None"]:
+    """(verdict, entry) of one fetched `index_journal` row against a
+    file's identity: the ONE place a row is judged (`lookup`, a
+    window's `judge`, `consult_many` and the procpool worker's
+    ``journal.match`` stage all come here). No row is a `miss`; a row
+    that does not decode is `bypassed` (the owner drops it); `hit` only
+    when all four identity fields match and the entry is not stale; an
+    `invalidated` entry is returned too, for its chunk cache."""
+    if row is None:
+        return MISS, None
+    entry = entry_of_row(row)
+    if entry is None:
+        return BYPASSED, None
+    if not entry.stale and identity is not None and entry.identity == identity:
+        return HIT, entry
+    return INVALIDATED, entry
+
+
 #: process-lifetime per-location runtime counters (hits/misses/…,
 #: bytes saved), keyed (db path, location_id) — IndexJournal instances
 #: are transient per-call wrappers, so the counts live here the way
@@ -268,53 +298,98 @@ class IndexJournal:
         `count=False` suppresses counting entirely — for probe-only
         consults (the watcher's debounce sizing) that are not pipeline
         verdicts and must not drag the /mesh hit rate."""
-        if not enabled():
-            if count:
-                _tm.INDEX_JOURNAL_OPS.inc(result="bypassed")
-                self._loc_count(location_id, "bypassed")
-            return BYPASSED, None
-        mat, name, ext = key
-        try:
-            row = self.db.query_one(
-                "SELECT * FROM index_journal WHERE location_id = ? AND "
-                "materialized_path = ? AND name = ? AND extension = ?",
-                (location_id, mat, name, ext),
-            )
-        except sqlite3.Error:
-            if count:
-                _tm.INDEX_JOURNAL_OPS.inc(result="bypassed")
-                self._loc_count(location_id, "bypassed")
-            return BYPASSED, None
-        if row is None:
-            if count:
-                _tm.INDEX_JOURNAL_OPS.inc(result="miss")
-                self._loc_count(location_id, "misses")
-            return MISS, None
-        entry = self._entry_of(row)
-        if entry is None:
-            # corrupt row: drop it so the next pass starts clean
-            self._delete_key(location_id, key)
-            if count:
-                _tm.INDEX_JOURNAL_OPS.inc(result="bypassed")
-                self._loc_count(location_id, "bypassed")
-            return BYPASSED, None
-        if (
-            not entry.stale
-            and identity is not None
-            and entry.identity == identity
-        ):
-            if count:
-                _tm.INDEX_JOURNAL_OPS.inc(result="hit")
-                self._loc_count(location_id, "hits")
-            return HIT, entry
-        if count_invalidated and count:
+        rows: dict[Key, dict] | None = None
+        if enabled():
+            mat, name, ext = key
+            try:
+                row = self.db.query_one(
+                    "SELECT * FROM index_journal WHERE location_id = ? AND "
+                    "materialized_path = ? AND name = ? AND extension = ?",
+                    (location_id, mat, name, ext),
+                )
+                rows = {} if row is None else {key: row}
+            except sqlite3.Error:
+                pass
+        return self.judge(location_id, key, rows, identity,
+                          count_invalidated, count)
+
+    def _count_verdict(self, location_id: int, verdict: str) -> None:
+        """One verdict on `sd_index_journal_ops_total` and on the
+        location's runtime counts (label values spelled out: sdlint
+        SD007 wants a fixed domain at the call)."""
+        if verdict == HIT:
+            _tm.INDEX_JOURNAL_OPS.inc(result="hit")
+            self._loc_count(location_id, "hits")
+        elif verdict == MISS:
+            _tm.INDEX_JOURNAL_OPS.inc(result="miss")
+            self._loc_count(location_id, "misses")
+        elif verdict == INVALIDATED:
             _tm.INDEX_JOURNAL_OPS.inc(result="invalidated")
             self._loc_count(location_id, "invalidated")
-        return INVALIDATED, entry
+        else:
+            _tm.INDEX_JOURNAL_OPS.inc(result="bypassed")
+            self._loc_count(location_id, "bypassed")
+
+    def judge(
+        self, location_id: int, key: Key, rows: dict[Key, dict] | None,
+        identity: Identity | None,
+        count_invalidated: bool = True, count: bool = True,
+    ) -> tuple[str, JournalEntry | None]:
+        """:meth:`lookup` less its read: the verdict of `key` among
+        `rows`, the journal rows a caller has already fetched
+        (:meth:`fetch_rows`; the file identifier reads a window's once
+        and judges each file as it comes to it). `rows` None (journal
+        off, or the read failed) is `bypassed`. Counts as `lookup`
+        does, and drops a corrupt row."""
+        if rows is None:
+            verdict, entry = BYPASSED, None
+        else:
+            row = rows.get(key)
+            verdict, entry = judge_row(row, identity)
+            if row is not None and entry is None:
+                # corrupt row: drop it so the next pass starts clean
+                self._delete_key(location_id, key)
+        if count and (count_invalidated or verdict != INVALIDATED):
+            self._count_verdict(location_id, verdict)
+        return verdict, entry
 
     #: keys per batched consult query — 3 bind params per key must stay
     #: under SQLite's default 999-variable limit with headroom
     CONSULT_CHUNK = 300
+
+    def fetch_rows(
+        self, location_id: int, keys: list[Key],
+    ) -> dict[Key, dict] | None:
+        """The journal rows of `keys` by key: one query per
+        ~:data:`CONSULT_CHUNK` keys instead of one SELECT per file, the
+        keys a table of constants joined to the journal so that each is
+        one search of the primary key (the row-value ``IN`` form this
+        replaces walked every journal row of the location once a
+        chunk). None when the journal is off or a read fails: every
+        key then judges `bypassed`."""
+        if not enabled():
+            return None
+        rows_by_key: dict[Key, dict] = {}
+        try:
+            for start in range(0, len(keys), self.CONSULT_CHUNK):
+                chunk = keys[start:start + self.CONSULT_CHUNK]
+                placeholders = ",".join("(?,?,?)" for _ in chunk)
+                params: list[Any] = [part for key in chunk for part in key]
+                params.append(location_id)
+                for row in self.db.query(
+                    f"WITH k(m, n, e) AS (VALUES {placeholders}) "
+                    "SELECT j.* FROM k CROSS JOIN index_journal j "
+                    "ON j.materialized_path = k.m AND j.name = k.n "
+                    "AND j.extension = k.e WHERE j.location_id = ?",
+                    params,
+                ):
+                    rows_by_key[(
+                        row["materialized_path"], row["name"],
+                        row["extension"],
+                    )] = row
+        except sqlite3.Error:
+            return None
+        return rows_by_key
 
     def consult_many(
         self,
@@ -323,83 +398,26 @@ class IndexJournal:
         count_invalidated: bool = True,
         count: bool = True,
     ) -> dict[Key, tuple[str, JournalEntry | None]]:
-        """Batched :meth:`lookup`: one row-value ``IN`` query per
-        ~:data:`CONSULT_CHUNK` keys instead of one SELECT per file —
-        the per-entry-SQL floor of mesh shard execution (ROADMAP PR 9
-        follow-up). Verdict semantics and counter discipline are
-        IDENTICAL to per-key lookup (parity-tested in
-        tests/test_serve.py), including the corrupt-row drop."""
-        out: dict[Key, tuple[str, JournalEntry | None]] = {}
+        """Batched :meth:`lookup`: :meth:`fetch_rows` then
+        :meth:`judge` per item — the per-entry-SQL floor of mesh shard
+        execution (ROADMAP PR 9 follow-up). Verdict semantics and
+        counter discipline are IDENTICAL to per-key lookup
+        (parity-tested in tests/test_serve.py), including the
+        corrupt-row drop."""
         if not items:
-            return out
-        if not enabled():
-            for key, _ident in items:
-                if count:
-                    _tm.INDEX_JOURNAL_OPS.inc(result="bypassed")
-                    self._loc_count(location_id, "bypassed")
-                out[key] = (BYPASSED, None)
-            return out
-        rows_by_key: dict[Key, dict] = {}
-        try:
-            for start in range(0, len(items), self.CONSULT_CHUNK):
-                chunk = items[start:start + self.CONSULT_CHUNK]
-                placeholders = ",".join("(?,?,?)" for _ in chunk)
-                params: list[Any] = [location_id]
-                for (mat, name, ext), _ident in chunk:
-                    params.extend((mat, name, ext))
-                for row in self.db.query(
-                    "SELECT * FROM index_journal WHERE location_id = ? "
-                    "AND (materialized_path, name, extension) IN "
-                    f"(VALUES {placeholders})",
-                    params,
-                ):
-                    rows_by_key[(
-                        row["materialized_path"], row["name"],
-                        row["extension"],
-                    )] = row
-        except sqlite3.Error:
-            for key, _ident in items:
-                if count:
-                    _tm.INDEX_JOURNAL_OPS.inc(result="bypassed")
-                    self._loc_count(location_id, "bypassed")
-                out[key] = (BYPASSED, None)
-            return out
-        pooled = self._consult_pool(
-            location_id, items, rows_by_key, count_invalidated, count,
-        )
-        if pooled is not None:
-            return pooled
-        for key, identity in items:
-            row = rows_by_key.get(key)
-            if row is None:
-                if count:
-                    _tm.INDEX_JOURNAL_OPS.inc(result="miss")
-                    self._loc_count(location_id, "misses")
-                out[key] = (MISS, None)
-                continue
-            entry = self._entry_of(row)
-            if entry is None:
-                self._delete_key(location_id, key)
-                if count:
-                    _tm.INDEX_JOURNAL_OPS.inc(result="bypassed")
-                    self._loc_count(location_id, "bypassed")
-                out[key] = (BYPASSED, None)
-                continue
-            if (
-                not entry.stale
-                and identity is not None
-                and entry.identity == identity
-            ):
-                if count:
-                    _tm.INDEX_JOURNAL_OPS.inc(result="hit")
-                    self._loc_count(location_id, "hits")
-                out[key] = (HIT, entry)
-                continue
-            if count_invalidated and count:
-                _tm.INDEX_JOURNAL_OPS.inc(result="invalidated")
-                self._loc_count(location_id, "invalidated")
-            out[key] = (INVALIDATED, entry)
-        return out
+            return {}
+        rows_by_key = self.fetch_rows(location_id, [k for k, _i in items])
+        if rows_by_key is not None:
+            pooled = self._consult_pool(
+                location_id, items, rows_by_key, count_invalidated, count,
+            )
+            if pooled is not None:
+                return pooled
+        return {
+            key: self.judge(location_id, key, rows_by_key, identity,
+                            count_invalidated, count)
+            for key, identity in items
+        }
 
     def _entry_of(self, row: dict) -> JournalEntry | None:
         return entry_of_row(row)

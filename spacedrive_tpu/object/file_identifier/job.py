@@ -58,6 +58,23 @@ def orphan_where_clause(sub_path_mat: str | None = None) -> str:
     return base
 
 
+def _open_and_read(full: str, size: int, want_identity: bool):
+    """One open for a file's identity and its sampled bytes: →
+    (identity, or None where the caller has the path's already;
+    message; seconds inside the `fstat`). The descriptor is closed on
+    every exit; an unreadable file or a short read is an OSError."""
+    fd = os.open(full, os.O_RDONLY)
+    try:
+        ident, fstat_s = None, 0.0
+        if want_identity:
+            t_stat = time.perf_counter()
+            ident = _journal.fd_identity(fd)
+            fstat_s = time.perf_counter() - t_stat
+        return ident, cas.read_message_fd(fd, size), fstat_s
+    finally:
+        os.close(fd)
+
+
 @register_job
 class FileIdentifierJob(StatefulJob):
     """init: {location_id, sub_path?, backend?, chunk_size?}"""
@@ -125,12 +142,19 @@ class FileIdentifierJob(StatefulJob):
         dispatched (async) so back-to-back windows pipeline transfers.
         Runs on a worker thread; disk I/O never blocks the loop.
 
-        The index journal is consulted per row BEFORE any byte is read:
-        a `hit` reuses the vouched cas_id with zero I/O; an invalidated
-        entry with a chunk cache and an unchanged message length takes
-        the host dirty-range rehash (only dirty chunks pay BLAKE3, zero
-        bytes shipped to the device); everything else rides the device
-        batch as before."""
+        The index journal is consulted BEFORE any byte is read, and
+        once a window: its rows for the window's keys come in one read
+        after the page, and each file is judged in memory as the loop
+        comes to it, so the loop itself never takes the connection's
+        lock (link-commit of the window before holds it meanwhile).
+        A file the journal holds an entry for is judged against
+        `stat_identity` of its path: a `hit` reuses the vouched cas_id
+        with zero I/O; an invalidated entry with a chunk cache and an
+        unchanged message length takes the host dirty-range rehash
+        (only dirty chunks pay BLAKE3, zero bytes shipped to the
+        device). A file the journal holds no entry for has nothing to
+        be judged against: it is opened once, and its identity comes
+        from the descriptor its bytes are read through."""
         d = self.data
         params: list[Any] = [d["location_id"]]
         where = orphan_where_clause(self.init.get("sub_path"))
@@ -160,14 +184,22 @@ class FileIdentifierJob(StatefulJob):
         # these five is the loop's own Python
         read_s = chunk_cache_s = stat_s = journal_s = rehash_s = 0.0
         n_sampled = 0  # messages in the sampled layout (file over 100 KiB)
+        n_by_path = n_by_fd = 0  # identities taken, by the call that gave them
         with span("identify.rows"):
-            for row in rows:
+            t_journal = time.perf_counter()
+            keys = [_journal.key_of(row) for row in rows]
+            known = journal.fetch_rows(loc_id, keys)
+            journal_s += time.perf_counter() - t_journal
+            for row, key in zip(rows, keys):
                 full = _row_full_path(loc_path, row)
                 size = blob_u64(row["size_in_bytes_bytes"]) or 0
-                key = _journal.key_of(row)
-                t_stat = time.perf_counter()
-                ident = _journal.stat_identity(full)
-                stat_s += time.perf_counter() - t_stat
+                has_entry = known is not None and key in known
+                ident = None
+                if has_entry or size == 0:
+                    t_stat = time.perf_counter()
+                    ident = _journal.stat_identity(full)
+                    stat_s += time.perf_counter() - t_stat
+                    n_by_path += ident is not None
                 if size == 0:
                     metas.append({"row": row, "cas_id": None})
                     # journal the empty file (cas sentinel "") so warm-pass
@@ -176,12 +208,12 @@ class FileIdentifierJob(StatefulJob):
                         to_record[row["id"]] = (key, ident, "", None, None)
                     continue
                 entry = None
-                if ident is not None:
+                if ident is not None or not has_entry:
                     # the walker already counted this file's verdict this
                     # pass — don't double-count the invalidation here
                     t_journal = time.perf_counter()
-                    verdict, entry = journal.lookup(
-                        loc_id, key, ident, count_invalidated=False
+                    verdict, entry = journal.judge(
+                        loc_id, key, known, ident, count_invalidated=False
                     )
                     vouched = verdict == _journal.HIT and entry.cas_id
                     if vouched:
@@ -195,14 +227,20 @@ class FileIdentifierJob(StatefulJob):
                         metas.append({"row": row, "cas_id": "journal"})
                         continue
                 t_read = time.perf_counter()
+                fstat_s = 0.0
                 try:
-                    msg = cas.read_message(full, size)
+                    fd_ident, msg, fstat_s = _open_and_read(
+                        full, size, want_identity=not has_entry)
                 except OSError as e:
                     metas.append(None)
                     logger.debug("identifier: unreadable %s: %s", full, e)
                     continue
                 finally:
-                    read_s += time.perf_counter() - t_read
+                    stat_s += fstat_s
+                    read_s += time.perf_counter() - t_read - fstat_s
+                if fd_ident is not None:
+                    ident = fd_ident
+                    n_by_fd += 1
                 if (
                     ident is not None
                     and entry is not None
@@ -246,6 +284,8 @@ class FileIdentifierJob(StatefulJob):
         _tm.IDENTIFIER_STAGE_SECONDS.observe(rehash_s, stage="rehash")
         _tm.IDENTIFIER_MESSAGES.inc(n_sampled, layout="sampled")
         _tm.IDENTIFIER_MESSAGES.inc(len(messages) - n_sampled, layout="whole")
+        _tm.IDENTIFIER_IDENTITY.inc(n_by_fd, source="descriptor")
+        _tm.IDENTIFIER_IDENTITY.inc(n_by_path, source="path")
         backend = d["backend"]
         use_device = backend in ("tpu", "device") or (
             backend == "auto" and cas._device_available()

@@ -72,18 +72,18 @@ def message_from_bytes(content: bytes, size: int | None = None) -> bytes:
 
 
 def read_message(path: str | os.PathLike, size: int | None = None) -> bytes:
-    """Read the sampling layout from disk (pread per range)."""
+    """Read the sampling layout from disk: the path form of
+    `read_message_fd` (at the end of this module), for callers that
+    hold no descriptor. Opens `path`, reads through the descriptor and
+    closes it on every exit. `size` None: whatever a stat of the path
+    says."""
     if size is None:
         size = os.stat(path).st_size
-    parts = [struct.pack("<Q", size)]
-    with open(path, "rb", buffering=0) as f:
-        for off, ln in sample_ranges(size):
-            f.seek(off)
-            buf = f.read(ln)
-            if len(buf) != ln:
-                raise OSError(f"short read at {off} in {path}")
-            parts.append(buf)
-    return b"".join(parts)
+    fd = os.open(path, os.O_RDONLY)
+    try:
+        return read_message_fd(fd, size)
+    finally:
+        os.close(fd)
 
 
 def message_len(size: int) -> int:
@@ -594,3 +594,22 @@ def _device_available() -> bool:
 
         _DEVICE_STATE = [jax.devices()[0].platform != "cpu"]
     return _DEVICE_STATE[0]
+
+
+# --- descriptor-based reads (added below everything else: the hash
+# programs' cache keys hold this module's line numbers, PERF.md §6) ---
+
+
+def read_message_fd(fd: int, size: int) -> bytes:
+    """The sampling layout of the file open behind `fd`, one `pread` a
+    range: no seek, and the descriptor's own offset stays where it was.
+    The caller owns the descriptor (the file identifier opens a file
+    once, takes its identity from the same descriptor, reads, closes).
+    A range that comes back short is an OSError, as from any read."""
+    parts = [struct.pack("<Q", size)]
+    for off, ln in sample_ranges(size):
+        buf = os.pread(fd, ln, off)
+        if len(buf) != ln:
+            raise OSError(f"short read at {off}: {len(buf)} of {ln} bytes")
+        parts.append(buf)
+    return b"".join(parts)

@@ -155,18 +155,16 @@ def _stage_journal_match(payload: dict) -> dict:
 
     verdicts: list[list] = []
     for (key, ident_raw), row in zip(payload["items"], payload["rows"]):
-        if row is None:
-            verdicts.append([_journal.MISS, None, False])
-            continue
-        entry = _journal.entry_of_row(row)
-        if entry is None:
-            # corrupt row: the owner drops it (DB write stays there)
-            verdicts.append([_journal.BYPASSED, None, True])
-            continue
         ident = (
             _journal.Identity(*(int(x) for x in ident_raw))
             if ident_raw is not None else None
         )
+        verdict, entry = _journal.judge_row(row, ident)
+        if entry is None:
+            # no row (miss), or a corrupt one: the owner drops it (the
+            # DB write stays there)
+            verdicts.append([verdict, None, row is not None])
+            continue
         plain = {
             "identity": (
                 [entry.identity.inode, entry.identity.dev,
@@ -184,11 +182,7 @@ def _stage_journal_match(payload: dict) -> dict:
             "chunks": entry.chunks.to_payload()
             if entry.chunks is not None else None,
         }
-        if not entry.stale and ident is not None \
-                and entry.identity == ident:
-            verdicts.append([_journal.HIT, plain, False])
-        else:
-            verdicts.append([_journal.INVALIDATED, plain, False])
+        verdicts.append([verdict, plain, False])
     return {"verdicts": verdicts}
 
 

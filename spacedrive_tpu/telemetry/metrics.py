@@ -86,8 +86,10 @@ IDENTIFIER_STAGE_SECONDS = REGISTRY.histogram(
     "sampled reads of the row loop (read), bucketing and packing the "
     "batch (pack) and handing it to the device (dispatch), and, inside "
     "the row loop beside the reads, the per-chunk digests of the "
-    "journal's chunk cache (chunk_cache), the stat of every row (stat), "
-    "the journal consult with its bytes-saved count (journal) and the "
+    "journal's chunk cache (chunk_cache), the call that gives a row its "
+    "identity, an fstat on the descriptor it is read through or a stat "
+    "of its path (stat), the window's one journal read and each row's "
+    "verdict in memory with its bytes-saved count (journal) and the "
     "dirty-range rehash of a changed file (rehash); the identify.rows "
     "span less these five is the loop's own Python. Nothing sums the "
     "labels: the two sides overlap in time",
@@ -100,6 +102,15 @@ IDENTIFIER_MESSAGES = REGISTRY.counter(
     "layout the file's size gave them (whole = the file itself up to "
     "100 KiB, sampled = header + 4 samples + footer, 57,352 bytes)",
     labels=("layout",),  # whole | sampled
+)
+IDENTIFIER_IDENTITY = REGISTRY.counter(
+    "sd_identifier_identity_total",
+    "stat identities the identifier's row loop took, by the call that "
+    "gave them: descriptor = fstat on the descriptor the file's bytes "
+    "are read through (a file the journal holds no entry for), path = "
+    "journal.stat_identity before any read (a file the journal knows, "
+    "and an empty file)",
+    labels=("source",),  # descriptor | path
 )
 CAS_DISPATCH_ROWS = REGISTRY.counter(
     "sd_cas_dispatch_rows_total",

@@ -188,14 +188,18 @@ async def test_warm_pass_reads_nothing_and_rehashes_only_changes(
     mgr = JobManager(TaskSystem(2))
     location = LocationCreateArgs(path=str(loc_path)).create(library)
 
+    from spacedrive_tpu.object.file_identifier import job as fi_job
+
     reads: list[str] = []
-    real_read = cas.read_message
+    real_read = fi_job._open_and_read
 
-    def counting_read(path, size=None):
+    def counting_read(path, size, want_identity):
         reads.append(os.fspath(path))
-        return real_read(path, size)
+        return real_read(path, size, want_identity)
 
-    monkeypatch.setattr(cas, "read_message", counting_read)
+    # the identifier's one door to a file's bytes (open, identity from
+    # the descriptor where the journal knows nothing, sampled preads)
+    monkeypatch.setattr(fi_job, "_open_and_read", counting_read)
 
     n_jobs = await _scan(library, location, mgr)
     await node.thumbnailer.wait_library_batch(library.id)
@@ -451,6 +455,162 @@ def test_journal_lookup_verdicts_and_stale(tmp_path):
     journal.record_cas(loc_id, key, ident, "beef" * 4)
     assert journal.lookup(loc_id, key, ident)[0] == "hit"
     lib.close()
+
+
+#: what one entry (or none) reads as, asked under Identity(1, 2, 3, 4)
+JUDGED = {"miss": "miss", "hit": "hit", "invalidated": "invalidated",
+          "stale": "invalidated", "corrupt": "bypassed",
+          "disabled": "bypassed"}
+
+
+def _one_entry(tmp_path, case, monkeypatch):
+    """A journal that holds one entry in the state `case` names (none
+    for `miss`) → (library, journal, location id, key, identity asked)."""
+    lib, journal = _memory_journal(tmp_path)
+    loc_id = lib.db.insert(
+        "location", pub_id=os.urandom(16), name="l", path="/tmp/x"
+    )
+    key, ident = ("/", "f", "bin"), Identity(1, 2, 3, 4)
+    msg = cas.message_from_bytes(b"x" * 5000)
+    if case != "miss":
+        journal.record_cas(loc_id, key, ident, "cafe" * 4,
+                           cas.build_chunk_cache(msg))
+    if case == "invalidated":
+        ident = Identity(1, 2, 99, 4)
+    elif case == "stale":
+        assert journal.mark_stale(loc_id, key) == 1
+    elif case == "corrupt":
+        lib.db.execute("UPDATE index_journal SET payload = X'00ff'")
+    elif case == "disabled":
+        monkeypatch.setenv("SD_INDEX_JOURNAL", "0")
+    return lib, journal, loc_id, key, ident
+
+
+@pytest.mark.parametrize("count_invalidated", [False, True],
+                         ids=["reconsult", "first_consult"])
+@pytest.mark.parametrize("case", sorted(JUDGED))
+def test_a_windows_judge_is_lookup_less_its_read(tmp_path, monkeypatch, case,
+                                                 count_invalidated):
+    """The identifier reads a window's journal rows once (`fetch_rows`)
+    and judges each file in memory (`judge`): verdict, entry, what is
+    counted on `sd_index_journal_ops_total` and per location, and the
+    corrupt row's drop are `lookup`'s, case by case."""
+    from spacedrive_tpu import telemetry
+
+    seen = {}
+    for how in ("lookup", "window"):
+        lib, journal, loc_id, key, asked = _one_entry(
+            tmp_path / how, case, monkeypatch)
+        telemetry.reset()
+        if how == "lookup":
+            verdict, entry = journal.lookup(
+                loc_id, key, asked, count_invalidated=count_invalidated)
+        else:
+            rows = journal.fetch_rows(loc_id, [("/", "other", "bin"), key])
+            assert (rows is None) == (case == "disabled")
+            verdict, entry = journal.judge(
+                loc_id, key, rows, asked, count_invalidated=count_invalidated)
+            assert journal.judge(loc_id, ("/", "other", "bin"), rows, asked,
+                                 count=False)[0] == (
+                "bypassed" if case == "disabled" else "miss")
+        seen[how] = (
+            verdict, entry,
+            {r: counter_value("sd_index_journal_ops_total", result=r)
+             for r in ("hit", "miss", "invalidated", "bypassed")},
+            list(journal_mod._LOC_RUNTIME.values()),
+            lib.db.count("index_journal"),
+        )
+        lib.close()
+        monkeypatch.delenv("SD_INDEX_JOURNAL", raising=False)
+    assert seen["window"] == seen["lookup"]
+    verdict, entry, ops, _per_location, left = seen["window"]
+    assert verdict == JUDGED[case]
+    assert (entry is not None) == (verdict in ("hit", "invalidated"))
+    counted = count_invalidated or verdict != "invalidated"
+    assert ops == {**dict.fromkeys(ops, 0.0), verdict: 1.0 if counted else 0.0}
+    # nothing recorded, or the corrupt row dropped; switched off, the
+    # journal is not read and its row stays
+    assert left == (0 if case in ("miss", "corrupt") else 1)
+    telemetry.reset()
+
+
+def test_fetch_rows_asks_once_per_chunk_of_keys(tmp_path, monkeypatch):
+    from spacedrive_tpu.db.database import blob_u64
+
+    lib, journal = _memory_journal(tmp_path)
+    loc_id = lib.db.insert(
+        "location", pub_id=os.urandom(16), name="l", path="/tmp/x"
+    )
+    other = lib.db.insert(
+        "location", pub_id=os.urandom(16), name="m", path="/tmp/y"
+    )
+    keys = [(f"/d{i % 9}/", f"f{i}", "bin" if i % 2 else "") for i in range(700)]
+    journal.record_many(loc_id, [
+        (k, Identity(i, 1, 1, 1), f"{i:016x}", None, None)
+        for i, k in enumerate(keys) if i % 3 == 0])
+    journal.record_cas(other, keys[1], Identity(9, 9, 9, 9), "ee" * 8)
+    asked = []
+    real = lib.db.query
+    monkeypatch.setattr(lib.db, "query", lambda sql, params=(): (
+        asked.append(len(params)), real(sql, params))[1])
+    rows = journal.fetch_rows(loc_id, keys)
+    # 300 + 300 + 100 keys of three parts, and the location
+    assert asked == [901, 901, 301]
+    assert sorted(rows) == sorted(k for i, k in enumerate(keys) if i % 3 == 0)
+    assert all(blob_u64(rows[k]["inode"]) == keys.index(k) for k in rows)
+    assert journal.fetch_rows(loc_id, []) == {} and len(asked) == 3
+    lib.close()
+
+
+@pytest.mark.asyncio
+async def test_a_cold_pass_records_the_identity_the_walker_will_stat(
+    tmp_path, monkeypatch
+):
+    """A cold pass takes each file's identity from the descriptor it
+    reads it through. Field for field it is what a `stat` of the path
+    gives, so the next pass's walker, which stats paths, finds every
+    file a hit and the identifier opens nothing."""
+    from spacedrive_tpu.object.file_identifier import job as fi_job
+
+    loc_path = tmp_path / "stuff"
+    _build_tree(loc_path)
+    node = _Node(tmp_path / "data")
+    library = _mk_library(tmp_path, node)
+    mgr = JobManager(TaskSystem(2))
+    location = LocationCreateArgs(path=str(loc_path)).create(library)
+    d0 = counter_value("sd_identifier_identity_total", source="descriptor")
+    p0 = counter_value("sd_identifier_identity_total", source="path")
+    n_jobs = await _scan(library, location, mgr)
+    await node.thumbnailer.wait_library_batch(library.id)
+    rows = library.db.query("SELECT * FROM index_journal")
+    assert len(rows) == 5  # a.txt, big.bin, small.bin, empty.txt, green.png
+    for row in rows:
+        name = row["name"] + ("." + row["extension"] if row["extension"] else "")
+        st = os.stat(str(loc_path) + row["materialized_path"] + name)
+        got = journal_mod.entry_of_row(row).identity
+        assert (got.inode, got.dev, got.mtime_ns, got.size) == (
+            st.st_ino, st.st_dev, st.st_mtime_ns, st.st_size), name
+    # four by descriptor, the empty file by its path
+    assert counter_value("sd_identifier_identity_total",
+                         source="descriptor") - d0 == 4
+    assert counter_value("sd_identifier_identity_total",
+                         source="path") - p0 == 1
+
+    def refuse(*a, **kw):
+        raise AssertionError("the warm pass opened a file")
+
+    monkeypatch.setattr(fi_job, "_open_and_read", refuse)
+    h0 = counter_value("sd_index_journal_ops_total", result="hit")
+    m0 = counter_value("sd_index_journal_ops_total", result="miss")
+    i0 = counter_value("sd_index_journal_ops_total", result="invalidated")
+    await _scan(library, location, mgr, n_jobs)
+    assert counter_value("sd_index_journal_ops_total", result="hit") - h0 >= 5
+    assert counter_value("sd_index_journal_ops_total", result="miss") == m0
+    assert counter_value("sd_index_journal_ops_total",
+                         result="invalidated") == i0
+    await node.thumbnailer.shutdown()
+    await mgr.system.shutdown()
+    library.close()
 
 
 def test_journal_rename_moves_vouches_and_delete_subtree(tmp_path):

@@ -134,10 +134,9 @@ def stage_stats():
     return {s: metrics.IDENTIFIER_STAGE_SECONDS.stats(stage=s) for s in STAGES}
 
 
-@pytest.fixture()
-def indexed(tmp_path):
-    """A library whose location is walked and saved, nothing identified:
-    → (library, location row, corpus directory)."""
+def walked_and_saved(tmp_path, corpus):
+    """A library whose location `corpus` is walked and saved, nothing
+    identified: → (library, location row)."""
     import asyncio
 
     from spacedrive_tpu.jobs import JobManager
@@ -147,13 +146,6 @@ def indexed(tmp_path):
     from spacedrive_tpu.node import Libraries
     from spacedrive_tpu.tasks import TaskSystem
 
-    corpus = tmp_path / "corpus"
-    corpus.mkdir()
-    (corpus / "vouched.bin").write_bytes(os.urandom(3000))
-    (corpus / "fresh.bin").write_bytes(os.urandom(5000))
-    (corpus / "sampled.bin").write_bytes(os.urandom(150_000))
-    (corpus / "empty.bin").write_bytes(b"")
-    (corpus / "gone.bin").write_bytes(os.urandom(2000))
     library = Libraries(tmp_path / "libs").create("parts")
     location = LocationCreateArgs(path=str(corpus)).create(library)
 
@@ -164,17 +156,32 @@ def indexed(tmp_path):
         await mgr.wait_idle()
 
     asyncio.run(index())
+    return library, location
+
+
+@pytest.fixture()
+def indexed(tmp_path):
+    """Five files, walked and saved: → (library, location row, corpus
+    directory)."""
+    corpus = tmp_path / "corpus"
+    corpus.mkdir()
+    (corpus / "vouched.bin").write_bytes(os.urandom(3000))
+    (corpus / "fresh.bin").write_bytes(os.urandom(5000))
+    (corpus / "sampled.bin").write_bytes(os.urandom(150_000))
+    (corpus / "empty.bin").write_bytes(b"")
+    (corpus / "gone.bin").write_bytes(os.urandom(2000))
+    library, location = walked_and_saved(tmp_path, corpus)
     yield library, location, corpus
     library.db.close()
 
 
-def fetch_window(library, location, corpus):
+def fetch_window(library, location, corpus, chunk_size=100):
     from spacedrive_tpu.object.file_identifier.job import FileIdentifierJob
 
     job = FileIdentifierJob({"location_id": location["id"], "backend": "cpu",
-                             "chunk_size": 100})
+                             "chunk_size": chunk_size})
     job.data.update(location_id=location["id"], location_path=str(corpus),
-                    backend="cpu", chunk_size=100, cursor=0)
+                    backend="cpu", chunk_size=chunk_size, cursor=0)
     return job._fetch_window(library, 0)
 
 
@@ -198,8 +205,11 @@ def test_the_row_loop_times_its_five_stages_once_a_window(indexed):
     assert len(to_record) == 3  # fresh, sampled, empty
     got = stage_stats()
     assert {s["count"] for s in got.values()} == {1}
-    assert got["stat"]["sum"] > 0       # five rows, five stats
-    assert got["journal"]["sum"] > 0    # one hit, two misses, with bytes_saved
+    # two stats of a path (the vouched file, the empty one), two fstats
+    # (fresh, sampled); the file that is gone fails in `open`
+    assert got["stat"]["sum"] > 0
+    # the window's read, then in memory: one hit with bytes_saved, three misses
+    assert got["journal"]["sum"] > 0
     assert got["read"]["sum"] > 0 and got["chunk_cache"]["sum"] > 0
     assert got["rehash"]["sum"] == 0.0  # no entry holds a chunk cache yet
     rows_span = span_stats("identify.rows")
@@ -230,6 +240,284 @@ def test_a_changed_file_is_rehashed_under_its_own_stage(indexed):
     assert got["rehash"]["count"] == 1 and got["rehash"]["sum"] > 0
     assert sum(s["sum"] for s in got.values()) <= span_stats(
         "identify.rows")["sum"]
+    # the journal knew the file: judged by a stat of its path before the
+    # read, as the empty file is; the other three by their descriptors
+    assert identity_counts() == {"descriptor": 3, "path": 2}
+    assert window[6][changed][1] == jn.stat_identity(path)
+
+
+# --- the window asks once what it asked once a file (PR 37) ------------------
+
+
+def identity_counts():
+    return {s: metrics.IDENTIFIER_IDENTITY.value(source=s)
+            for s in ("descriptor", "path")}
+
+
+def journal_ops():
+    return {r: metrics.INDEX_JOURNAL_OPS.value(result=r)
+            for r in ("hit", "miss", "invalidated", "bypassed")}
+
+
+def open_descriptors():
+    return len(os.listdir("/proc/self/fd"))
+
+
+def seek_and_read(path, size):
+    """The reader `cas.read_message` was before it went through a
+    descriptor: the reference the descriptor form is held to."""
+    import struct
+
+    from spacedrive_tpu.ops import cas
+
+    parts = [struct.pack("<Q", size)]
+    with open(path, "rb", buffering=0) as f:
+        for off, ln in cas.sample_ranges(size):
+            f.seek(off)
+            parts.append(f.read(ln))
+    return b"".join(parts)
+
+
+#: a size in every chunk bucket of `cas.SMALL_BUCKETS` (1, 2, 4, 8, 16,
+#: 32, 64, 101 chunks of message), both sides of 100 KiB, and sampled
+#: sizes whose jump is odd
+SIZES = [1, 1016, 1017, 3000, 7000, 15_000, 30_000, 60_000, 102_400,
+         102_401, 150_001, 2_000_003]
+
+
+@pytest.mark.parametrize("size", SIZES)
+def test_the_descriptor_reader_returns_read_messages_bytes(tmp_path, size):
+    from spacedrive_tpu.ops import cas
+
+    path = tmp_path / "f.bin"
+    path.write_bytes(os.urandom(size))
+    want = seek_and_read(path, size)
+    assert len(want) == cas.message_len(size)
+    before = open_descriptors()
+    fd = os.open(path, os.O_RDONLY)
+    try:
+        assert cas.read_message_fd(fd, size) == want
+        assert os.lseek(fd, 0, os.SEEK_CUR) == 0  # no seek: the offset stays
+    finally:
+        os.close(fd)
+    assert cas.read_message(path, size) == want
+    assert cas.read_message(str(path)) == want  # the size from a stat
+    assert open_descriptors() == before
+
+
+@pytest.mark.parametrize("size,claimed", [(500, 501), (150_000, 300_000),
+                                          (0, 1)])
+def test_a_short_read_is_an_oserror_and_leaves_no_descriptor(tmp_path, size,
+                                                             claimed):
+    from spacedrive_tpu.object.file_identifier import job as fi_job
+    from spacedrive_tpu.ops import cas
+
+    path = tmp_path / "short.bin"
+    path.write_bytes(os.urandom(size))
+    before = open_descriptors()
+    with pytest.raises(OSError, match="short read"):
+        cas.read_message(path, claimed)
+    for want_identity in (False, True):
+        with pytest.raises(OSError, match="short read"):
+            fi_job._open_and_read(str(path), claimed, want_identity)
+    with pytest.raises(FileNotFoundError):
+        cas.read_message(tmp_path / "none.bin", 10)
+    with pytest.raises(FileNotFoundError):
+        fi_job._open_and_read(str(tmp_path / "none.bin"), 10, True)
+    assert open_descriptors() == before
+
+
+def test_one_open_gives_the_identity_and_the_bytes(tmp_path):
+    from spacedrive_tpu.location.indexer import journal as jn
+    from spacedrive_tpu.object.file_identifier import job as fi_job
+    from spacedrive_tpu.ops import cas
+
+    path = tmp_path / "f.bin"
+    path.write_bytes(os.urandom(150_000))
+    before = open_descriptors()
+    ident, msg, fstat_s = fi_job._open_and_read(str(path), 150_000, True)
+    assert ident == jn.Identity.from_stat(os.stat(path)) == jn.stat_identity(path)
+    assert msg == cas.read_message(path, 150_000) and fstat_s > 0
+    assert fi_job._open_and_read(str(path), 150_000, False) == (None, msg, 0.0)
+    assert open_descriptors() == before
+
+
+@pytest.fixture(params=[1, 300, 301, 650])
+def many(request, tmp_path):
+    """n small files in seven directories, walked and saved, the journal
+    empty: → (library, location, corpus, n)."""
+    n = request.param
+    corpus = tmp_path / "corpus"
+    for i in range(n):
+        d = corpus / f"d{i % 7}"
+        d.mkdir(parents=True, exist_ok=True)
+        (d / f"f{i:04d}.bin").write_bytes(i.to_bytes(4, "little") * (1 + i % 50))
+    library, location = walked_and_saved(tmp_path, corpus)
+    yield library, location, corpus, n
+    library.db.close()
+
+
+def test_a_cold_window_asks_the_journal_once_and_no_path_of_its_stat(
+        many, monkeypatch):
+    """n rows the journal holds nothing for: the page and ⌈n ÷ 300⌉
+    journal reads, not one `query_one`; not one `stat` of a path; every
+    identity an `fstat`'s, and field for field what a `stat` of the
+    path gives, so the next pass's walker finds every file a hit."""
+    from spacedrive_tpu.db.database import LibraryDb
+    from spacedrive_tpu.location.indexer import journal as jn
+
+    library, location, corpus, n = many
+    reads = {"query": [], "query_one": []}
+    for name in reads:
+        real = getattr(LibraryDb, name)
+
+        def wrapped(self, sql, params=(), _real=real, _name=name):
+            reads[_name].append(sql)
+            return _real(self, sql, params)
+
+        monkeypatch.setattr(LibraryDb, name, wrapped)
+    monkeypatch.setattr(jn, "stat_identity", lambda path: pytest.fail(
+        f"a path-based stat of {path} for a file the journal does not know"))
+    telemetry.reset()
+    (rows, metas, messages, _msg_rows, _fin, resolved, to_record, jstats,
+     _limit) = fetch_window(library, location, corpus, chunk_size=1000)
+    assert len(rows) == len(messages) == len(to_record) == n and not resolved
+    assert reads["query_one"] == []
+    assert len(reads["query"]) == 1 + -(-n // 300)
+    assert sum("index_journal" in sql for sql in reads["query"]) == -(-n // 300)
+    assert identity_counts() == {"descriptor": n, "path": 0}
+    assert journal_ops() == {"hit": 0, "miss": n, "invalidated": 0,
+                             "bypassed": 0}
+    for row in rows:
+        key, ident, cas_hex, cache, carry = to_record[row["id"]]
+        st = os.stat(corpus / row["materialized_path"].strip("/")
+                     / f"{row['name']}.{row['extension']}")
+        assert (ident.inode, ident.dev, ident.mtime_ns, ident.size) == (
+            st.st_ino, st.st_dev, st.st_mtime_ns, st.st_size)
+        assert ident == jn.Identity.from_stat(st)
+        assert cas_hex is None and carry is None and cache is not None
+    # recorded as link-commit would, the journal vouches for every file
+    # under the walker's own stat: the second pass is all hits
+    monkeypatch.undo()
+    journal = jn.IndexJournal(library.db)
+    journal.record_many(location["id"], [
+        (key, ident, "c" * 16, cache, None)
+        for key, ident, _cas, cache, _carry in to_record.values()])
+    telemetry.reset()
+    again = fetch_window(library, location, corpus, chunk_size=1000)
+    assert again[7]["hit"] == n and again[2] == [] and not again[6]
+    assert journal_ops() == {"hit": n, "miss": 0, "invalidated": 0,
+                             "bypassed": 0}
+    assert identity_counts() == {"descriptor": 0, "path": n}
+
+
+def test_a_vouched_row_opens_nothing_and_reads_nothing(indexed, monkeypatch):
+    from spacedrive_tpu.location.indexer import journal as jn
+    from spacedrive_tpu.object.file_identifier import job as fi_job
+    from spacedrive_tpu.ops import cas
+
+    library, location, corpus = indexed
+    names = ["vouched", "fresh", "sampled", "gone"]
+    jn.IndexJournal(library.db).record_many(location["id"], [
+        (("/", name, "bin"), jn.stat_identity(corpus / f"{name}.bin"),
+         f"{i:016x}", None, None) for i, name in enumerate(names)])
+
+    def refuse(*a, **kw):
+        raise AssertionError("a vouched file was opened or read")
+
+    monkeypatch.setattr(fi_job, "_open_and_read", refuse)
+    monkeypatch.setattr(cas, "read_message_fd", refuse)
+    monkeypatch.setattr(jn, "fd_identity", refuse)
+    telemetry.reset()
+    window = fetch_window(library, location, corpus)
+    assert window[7]["hit"] == 4 and window[2] == []
+    assert sorted(window[5].values()) == [f"{i:016x}" for i in range(4)]
+    assert journal_ops()["hit"] == 4 and journal_ops()["miss"] == 0
+    # four vouched files and the empty one, each by a stat of its path
+    assert identity_counts() == {"descriptor": 0, "path": 5}
+
+
+def test_identities_taken_add_up_to_the_rows_that_took_one(indexed):
+    from spacedrive_tpu.location.indexer import journal as jn
+
+    library, location, corpus = indexed
+    jn.IndexJournal(library.db).record_many(location["id"], [
+        (("/", "vouched", "bin"), jn.stat_identity(corpus / "vouched.bin"),
+         "c" * 16, None, None),
+        # an entry under another identity: judged by the path's stat,
+        # invalidated, read through a descriptor that is not asked again
+        (("/", "fresh", "bin"), jn.Identity(1, 2, 3, 4), "d" * 16, None, None)])
+    os.unlink(corpus / "gone.bin")  # no identity: `open` fails
+    telemetry.reset()
+    window = fetch_window(library, location, corpus)
+    to_record = window[6]
+    assert identity_counts() == {"descriptor": 1, "path": 3}
+    # sampled (descriptor); vouched, fresh, empty (path); gone took none
+    assert len(to_record) + window[7]["hit"] == 1 + 3
+    by_name = {r["name"]: r["id"] for r in window[0]}
+    for name in ("sampled", "fresh", "empty"):
+        assert to_record[by_name[name]][1] == jn.stat_identity(
+            corpus / f"{name}.bin")
+    assert journal_ops() == {"hit": 1, "miss": 2, "invalidated": 0,
+                             "bypassed": 0}
+
+
+@pytest.mark.parametrize("journal_on", [True, False], ids=["on", "off"])
+def test_a_window_of_every_verdict_counts_what_lookup_counts(
+        indexed, monkeypatch, journal_on):
+    """hit, invalidated (not counted: the walker counted it), stale with
+    a chunk cache (rehashed on the host), a corrupt row (bypassed and
+    dropped), no entry (miss); with `SD_INDEX_JOURNAL=0` every row that
+    is judged reads `bypassed` and nothing is fetched."""
+    from spacedrive_tpu.location.indexer import journal as jn
+    from spacedrive_tpu.ops import cas
+
+    library, location, corpus = indexed
+    journal = jn.IndexJournal(library.db)
+    sampled = cas.read_message(corpus / "sampled.bin", 150_000)
+    journal.record_many(location["id"], [
+        (("/", "vouched", "bin"), jn.stat_identity(corpus / "vouched.bin"),
+         "a" * 16, None, None),
+        (("/", "fresh", "bin"), jn.Identity(1, 2, 3, 4), "b" * 16, None, None),
+        (("/", "sampled", "bin"), jn.stat_identity(corpus / "sampled.bin"),
+         cas.cas_ids([sampled], "cpu")[0], cas.build_chunk_cache(sampled), None),
+        (("/", "gone", "bin"), jn.stat_identity(corpus / "gone.bin"),
+         "d" * 16, None, None)])
+    assert journal.mark_stale(location["id"], ("/", "sampled", "bin")) == 1
+    library.db.execute(
+        "UPDATE index_journal SET payload = X'00ff' WHERE name = 'gone'")
+    # the oracle: per-row `lookup` as the loop asked it before PR 37,
+    # on the rows a lookup leaves as they are
+    oracle = {name: journal.lookup(
+        location["id"], ("/", name, "bin"),
+        jn.stat_identity(corpus / f"{name}.bin"), count=False)[0]
+        for name in ("vouched", "fresh", "sampled")}
+    assert oracle == {"vouched": "hit", "fresh": "invalidated",
+                      "sampled": "invalidated"}
+    if not journal_on:
+        monkeypatch.setenv("SD_INDEX_JOURNAL", "0")
+    telemetry.reset()
+    window = fetch_window(library, location, corpus)
+    by_name = {r["name"]: m and m["cas_id"] for r, m in zip(window[0], window[1])}
+    left = {r["name"] for r in library.db.query("SELECT name FROM index_journal")}
+    if journal_on:
+        assert by_name == {"vouched": "journal", "fresh": "pending",
+                           "sampled": "journal", "empty": None,
+                           "gone": "pending"}
+        assert window[7] == {"hit": 1, "dirty": 1, "dirty_chunks": 0}
+        assert journal_ops() == {"hit": 1, "miss": 0, "invalidated": 0,
+                                 "bypassed": 1}
+        assert left == {"vouched", "fresh", "sampled"}  # the corrupt row went
+        # the journal held a row for all four: each judged by its path
+        assert identity_counts() == {"descriptor": 0, "path": 5}
+    else:
+        assert by_name == {"vouched": "pending", "fresh": "pending",
+                           "sampled": "pending", "empty": None,
+                           "gone": "pending"}
+        assert journal_ops() == {"hit": 0, "miss": 0, "invalidated": 0,
+                                 "bypassed": 4}
+        assert left == {"vouched", "fresh", "sampled", "gone"}
+        assert identity_counts() == {"descriptor": 4, "path": 1}
 
 
 # --- db.txn → body and COMMIT, and the reads ---------------------------------
@@ -437,8 +725,8 @@ def test_reads_of_a_pass_equal_the_calls(indexed):
         for name, fn in real.items():
             setattr(LibraryDb, name, fn)
     library.db.execute("UPDATE location SET name = name")
-    # the page, a journal consult a non-empty file, a find, a count
-    assert calls["n"] == 1 + 4 + 1 + 1
+    # the page, the window's one journal read, a find, a count
+    assert calls["n"] == 1 + 1 + 1 + 1
     assert db_counts()["reads"] == calls["n"]
 
 

@@ -17,7 +17,7 @@ import pytest
 from benchmark import check, harness
 from benchmark.generators import clip_roll, common
 from benchmark.reference import video as ref
-from spacedrive_tpu import native
+from spacedrive_tpu import native, telemetry
 from spacedrive_tpu.object.media.thumbnail import process
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -203,6 +203,10 @@ def indexed(tmp_path_factory, location, kind):
     root, manifest, clips = location
     data_dir = str(tmp_path_factory.mktemp("clips_node"))
     autotune.reset()
+    # a fresh registry: `sd_span_seconds` holds 64 series, and a path first
+    # seen after those a worker's earlier test files left folds into
+    # `__overflow__` (PERF.md §7), which the cases below would read as absent
+    telemetry.reset()
     before = harness.flat_counters()
     summary = asyncio.run(_index(data_dir, root))
     counters = {k: v - before.get(k, 0.0)

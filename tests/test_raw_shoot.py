@@ -14,6 +14,7 @@ import pytest
 from benchmark import check, harness
 from benchmark.generators import common, raw_shoot
 from benchmark.reference import blake3_np, cas_layout
+from spacedrive_tpu import telemetry
 from spacedrive_tpu.ops import cas
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -135,6 +136,10 @@ def _indexed(root, seed: int = SEEDS[1]) -> dict:
     manifest = raw_shoot.plan(tiny_config(), seed)
     common.write_manifest(location, manifest)
     autotune.reset()
+    # a fresh registry: `sd_span_seconds` holds 64 series, and a path first
+    # seen after those a worker's earlier test files left folds into
+    # `__overflow__` (PERF.md §7), which the cases below would read as absent
+    telemetry.reset()
     before = harness.flat_counters()
     summary = asyncio.run(_index(data_dir, location))
     counters = {k: v - before.get(k, 0.0)

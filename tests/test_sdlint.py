@@ -997,6 +997,14 @@ def test_sd012_flags_stat_and_full_read_in_scoped_modules(tmp_path):
         ["SD012"],
     )
     assert len(findings) == 1
+    # the stat of an open descriptor is the journal's too (fd_identity)
+    findings = run_scoped(
+        tmp_path,
+        "spacedrive_tpu/object/file_identifier/job.py",
+        "import os\n\ndef f(fd):\n    return os.fstat(fd).st_ino\n",
+        ["SD012"],
+    )
+    assert len(findings) == 1 and "fd_identity" in findings[0].message
 
 
 def test_sd012_silent_outside_scope_and_in_journal_itself(tmp_path):

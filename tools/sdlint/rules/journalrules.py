@@ -6,8 +6,9 @@ The incremental-indexing contract (docs/performance.md "Incremental
 indexing") is that the walker/identifier/media/duplicates orchestration
 layers consult the per-location index journal BEFORE touching a file:
 stats go through ``journal.stat_identity`` (whose result is what a
-journal verdict is judged against) and reads only happen for files the
-journal did not vouch for. A direct ``os.stat`` or an unbounded
+journal verdict is judged against; ``journal.fd_identity`` is its form
+for the descriptor a file unknown to the journal is read through) and
+reads only happen for files the journal did not vouch for. A direct ``os.stat`` or an unbounded
 ``open(...).read()`` in those modules is a byte the journal can never
 save — and, worse, a verdict computed against a *different* stat than
 the one recorded.
@@ -22,9 +23,11 @@ work the journal decided must happen.
 
 Flags:
 
-- calls to ``os.stat`` / ``os.lstat`` / ``os.path.getsize`` /
-  ``os.path.getmtime`` (``dirent.stat`` from ``os.scandir`` is exempt —
-  the walker's single stat per entry IS the journal's input);
+- calls to ``os.stat`` / ``os.lstat`` / ``os.fstat`` /
+  ``os.path.getsize`` / ``os.path.getmtime`` (``dirent.stat`` from
+  ``os.scandir`` is exempt — the walker's single stat per entry IS the
+  journal's input; the stat of an open descriptor goes through
+  ``journal.fd_identity``);
 - whole-file reads: a no-arg ``.read()`` chained directly onto
   ``open(...)``, or ``Path.read_bytes()`` / ``Path.read_text()``.
 """
@@ -52,6 +55,7 @@ ALLOWLIST_FRAGMENTS = ("location/indexer/journal.py",)
 _STAT_CALLS = {
     "os.stat",
     "os.lstat",
+    "os.fstat",
     "os.path.getsize",
     "os.path.getmtime",
 }
@@ -100,7 +104,8 @@ def check_journal_bypass(ctx: FileContext) -> Iterator[Finding]:
                 node,
                 f"`{name}` bypasses the index journal: use "
                 "location.indexer.journal.stat_identity (the stat a "
-                "journal verdict is judged against) instead",
+                "journal verdict is judged against; fd_identity for "
+                "an open descriptor) instead",
             )
             continue
         if _is_open_read(node):
