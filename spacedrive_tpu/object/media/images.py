@@ -187,6 +187,16 @@ def heif_container(path: str) -> tuple[tuple[int, int], bytes | None]:
         return size, raw[start:] if n >= 4 and start + 8 <= n else None
 
 
+def heif_frame_shape(path: str) -> tuple[int, int, int]:
+    """(h, w, channels) of the array `decode_heif` will hand on, from
+    the container alone: the handle's size, transforms applied, and
+    whether it reports an alpha channel. Nothing is decoded."""
+    with _heif_primary(path) as (lib, handle):
+        return (lib.heif_image_handle_get_height(handle),
+                lib.heif_image_handle_get_width(handle),
+                4 if lib.heif_image_handle_has_alpha_channel(handle) else 3)
+
+
 def decode_heif(path: str) -> np.ndarray:
     """HEIC/HEIF/AVIF → uint8 via the system libheif (the same C
     library the reference links, ref:crates/images/Cargo.toml:13,32),

@@ -37,10 +37,12 @@ from .process import (
     Decoded,
     ThumbError,
     can_generate,
+    chunk_len,
     decode,
+    decodes_full_size,
     finish,
     generate_one_cpu,
-    needs_cpu_fallback,
+    host_resize_reason,
     resize_cpu,
     resize_decoded,
 )
@@ -525,9 +527,20 @@ class Thumbnailer:
                     logger.debug("thumb decode failed %s: %s", path, e)
                     return None
 
-        async def _decode_chunk(chunk):
+        entries = list(batch.entries)
+        done = 0  # entries fully stored+accounted (a prefix of `entries`)
+
+        async def _decode_chunk(start: int):
+            """→ (the chunk that starts at entry `start`, its frames):
+            `chunk_rows` entries, or fewer where the frames of stills
+            that decode at full size would pass the host's byte bound
+            (`process.chunk_len` reads their headers; no other chunk
+            pays for a look)."""
+            chunk = entries[start:start + chunk_rows]
             work: list[float] = []
             async with span("thumbnail.decode") as decode_span:
+                if any(decodes_full_size(ext) for _c, _p, ext in chunk):
+                    chunk = chunk[:await asyncio.to_thread(chunk_len, chunk)]
                 decoded = await asyncio.gather(
                     *(_decode(e, work) for e in chunk))
             _tm.THUMB_STAGE_SECONDS.observe(
@@ -535,10 +548,7 @@ class Thumbnailer:
             _tm.THUMB_WORK_SECONDS.observe(sum(work), stage="decode")
             _tm.PIPELINE_HOST_SECONDS.observe(
                 decode_span.duration, pipeline="thumbnail")
-            return decoded
-
-        entries = list(batch.entries)
-        done = 0  # entries fully stored+accounted (a prefix of `entries`)
+            return chunk, decoded
 
         async def _encode_chunk(chunk, decoded, device_idx, ds, resized):
             """Final stage for one chunk: webp-encode device outputs,
@@ -549,30 +559,37 @@ class Thumbnailer:
                 if d is None:
                     self.errors += 1
                     _tm.THUMB_FILES.inc(result="error")
-            # host-path stragglers (extreme aspect / no device),
+            # host-path stragglers (an aspect over 16:1 / no device),
             # concurrent now that they ride their own pipeline stage
-            fallback = [
-                i for i, d in enumerate(decoded)
+            fallback = {
+                i: host_resize_reason(d) if self.use_device else "no_device"
+                for i, d in enumerate(decoded)
                 if d is not None and i not in device_idx
-            ]
+            }
             if device_idx and resized is None:
                 # the device stage failed past the degradation ladder:
                 # degrade the chunk to the CPU reference resize instead
                 # of erroring it — slower pixels beat missing thumbnails
-                from ....telemetry.events import RESILIENCE_EVENTS
-
-                RESILIENCE_EVENTS.emit(
-                    "thumbnail_cpu_fallback", entries=len(device_idx),
-                )
-                fallback = fallback + list(device_idx)
+                fallback.update((i, "device_failed") for i in device_idx)
                 device_idx = []
                 ds = []
 
             async def _one_fallback(i):
+                if self.use_device:
+                    # a still a device node resized on the host, whatever
+                    # the reason: `cli.device_report` counts these, so a
+                    # pass cannot say "tpu" over one
+                    from ....telemetry.events import RESILIENCE_EVENTS
+
+                    RESILIENCE_EVENTS.emit(
+                        "thumbnail_cpu_fallback", reason=fallback[i],
+                        cas_id=chunk[i][0],
+                    )
                 async with sem:  # same host-thread budget as decode
                     try:
                         webp = await asyncio.wait_for(
-                            asyncio.to_thread(resize_cpu, decoded[i]),
+                            asyncio.to_thread(
+                                resize_cpu, decoded[i], fallback[i]),
                             timeout=GENERATION_TIMEOUT_S,
                         )
                         self._store_one(batch.library_id, chunk[i][0], webp)
@@ -631,22 +648,19 @@ class Thumbnailer:
         encode_task: asyncio.Task | None = None
         try:
             while pos < len(entries) and not self._stopped:
-                chunk = entries[pos:pos + chunk_rows]
                 if decode_task is None:
-                    decode_task = asyncio.ensure_future(_decode_chunk(chunk))
-                decoded = await decode_task
+                    decode_task = asyncio.ensure_future(_decode_chunk(pos))
+                chunk, decoded = await decode_task
                 decode_task = None
                 pos += len(chunk)
                 if pos < len(entries) and not self._stopped:
                     # chunk N+1 decodes while chunk N rides the device
-                    decode_task = asyncio.ensure_future(
-                        _decode_chunk(entries[pos:pos + chunk_rows])
-                    )
+                    decode_task = asyncio.ensure_future(_decode_chunk(pos))
                 _tm.THUMB_BATCH_FILL.observe(len(chunk) / chunk_rows)
                 device_idx = [
                     i for i, d in enumerate(decoded)
                     if d is not None and self.use_device
-                    and not needs_cpu_fallback(d)
+                    and host_resize_reason(d) is None
                 ]
                 ds = [decoded[i] for i in device_idx]
                 resized = None
@@ -658,6 +672,12 @@ class Thumbnailer:
                         ) as device_span:
                             resized = await asyncio.to_thread(
                                 resize_decoded, ds)
+                            # the frames have done their work: two
+                            # chunks' worth stay on the host, not three
+                            # (unmapping a gigabyte takes the stage
+                            # some tens of milliseconds more)
+                            for d in ds:
+                                d.array = None
                         _tm.THUMB_STAGE_SECONDS.observe(
                             device_span.duration, stage="device")
                         _tm.PIPELINE_DEVICE_SECONDS.observe(

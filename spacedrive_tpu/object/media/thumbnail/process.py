@@ -32,16 +32,29 @@ logger = logging.getLogger(__name__)
 WEBP_QUALITY = 30  # ref:process.rs:440
 from ..images import MAXIMUM_FILE_SIZE as MAX_FILE_SIZE  # ref:consts.rs:9
 
-MAX_DIM = 4096  # ref:crates/images/src/consts.rs:33
+#: the longest side the device path takes whole (`benchmark/warm.py`
+#: reads it by this name)
+MAX_DIM = tj.MAX_SIDE
+#: decoded frames one chunk of the thumbnailer holds on the host: the
+#: bytes its widest device call takes (`chunk_len` cuts a chunk there)
+CHUNK_FRAME_BYTES = tj.CALL_CANVAS_BYTES
 
 
 def shrink_to_max_dim(arr: "np.ndarray") -> "np.ndarray":
-    """Stride-downsample oversized decodes to fit the largest bucket
-    (the reference rejects >4096² outright; we degrade instead)."""
+    """Every frame on its way to the resize passes here and is counted:
+    `whole` as the decoder handed it on, or, with a side over MAX_DIM,
+    `thinned` to every `step`-th row and column so that the largest
+    canvas holds it (unfiltered: what a panorama of 30,000 pixels still
+    pays). Whether the reference rejects a picture over some size
+    (crates/images/src/consts.rs:33, cited from memory) cannot be
+    checked here: no copy of upstream is at hand."""
     h, w = arr.shape[:2]
     if max(h, w) > MAX_DIM:
         step = math.ceil(max(h, w) / MAX_DIM)
         arr = np.ascontiguousarray(arr[::step, ::step])
+        _tm.THUMB_FRAMES.inc(path="thinned")
+    else:
+        _tm.THUMB_FRAMES.inc(path="whole")
     return arr
 
 # Decodable subsets of the taxonomy (the taxonomy stays the single
@@ -101,7 +114,9 @@ FrameTap = Callable[[Any, int], None]
 @dataclass
 class Decoded:
     """One decoded frame ready for the device batch."""
-    array: np.ndarray  # uint8, HxWx3 RGB or HxWx4 RGBA where there is alpha
+    # uint8, HxWx3 RGB or HxWx4 RGBA where there is alpha; None once the
+    # device stage has resized it (the encode stage needs the rest only)
+    array: np.ndarray | None
     target: tuple[int, int]  # (th, tw) scaled dims
     orientation: int = 1
     is_video: bool = False  # film-strip overlay on finish
@@ -153,13 +168,65 @@ def decode_image(path: str, tap: FrameTap | None = None) -> Decoded:
     return Decoded(array=arr, target=(th, tw), orientation=orientation)
 
 
+def host_resize_reason(d: Decoded) -> str | None:
+    """Why a frame cannot take the batched device path and resizes on
+    the host: `aspect` for a target beyond the output canvases (over
+    16:1), `size` for a frame no canvas holds; None for every other."""
+    if tj.out_canvas_for(*d.target) is None:
+        return "aspect"
+    if tj.bucket_for(*d.array.shape[:2]) is None:
+        return "size"
+    return None
+
+
 def needs_cpu_fallback(d: Decoded) -> bool:
-    """Targets beyond the device output canvas (aspect > 4:1) resize on
-    host instead of the batched device path."""
-    oh, ow = tj.OUT_CANVAS_HW
-    return min(d.target) > oh or max(d.target) > ow or max(
-        d.array.shape[:2]
-    ) > tj.BUCKETS[-1]
+    """Whether the frame resizes on the host (`host_resize_reason`)."""
+    return host_resize_reason(d) is not None
+
+
+def decodes_full_size(extension: str | None) -> bool:
+    """Whether `decode` hands on the file's every pixel: a still that is
+    no JPEG (a JPEG decodes in draft mode, near its target), no clip's
+    frame and no document's render (both thinned or capped to MAX_DIM
+    and under)."""
+    e = (extension or "").lower()
+    return e in IMAGE_EXTENSIONS and e not in ("jpg", "jpeg")
+
+
+def frame_bytes(path: str, extension: str | None) -> int:
+    """nbytes of the frame `decode` will hand on, from the file's header
+    alone; 0 where `decodes_full_size` says the decoder bounds it, and
+    for a file whose header cannot be read (its decode says why)."""
+    if not decodes_full_size(extension):
+        return 0
+    try:
+        if (extension or "").lower() in HEIF_EXTENSIONS:
+            from ..images import heif_frame_shape
+
+            h, w, channels = heif_frame_shape(path)
+        else:
+            from PIL import Image
+
+            with Image.open(path) as img:
+                w, h = img.size
+                channels = 4 if img.has_transparency_data else 3
+    except Exception:
+        return 0
+    return h * w * channels
+
+
+def chunk_len(entries: list[tuple[str, str, str]]) -> int:
+    """How many of `entries` ((cas_id, path, extension), in order) one
+    chunk of the thumbnailer takes: all of them, unless the frames their
+    decodes hand on pass CHUNK_FRAME_BYTES before the last (32 decoded
+    48 MP photos are 4.7 GB); then those before the one that passes it,
+    and always one."""
+    held = 0
+    for n, (_cas_id, path, ext) in enumerate(entries):
+        held += frame_bytes(path, ext)
+        if held > CHUNK_FRAME_BYTES and n:
+            return n
+    return len(entries)
 
 
 def _video_frame_native(path: str) -> tuple[np.ndarray, int, bool]:
@@ -342,11 +409,13 @@ def resize_decoded(batch: list[Decoded]) -> list[np.ndarray]:
     return tj.resize_batch([d.array for d in batch], [d.target for d in batch])
 
 
-def resize_cpu(d: Decoded) -> bytes:
+def resize_cpu(d: Decoded, reason: str = "no_device") -> bytes:
     """Pure-CPU fallback path (extreme aspect ratios / no device): PIL
-    resize with the same Triangle filter + quality."""
+    resize with the same Triangle filter + quality. Counted by `reason`
+    (`sd_thumbnail_host_resize_total`)."""
     from PIL import Image
 
+    _tm.THUMB_HOST_RESIZE.inc(reason=reason)
     th, tw = d.target
     img = Image.fromarray(d.array).resize((tw, th), Image.BILINEAR)
     arr = tj.apply_orientation(np.asarray(img), d.orientation)

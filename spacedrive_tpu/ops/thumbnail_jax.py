@@ -25,10 +25,18 @@ variable-shape).
 What crosses the link is held to what the pixels need: the colour
 planes go as `[B, bh, bw, 3]` canvases, an alpha plane goes beside them
 (`[B, bh, bw, 1]`, the same jitted function) only for the images that
-have one, every thumbnail comes back in the one landscape
-`OUT_CANVAS_HW` canvas, the host side of a canvas is a kept staging
-buffer that `pack` writes a frame and the filter's margin into, and on
-the link the planes are folded into the row (`_resize_rows` says why).
+have one, every thumbnail comes back in a landscape output canvas
+(`OUT_CANVAS_HW`, or `OUT_CANVAS_WIDE_HW` for a target over 4:1), the
+host side of a canvas is a kept staging buffer that `pack` writes a
+frame and the filter's margin into, on the link the planes are folded
+into the row (`_resize_rows` says why), and one call takes at most
+`CALL_CANVAS_BYTES` of canvases (`call_rows`).
+
+The host never resamples a frame with a side up to `MAX_SIDE`: above
+the square rungs a canvas is one of `WIDE_BUCKETS` wide and a quarter,
+a half, three quarters or the whole of that high (`bucket_for`), so a
+24 MP photo, a 48 MP one and a 16,000-pixel panorama each go to the
+filter with every pixel the decoder handed on.
 """
 
 from __future__ import annotations
@@ -45,17 +53,40 @@ TARGET_PX = 262144  # ref:core/src/object/media/thumbnail/mod.rs:45
 WEBP_QUALITY = 30  # ref:thumbnail/mod.rs:49
 VIDEO_MAX_DIM = 256  # ref:thumbnail/process.rs:470
 
-# Square input buckets (images are padded up to the next one). 4096 is
-# the reference's max decodable dimension (ref:crates/images/src/consts.rs:33).
+# Square input buckets (images are padded up to the next one). Whether
+# upstream caps a decodable dimension at 4096 (crates/images/src/
+# consts.rs:33, cited from memory) cannot be checked here; what is known
+# is that its thumbnail is a Triangle filter over the whole decoded
+# picture (BASELINE.md:28).
 BUCKETS = (256, 512, 1024, 2048, 4096)
-# Longest side a device thumbnail may have: covers aspect ratios up to
-# 4:1 at TARGET_PX (tw = sqrt(262144·4) = 1024); more extreme aspects
-# fall back to CPU.
+# Canvas widths above the squares (ISSUE 38): a frame with a side over
+# 4096 takes the smallest canvas by area among these widths at a
+# quarter, a half, three quarters or the whole of the width high. A
+# 5712 × 4284 photo fills 86 % of (4608, 6144), an 8064 × 6048 one 97 %
+# of (6144, 8192), a 16382 × 3628 panorama 89 % of (4096, 16384); a
+# square 8192 rung would hold the first at 36 %.
+WIDE_BUCKETS = (6144, 8192, 12288, 16384)
+# Longest side the device path takes whole.
+MAX_SIDE = WIDE_BUCKETS[-1]
+# Longest side of a thumbnail in the first output canvas: covers aspect
+# ratios up to 4:1 at TARGET_PX (tw = sqrt(262144·4) = 1024).
 OUT_CANVAS = 1024
-MAX_ASPECT = (OUT_CANVAS * OUT_CANVAS) / TARGET_PX  # 4.0
-# The one output canvas, landscape: a portrait whose target is higher
-# transposes in, so th ≤ tw and th·tw ≈ TARGET_PX give th ≤ 512.
+# The output canvases, landscape: a portrait whose target is higher
+# transposes in, so th ≤ tw and th·tw ≈ TARGET_PX give th ≤ 512. The
+# second takes the targets over 4:1 up to MAX_ASPECT (tw = sqrt(262144·16)
+# = 2048, th ≤ 256: a panorama); more extreme aspects resize on the
+# host, counted (`sd_thumbnail_host_resize_total`).
 OUT_CANVAS_HW = (OUT_CANVAS // 2, OUT_CANVAS)
+OUT_CANVAS_WIDE_HW = (OUT_CANVAS // 4, 2 * OUT_CANVAS)
+MAX_ASPECT = (2 * OUT_CANVAS) ** 2 / TARGET_PX  # 16.0
+# Canvas bytes one device call takes (a chunk's bucket goes in as many
+# calls as that asks for, `call_rows`). 1.5 GiB is the widest call
+# formed before there was a bound, 32 canvases of (4096, 4096) × 3 or
+# 128 of (2048, 2048) × 3, which so still go as one; XLA fuses the
+# float32 convert into the first pass (1.71 GB of peak HBM for that
+# call, PERF.md §4), and were it to materialise the float32 canvases
+# they would be 6 GiB beside the 1.5, inside the chip's 16 GB.
+CALL_CANVAS_BYTES = 3 << 29
 
 
 def scale_dimensions(w: int, h: int, target_px: int = TARGET_PX) -> tuple[int, int]:
@@ -77,6 +108,24 @@ def video_dimensions(w: int, h: int, max_dim: int = VIDEO_MAX_DIM) -> tuple[int,
     return max(1, round(w * ratio)), max(1, round(h * ratio))
 
 
+def call_rows(bh: int, bw: int, planes: int) -> int:
+    """Canvases of (bh, bw, planes) one device call takes: the largest
+    power of two within `CALL_CANVAS_BYTES`, and always one."""
+    fit = max(1, CALL_CANVAS_BYTES // (bh * bw * planes))
+    return 1 << (fit.bit_length() - 1)
+
+
+def out_canvas_for(th: int, tw: int) -> tuple[int, int] | None:
+    """The output canvas a (th, tw) target comes back in, whichever way
+    up it goes in; None beyond `MAX_ASPECT` (or for a target larger than
+    `scale_dimensions` makes one)."""
+    lo, hi = sorted((th, tw))
+    for oh, ow in (OUT_CANVAS_HW, OUT_CANVAS_WIDE_HW):
+        if lo <= oh and hi <= ow:
+            return oh, ow
+    return None
+
+
 def bucket_for(h: int, w: int) -> tuple[int, int] | None:
     """Smallest canvas bucket holding (h, w) in its landscape
     orientation; None if over the cap.
@@ -87,11 +136,16 @@ def bucket_for(h: int, w: int) -> tuple[int, int] | None:
     count at 2 per ladder rung (the reason canvases exist at all:
     SURVEY §7 hard part 3, shape bucketing vs recompilation). A portrait
     that does not fit it as it stands transposes in on the host
-    (resize_batch), so both orientations share one device call."""
+    (resize_batch), so both orientations share one device call. A frame
+    with a side over 4096 takes one of the `WIDE_BUCKETS` canvases."""
     m = max(h, w)
     b = next((x for x in BUCKETS if m <= x), None)
     if b is None:
-        return None
+        # above the squares: every quarter step of every width, the
+        # smallest by area (ties to the narrower)
+        fits = [(q * x // 4, x) for x in WIDE_BUCKETS if m <= x
+                for q in (1, 2, 3, 4) if min(h, w) <= q * x // 4]
+        return min(fits, key=lambda c: (c[0] * c[1], c[1]), default=None)
     half = b // 2
     # only the big rungs: for small canvases the halved payload saves
     # less than the compile + executable load each extra jitted shape
@@ -263,10 +317,11 @@ def _pack_one(canvas: np.ndarray, img: np.ndarray,
     return (h + my) * (w + mx) * planes
 
 
-def _resize_bucket(images, targets, bh: int, bw: int, devs) -> np.ndarray:
+def _resize_bucket(images, targets, bh: int, bw: int, devs,
+                   out_hw: tuple[int, int] = OUT_CANVAS_HW) -> np.ndarray:
     """Pack one bucket's canvases and run its device call: `images` are
     [h, w, C] uint8 arrays of one C that fit (bh, bw) as they are handed
-    in, `targets` their (th, tw).
+    in, `targets` their (th, tw), each inside the output canvas `out_hw`.
     Returns the [bpad, OH, OW, C] uint8 result (validated — a device
     returning the wrong shape is an error the caller can demote on,
     never a silent corruption)."""
@@ -286,7 +341,8 @@ def _resize_bucket(images, targets, bh: int, bw: int, devs) -> np.ndarray:
     if n_dev > 1:
         bpad = max(bpad, n_dev)
         bpad += (-bpad) % n_dev
-    oh, ow = OUT_CANVAS_HW
+    oh, ow = out_hw
+    bucket = f"{bh}x{bw}"
     with _staging_canvas(bpad, bh, bw, n_planes) as canv:
         with _span("pack") as part:
             scales = np.ones((bpad, 2), np.float32)
@@ -332,6 +388,8 @@ def _resize_bucket(images, targets, bh: int, bw: int, devs) -> np.ndarray:
             result = jax.block_until_ready(
                 fn(*on_device, out_hw=(oh, ow), planes=n_planes))
         _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="run")
+        _tm.THUMB_DEVICE_CALLS.inc(bucket=bucket, out=f"{oh}x{ow}")
+        _tm.THUMB_CANVAS_BYTES.inc(canv.nbytes, bucket=bucket)
         with _span("get") as part:
             out = np.asarray(result)
         _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="get")
@@ -346,7 +404,8 @@ def _resize_bucket(images, targets, bh: int, bw: int, devs) -> np.ndarray:
     return out.reshape(bpad, oh, ow, n_planes)
 
 
-def _resize_on_ladder(images, targets, bh: int, bw: int) -> np.ndarray:
+def _resize_on_ladder(images, targets, bh: int, bw: int,
+                      out_hw: tuple[int, int]) -> np.ndarray:
     """`_resize_bucket` on the degradation ladder (parallel.mesh.LADDER):
     a failed bucket call demotes — full mesh → surviving subset → single
     default device (the per-image math is identical at every rung, so
@@ -370,7 +429,7 @@ def _resize_on_ladder(images, targets, bh: int, bw: int) -> np.ndarray:
         else:
             use = None
         try:
-            out = _resize_bucket(images, targets, bh, bw, use)
+            out = _resize_bucket(images, targets, bh, bw, use, out_hw)
         except Exception as exc:  # noqa: BLE001 - demote & retry
             # always settle the ladder bookkeeping (a probe left
             # unreported would block re-arming), THEN decide whether
@@ -400,17 +459,20 @@ def resize_batch(
     """Resize a batch of uint8 images, HxWx3 RGB or HxWx4 RGBA, to
     per-image (th, tw); a result has its input's channels.
 
-    Groups by input bucket, writes each image and the filter's margin
-    into the bucket's kept canvas (`_pack_one`), runs one device call
-    per bucket for the colour planes and one more, through the same
-    program at one plane, for the alpha of those images that have it;
-    crops on host. A portrait goes in as it stands where its bucket and
-    `OUT_CANVAS_HW` take it that way (a clip's 1920 × 1080 frame, bound
-    to 256 × 144), transposed where they do not (a photo: its target is
-    over 512 high); the two ways run the separable passes in the other
-    order, so a byte may differ by 1 where a float sum lands on .5.
+    Groups by input bucket and output canvas, writes each image and
+    the filter's margin into the bucket's kept canvas (`_pack_one`),
+    runs the group's device calls (one, or as many as `call_rows` makes
+    of it: the per-image math does not depend on its neighbours, so the
+    bytes are the same either way) for the colour planes and again,
+    through the same program at one plane, for the alpha of those
+    images that have it; crops on host. A portrait goes in as it stands
+    where its bucket and its output canvas take it that way (a clip's
+    1920 × 1080 frame, bound to 256 × 144), transposed where they do not
+    (a photo: its target is over 512 high); the two ways run the
+    separable passes in the other order, so a byte may differ by 1 where
+    a float sum lands on .5.
     Returns resized uint8 arrays in input order. Images too large for
-    any bucket or with th/tw beyond the output canvas must be filtered
+    any bucket or with th/tw beyond the output canvases must be filtered
     by the caller beforehand.
 
     With >1 local device (or an explicit `devices` list) the batch dim
@@ -422,9 +484,8 @@ def resize_batch(
     from ..telemetry import metrics as _tm
     from ..telemetry import span as _span
 
-    oh, ow = OUT_CANVAS_HW
     results: list[np.ndarray | None] = [None] * len(images)
-    by_bucket: dict[tuple[int, int], list[int]] = {}
+    by_bucket: dict[tuple, list[int]] = {}
     # a portrait that its bucket or the output canvas does not take as it
     # stands transposes in (a view here; `_pack_one` makes the copy) and
     # is un-transposed after the crop
@@ -439,6 +500,7 @@ def resize_batch(
         if b is None:
             raise ValueError(f"image {i} ({h}x{w}) exceeds max bucket")
         th, tw = targets[i]
+        oh, ow = out_hw = out_canvas_for(th, tw) or OUT_CANVAS_HW
         if h > w and (h > b[0] or th > oh or tw > ow):
             flip[i] = True
             placed[i] = np.transpose(img, (1, 0, 2))
@@ -446,27 +508,37 @@ def resize_batch(
         if th > oh or tw > ow:
             raise ValueError(
                 f"image {i}: target {targets[i]} exceeds the output canvas")
-        by_bucket.setdefault(b, []).append(i)
+        by_bucket.setdefault((b, out_hw), []).append(i)
 
-    def dispatch(members, channels, bh, bw):
-        group = [placed[i][..., channels] for i in members]
-        want = [canvas_targets[i] for i in members]
-        if devices is not None:
-            return _resize_bucket(group, want, bh, bw, list(devices))
-        return _resize_on_ladder(group, want, bh, bw)
+    def dispatch(members, channels, bh, bw, out_hw):
+        """→ the members' [OH, OW, C] results, in as many device calls
+        as `call_rows` makes of them."""
+        rows = call_rows(bh, bw, channels.stop - channels.start)
+        out: list[np.ndarray] = []
+        for lo in range(0, len(members), rows):
+            part = members[lo:lo + rows]
+            group = [placed[i][..., channels] for i in part]
+            want = [canvas_targets[i] for i in part]
+            if devices is not None:
+                got = _resize_bucket(group, want, bh, bw, list(devices),
+                                     out_hw)
+            else:
+                got = _resize_on_ladder(group, want, bh, bw, out_hw)
+            out.extend(got[:len(part)])
+        return out
 
-    for (bh, bw), idxs in by_bucket.items():
+    for ((bh, bw), out_hw), idxs in by_bucket.items():
         with_alpha = [i for i in idxs if placed[i].shape[2] == 4]
-        colour = dispatch(idxs, slice(0, 3), bh, bw)
-        alpha = dispatch(with_alpha, slice(3, 4), bh, bw) if with_alpha else None
+        colour = dispatch(idxs, slice(0, 3), bh, bw, out_hw)
+        alpha = dispatch(with_alpha, slice(3, 4), bh, bw, out_hw)
         with _span("crop") as part:
             alpha_row = {i: k for k, i in enumerate(with_alpha)}
             for j, i in enumerate(idxs):
                 th, tw = canvas_targets[i]
-                out = colour[j, :th, :tw]
+                out = colour[j][:th, :tw]
                 if i in alpha_row:
                     out = np.concatenate(
-                        [out, alpha[alpha_row[i], :th, :tw]], axis=-1)
+                        [out, alpha[alpha_row[i]][:th, :tw]], axis=-1)
                 results[i] = np.transpose(out, (1, 0, 2)) if flip[i] else out
         _tm.THUMB_DEVICE_SECONDS.inc(part.duration, part="crop")
         if with_alpha:

@@ -183,6 +183,33 @@ THUMB_RESIZE_IMAGES = REGISTRY.counter(
     "the colour planes",
     labels=("alpha",),  # 0 | 1
 )
+THUMB_DEVICE_CALLS = REGISTRY.counter(
+    "sd_thumbnail_device_calls_total",
+    "device calls of the resize, by the input canvas (rung) and the output "
+    "canvas of the program that ran, counted beside thumbnail.device.run",
+    labels=("bucket", "out"),  # e.g. 4608x6144 ; 512x1024 | 256x2048
+)
+THUMB_CANVAS_BYTES = REGISTRY.counter(
+    "sd_thumbnail_canvas_bytes_total",
+    "nbytes of the input canvases of those calls, the pad rows and the "
+    "canvas round each frame included, by rung",
+    labels=("bucket",),
+)
+THUMB_FRAMES = REGISTRY.counter(
+    "sd_thumbnail_frames_total",
+    "decoded frames as they reach the resize: whole (every pixel the "
+    "decoder handed on), or thinned by a stride on the host because a "
+    "side passed MAX_DIM",
+    labels=("path",),  # whole | thinned
+)
+THUMB_HOST_RESIZE = REGISTRY.counter(
+    "sd_thumbnail_host_resize_total",
+    "frames resized by PIL on a host thread (resize_cpu) and not on the "
+    "device: a target beyond the output canvases (aspect), a frame beyond "
+    "the rungs (size), a device stage that failed past the ladder "
+    "(device_failed), a node that uses no device (no_device)",
+    labels=("reason",),  # aspect | size | device_failed | no_device
+)
 
 THUMB_VIDEO_FRAMES = REGISTRY.counter(
     "sd_thumbnail_video_frames_total",

@@ -4,7 +4,8 @@ libtpu ships the compiler, so `jax.experimental.topologies` can hand out
 a `v5e:2x2` topology descriptor on a CPU-only host and `.lower().compile()`
 runs Mosaic + XLA:TPU against it. A PR learns "Mosaic refuses this
 kernel" or "this resize bucket no longer fits" here, at no chip cost.
-Slow lane only: the two programs take roughly 17 s + 11 s to compile.
+Slow lane only: the programs take roughly 17 s + 11 s to compile, the
+two widest resize calls (ISSUE 38) about 17 s each.
 """
 
 import os
@@ -48,6 +49,21 @@ for planes in (3, 1):
     # canvases + scales in, one output canvas each out — and it fits a chip
     mem = resized.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 << 30
+
+# the widest calls the byte bound lets through (ISSUE 38): a panorama's
+# canvases into the second output canvas, and the largest canvas there is
+for (bh, bw), out_hw in (((4096, 16384), thumbnail_jax.OUT_CANVAS_WIDE_HW),
+                         ((16384, 16384), thumbnail_jax.OUT_CANVAS_HW)):
+    pad = thumbnail_jax.call_rows(bh, bw, 3)
+    assert pad * bh * bw * 3 <= thumbnail_jax.CALL_CANVAS_BYTES
+    resized = thumbnail_jax._resize_fn().lower(
+        spec((pad, bh, bw * 3), np.uint8), spec((pad, 2), np.float32),
+        out_hw=out_hw, planes=3,
+    ).compile()
+    assert resized.out_info.shape == (pad, out_hw[0], out_hw[1] * 3)
+    mem = resized.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 8 << 30  # 3.6 and 5.4 GB of 16
 print("AOT_OK")
 """
 

@@ -115,11 +115,11 @@ def test_tap_plane_is_decode_image_plane(tmp_path, case):
     assert scale == SCALES.get(case, 1)
     assert frame.mode in ("RGB", "RGBA")
     if case == "png_over_4096":
-        # the plane is taken before shrink_to_max_dim thins the frame
+        # since ISSUE 38 no stride thins it: the frame the tap saw is the
+        # frame the resize gets ((150, 2100) of it before)
         assert frame.size == (4200, 300)
-        assert decoded.array.shape[:2] == (150, 2100)
-    else:
-        assert np.array_equal(decoded.array, np.asarray(frame))
+        assert decoded.array.shape[:2] == (300, 4200)
+    assert np.array_equal(decoded.array, np.asarray(frame))
 
     before = _planes()
     shared = embedder.plane_from_frame(frame, scale)
@@ -564,8 +564,11 @@ GOLDEN = {
         "d5446d325abdf63810055443", "e266088c0ebfd92c61775907"],
     "jpeg_grey": [[768, 1024, 3], [443, 591], 1,
         "71ba0c8a4466d8af453b2dd3", "399be017a7801f683021b6a1"],
-    "png_over_4096": [[150, 2100, 3], [137, 1916], 1,
-        "70ce320a9df53f50a1919098", "5c9a14764911e8437949f055"],
+    # since ISSUE 38 the frame goes whole, through the device's second
+    # output canvas: on the parent `[::2, ::2]` made it (150, 2100) and
+    # PIL resized that on a host thread ("70ce320a…", "5c9a1476…")
+    "png_over_4096": [[300, 4200, 3], [137, 1916], 1,
+        "b098d8fefd2705e0083e4476", "fab0f5626cb34abbb03d6a7f"],
     "png_p_transparent": [[375, 500, 4], [375, 500], 1,
         "3a0bb018b8fb55859ccaa231", "07540687b16effb429b1e0b8"],
     "png_rgb": [[375, 500, 3], [375, 500], 1,

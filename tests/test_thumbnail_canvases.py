@@ -82,15 +82,20 @@ def test_aspects_up_to_four_fit_the_canvas_the_right_way_up(h, w):
     assert np.abs(out.astype(int) - ref.astype(int)).mean() < 1.5
 
 
-@pytest.mark.parametrize("h,w", [(500, 2005), (2005, 500)])
-def test_beyond_four_to_one_still_resizes_on_the_host(h, w):
+@pytest.mark.parametrize("h,w", [(250, 4100), (4100, 250)])
+def test_beyond_sixteen_to_one_still_resizes_on_the_host(h, w):
+    """The limit stood at 4:1 until ISSUE 38 (tests/test_photolib_hires.py
+    holds what lies between); beyond 16:1 the host's path stays, counted."""
     img = _photo(h, w, 3)
     d = process.Decoded(array=img, target=_targets([img])[0])
     assert process.needs_cpu_fallback(d)
+    assert process.host_resize_reason(d) == "aspect"
     with pytest.raises(ValueError, match="exceeds the output canvas"):
         process.resize_decoded([d])
-    with Image.open(io.BytesIO(process.resize_cpu(d))) as im:
+    counted = tm.THUMB_HOST_RESIZE.value(reason="aspect")
+    with Image.open(io.BytesIO(process.resize_cpu(d, "aspect"))) as im:
         assert im.size == (d.target[1], d.target[0])
+    assert tm.THUMB_HOST_RESIZE.value(reason="aspect") == counted + 1
 
 
 # (c) what is stored: transparency is kept, and only where there is some
