@@ -27,10 +27,11 @@ planes go as `[B, bh, bw, 3]` canvases, an alpha plane goes beside them
 (`[B, bh, bw, 1]`, the same jitted function) only for the images that
 have one, every thumbnail comes back in a landscape output canvas
 (`OUT_CANVAS_HW`, or `OUT_CANVAS_WIDE_HW` for a target over 4:1), the
-host side of a canvas is a kept staging buffer that `pack` writes a
-frame and the filter's margin into, on the link the planes are folded
-into the row (`_resize_rows` says why), and one call takes at most
-`CALL_CANVAS_BYTES` of canvases (`call_rows`).
+host side of a call's canvases is a view of one kept buffer, the arena
+(`_staging_canvas`), that `pack` writes a frame and the filter's margin
+into, on the link the planes are folded into the row (`_resize_rows`
+says why), and one call takes at most `CALL_CANVAS_BYTES` of canvases
+(`call_rows`).
 
 The host never resamples a frame with a side up to `MAX_SIDE`: above
 the square rungs a canvas is one of `WIDE_BUCKETS` wide and a quarter,
@@ -241,45 +242,47 @@ def _resize_fn_sharded(devices):
     return fn
 
 
-# Kept host canvases, one per (bucket, planes), grown to the widest pad
-# seen and lent to one call at a time: a fresh 100 MiB mapping per call
-# pays a page fault per 4 KiB filled. Bounded, least recently used out
-# first, so a process that once resized a full batch of 4096² canvases
-# does not hold them for good. A chunk of 32 RGB clips (2048², 384 MiB)
-# and its stills' canvas fit together; `sd_thumbnail_staging_total` says
-# whether a call found its canvas.
-_STAGING_MAX_BYTES = 512 << 20
-_staging: dict[tuple[int, int, int], np.ndarray] = {}
-_staging_lock = threading.Lock()
+# The kept host side of every bucket call: one flat buffer (the arena),
+# lent to one call at a time as a view of its first bytes, whatever the
+# call's bucket, pad and planes. A canvas mapped anew pays a page fault
+# per 4 KiB `pack` fills (1.4-1.5 s of a 1.5 GiB call on the chip's
+# host). No call takes more than `CALL_CANVAS_BYTES` of canvases, so the
+# arena grows to the largest call seen and never past that; None while
+# it is lent, and before the first call. `sd_thumbnail_staging_total`
+# says whether a call found it.
+_arena: np.ndarray | None = None
+_arena_lock = threading.Lock()
 
 
 @contextlib.contextmanager
 def _staging_canvas(bpad: int, bh: int, bw: int, planes: int):
-    """Lend the [bpad, bh, bw, planes] staging canvas of a bucket for
-    one device call. A caller that finds it lent (two actors resizing at
-    once) or too narrow gets a fresh one (counted `mapped`, else `kept`).
-    The canvas is taken back only when the call has returned its output:
-    on the CPU backend `device_put` may alias the host array, and a call
-    that failed may still be reading it. Its bytes are whatever the last
-    call left there, or nothing yet: `_pack_one` says what a call has to
-    write."""
+    """Lend a [bpad, bh, bw, planes] staging canvas for one device call:
+    a view of the arena where it is there and large enough (counted
+    `kept`), else a canvas of the call's own (`mapped`: first use, a
+    larger call, the arena lent to another call, or a call over
+    `CALL_CANVAS_BYTES`). The canvas is taken back only when the call
+    has returned its output: on the CPU backend `device_put` may alias
+    the host array, and a call that failed may still be reading it. Of
+    the arena and a canvas taken back the larger is kept, up to the
+    bound. Its bytes are whatever the last call of any bucket left
+    there, or nothing yet: `_pack_one` says what a call has to write."""
     from ..telemetry import metrics as _tm
 
-    key = (bh, bw, planes)
-    with _staging_lock:
-        buf = _staging.pop(key, None)
-    kept = buf is not None and buf.shape[0] >= bpad
+    global _arena
+    shape = (bpad, bh, bw, planes)
+    size = math.prod(shape)
+    with _arena_lock:
+        kept = _arena is not None and _arena.size >= size
+        if kept:
+            buf, _arena = _arena, None
     if not kept:
-        buf = np.empty((bpad, bh, bw, planes), np.uint8)
+        buf = np.empty(size, np.uint8)
     _tm.THUMB_STAGING.inc(result="kept" if kept else "mapped")
-    yield buf[:bpad]
-    with _staging_lock:
-        other = _staging.pop(key, None)
-        if other is not None and other.shape[0] > buf.shape[0]:
-            buf = other
-        _staging[key] = buf  # last in the dict: most recently used
-        while sum(c.nbytes for c in _staging.values()) > _STAGING_MAX_BYTES:
-            del _staging[next(iter(_staging))]
+    yield buf[:size].reshape(shape)
+    with _arena_lock:
+        if buf.size <= CALL_CANVAS_BYTES and (
+                _arena is None or buf.size > _arena.size):
+            _arena = buf
 
 
 def _pack_one(canvas: np.ndarray, img: np.ndarray,
@@ -295,9 +298,10 @@ def _pack_one(canvas: np.ndarray, img: np.ndarray,
     rows and columns (one more, for float32's rounding of the bound)
     are the image's edge replicated, so that the window clamps at the
     image's boundary as the reference resampler does, and the corner
-    between them. Whatever the rest of the canvas holds is a finite
-    uint8 times a weight of 0, in the sum and in the weights' own
-    normalising sum alike, so nothing else is written.
+    between them. Whatever the rest of the canvas holds (the bytes a
+    previous call of any bucket, pad or plane count left in the arena)
+    is a finite uint8 times a weight of 0, in the sum and in the
+    weights' own normalising sum alike, so nothing else is written.
 
     A frame whose rows are contiguous goes in as one copy. Anything
     else (a portrait transposed in, the colour planes of an RGBA still)
@@ -460,10 +464,11 @@ def resize_batch(
     per-image (th, tw); a result has its input's channels.
 
     Groups by input bucket and output canvas, writes each image and
-    the filter's margin into the bucket's kept canvas (`_pack_one`),
-    runs the group's device calls (one, or as many as `call_rows` makes
-    of it: the per-image math does not depend on its neighbours, so the
-    bytes are the same either way) for the colour planes and again,
+    the filter's margin into its call's canvas, a view of the kept
+    arena (`_staging_canvas`, `_pack_one`), runs the group's device
+    calls (one, or as many as `call_rows` makes of it: the per-image
+    math does not depend on its neighbours, so the bytes are the same
+    either way) for the colour planes and again,
     through the same program at one plane, for the alpha of those
     images that have it; crops on host. A portrait goes in as it stands
     where its bucket and its output canvas take it that way (a clip's

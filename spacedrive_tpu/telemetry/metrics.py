@@ -167,8 +167,9 @@ THUMB_PACK_BYTES = REGISTRY.counter(
 )
 THUMB_STAGING = REGISTRY.counter(
     "sd_thumbnail_staging_total",
-    "bucket calls by whether the kept staging canvas was there (kept) or "
-    "one had to be allocated: first use, a wider pad, lent, evicted (mapped)",
+    "bucket calls by whether the kept staging arena was there (kept) or "
+    "a canvas had to be allocated: first use, a larger call, the arena "
+    "lent, a call over the bound CALL_CANVAS_BYTES (mapped)",
     labels=("result",),  # kept | mapped
 )
 THUMB_DEVICE_BYTES = REGISTRY.counter(

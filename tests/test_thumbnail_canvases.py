@@ -1,10 +1,12 @@
 """The canvases of the thumbnailer's device stage (ISSUE 28): three
 colour planes where the image has no alpha, an alpha plane beside them
 where it has, one landscape 512 × 1024 output canvas, a kept staging
-buffer; what `pack` writes into it (ISSUE 33): a frame once, the margin
-the filter reads, a portrait as it stands where its canvas takes it;
-and the contract `benchmark/warm.py` holds the stage to."""
+buffer (since ISSUE 39 one arena for every bucket call); what `pack`
+writes into it (ISSUE 33): a frame once, the margin the filter reads, a
+portrait as it stands where its canvas takes it; and the contract
+`benchmark/warm.py` holds the stage to."""
 
+import contextlib
 import io
 import sys
 import threading
@@ -147,82 +149,134 @@ def test_decode_hands_over_alpha_only_where_the_file_has_it(tmp_path):
         str(tmp_path / "pal.png")).array.shape == (64, 96, 4)
 
 
-# (d) the kept staging canvas
+# (d) the kept staging arena: one flat buffer for every bucket call
 
 
 @pytest.fixture
-def staging(monkeypatch):
-    """An empty set of kept canvases: what other tests of this process
-    left there does not decide which canvas is kept here."""
-    kept: dict = {}
-    monkeypatch.setattr(tj, "_staging", kept)
-    return kept
-
-
-def test_second_call_through_the_kept_canvas_returns_nothing_of_the_first(
-        staging):
-    bright = [np.full((700, 1000, 3), 255, np.uint8) for _ in range(4)]
-    tj.resize_batch(bright, _targets(bright))
-    key = (1024, 1024, 3)
-    kept = staging[key]
-    assert kept.shape[0] >= 4 and (kept[:4, :700, :1000] == 255).all()
-    kept[...] = 255  # the most a call could have left there
-    dark = [np.zeros((520, 640, 3), np.uint8)]
-    out = tj.resize_batch(dark, _targets(dark))[0]
-    assert out.shape == (*_targets(dark)[0], 3)
-    assert (out == 0).all()
-    # one canvas per (bucket, planes), the same one, and never the result
-    assert staging[key] is kept and list(staging) == [key]
-    assert not np.shares_memory(out, kept)
+def arena(monkeypatch):
+    """No arena yet: what other tests of this process left there does
+    not decide what is kept here (monkeypatch puts theirs back)."""
+    monkeypatch.setattr(tj, "_arena", None)
 
 
 def _staging_counts() -> dict:
     return {r: tm.THUMB_STAGING.value(result=r) for r in ("kept", "mapped")}
 
 
-def test_second_call_of_a_shape_gets_the_canvas_the_first_left(staging):
-    frames = [_photo(180, 320, 3), _photo(320, 180, 3), _photo(200, 300)]
-    start, wrote = _staging_counts(), tm.THUMB_PACK_BYTES.value()
-    first = tj.resize_batch(frames, _targets(frames))
-    mapped = _staging_counts()
-    # one bucket: a colour canvas for the three, an alpha canvas for one
-    assert mapped == {"kept": start["kept"], "mapped": start["mapped"] + 2}
-    assert set(staging) == {(512, 512, 3), (512, 512, 1)}
-    canvases = {k: v.ctypes.data for k, v in staging.items()}
-    # a frame and its margin of ceil(1/s) + 1 = 2 rows and columns, once
-    assert tm.THUMB_PACK_BYTES.value() - wrote == \
-        2 * 182 * 322 * 3 + 202 * 302 * 3 + 202 * 302 * 1
-    again = tj.resize_batch(frames[::-1], _targets(frames[::-1]))
-    assert _staging_counts() == {"kept": mapped["kept"] + 2,
-                                 "mapped": mapped["mapped"]}
-    assert {k: v.ctypes.data for k, v in staging.items()} == canvases
-    for a, b in zip(first, again[::-1]):
-        assert np.array_equal(a, b)
-    # a wider pad than the kept canvas has maps a new one, and keeps that
-    tj.resize_batch(frames[:2] * 3, _targets(frames[:2] * 3))
-    assert _staging_counts() == {"kept": mapped["kept"] + 2,
-                                 "mapped": mapped["mapped"] + 1}
-    assert staging[(512, 512, 3)].shape[0] == 8
+def _arena_canvas(bh: int, bw: int, planes: int, j: int = 0) -> np.ndarray:
+    """Canvas j of a (bh, bw, planes) call as the arena holds it."""
+    n = bh * bw * planes
+    return tj._arena[j * n:(j + 1) * n].reshape(bh, bw, planes)
 
 
-def test_kept_canvases_are_bounded_least_recently_used_first(
-        staging, monkeypatch):
-    # room for the colour canvas and the small one, not for the alpha too
-    monkeypatch.setattr(tj, "_STAGING_MAX_BYTES",
-                        2 * 256 * 256 * 3 + 128 * 128 * 3)
-    for planes in (3, 1, 3):  # colour, alpha, colour again: alpha is oldest
-        with tj._staging_canvas(2, 256, 256, planes) as canvas:
-            assert canvas.shape == (2, 256, 256, planes)
-    with tj._staging_canvas(1, 128, 128, 3):
+def test_a_call_of_another_bucket_and_planes_is_lent_the_same_bytes(arena):
+    bright = [np.full((700, 1000, 3), 255, np.uint8) for _ in range(4)]
+    start = _staging_counts()
+    tj.resize_batch(bright, _targets(bright))
+    assert _staging_counts() == {"kept": start["kept"],
+                                 "mapped": start["mapped"] + 1}
+    kept = tj._arena
+    assert kept.size == 4 * 1024 * 1024 * 3
+    # another bucket, a colour and an alpha call: both lent the arena
+    small = [_photo(200, 300)]
+    tj.resize_batch(small, _targets(small))
+    assert _staging_counts() == {"kept": start["kept"] + 2,
+                                 "mapped": start["mapped"] + 1}
+    assert tj._arena is kept
+    with tj._staging_canvas(3, 256, 512, 1) as canvas:
+        assert canvas.shape == (3, 256, 512, 1)
+        assert np.shares_memory(canvas, kept)
+        assert tj._arena is None  # lent
+    assert tj._arena is kept
+    assert _staging_counts()["kept"] == start["kept"] + 3
+
+
+def test_second_call_through_the_arena_returns_nothing_of_the_first(arena):
+    bright = [np.full((700, 1000, 3), 255, np.uint8) for _ in range(4)]
+    tj.resize_batch(bright, _targets(bright))
+    kept = tj._arena
+    assert (_arena_canvas(1024, 1024, 3, 3)[:700, :1000] == 255).all()
+    kept[...] = 255  # the most a call could have left there
+    dark = [np.zeros((300, 400, 3), np.uint8)]  # (512, 512): a smaller bucket
+    out = tj.resize_batch(dark, _targets(dark))[0]
+    assert out.shape == (*_targets(dark)[0], 3)
+    assert (out == 0).all()
+    # the same arena, lent again, and never the result
+    assert tj._arena is kept
+    assert not np.shares_memory(out, kept)
+
+
+def test_the_arena_grows_to_the_largest_call_and_no_further(
+        arena, monkeypatch):
+    start = _staging_counts()
+    with tj._staging_canvas(2, 256, 256, 3):
         pass
-    assert list(staging) == [(256, 256, 3), (128, 128, 3)]
+    first = tj._arena
+    assert first.size == 2 * 256 * 256 * 3
+    with tj._staging_canvas(4, 512, 512, 1) as canvas:  # larger: mapped
+        assert not np.shares_memory(canvas, first)
+    grown = tj._arena
+    assert grown.size == 4 * 512 * 512
+    with tj._staging_canvas(1, 256, 1024, 3) as canvas:  # fits: kept
+        assert np.shares_memory(canvas, grown)
+    assert tj._arena is grown
+    assert _staging_counts() == {"kept": start["kept"] + 1,
+                                 "mapped": start["mapped"] + 2}
+    # a call over the bound maps its own canvas and leaves the arena
+    monkeypatch.setattr(tj, "CALL_CANVAS_BYTES", 6 * 512 * 512)
+    with tj._staging_canvas(8, 512, 512, 1) as canvas:
+        assert canvas.shape == (8, 512, 512, 1)
+        assert not np.shares_memory(canvas, grown)
+        assert tj._arena is grown
+    assert tj._arena is grown
+    assert _staging_counts() == {"kept": start["kept"] + 1,
+                                 "mapped": start["mapped"] + 3}
+
+
+def test_a_failed_call_does_not_put_its_buffer_back(arena):
     with pytest.raises(RuntimeError):
         with tj._staging_canvas(2, 256, 256, 3):
-            raise RuntimeError("the call failed: its canvas is not kept")
-    assert list(staging) == [(128, 128, 3)]
+            raise RuntimeError("the call failed: a transfer may still read")
+    assert tj._arena is None
+    with tj._staging_canvas(2, 256, 256, 3):
+        pass
+    kept = tj._arena
+    with pytest.raises(RuntimeError):
+        with tj._staging_canvas(1, 256, 256, 3) as canvas:
+            assert np.shares_memory(canvas, kept)
+            raise RuntimeError("the call failed with the arena lent")
+    assert tj._arena is None
 
 
-def test_two_threads_resizing_at_once_get_a_canvas_each(staging):
+def test_two_calls_at_once_one_is_lent_the_arena_one_maps(arena):
+    with tj._staging_canvas(2, 256, 256, 3):
+        pass
+    kept = tj._arena
+    start = _staging_counts()
+    both = threading.Barrier(2, timeout=30)
+    canvases: list[np.ndarray] = []
+
+    def call(planes: int) -> None:
+        with tj._staging_canvas(2, 256, 256, planes) as canvas:
+            canvases.append(canvas)
+            both.wait()  # the two canvases are lent at the same time
+
+    threads = [threading.Thread(target=call, args=(p,)) for p in (3, 1)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    assert _staging_counts() == {"kept": start["kept"] + 1,
+                                 "mapped": start["mapped"] + 1}
+    a, b = canvases
+    assert not np.shares_memory(a, b)
+    assert sum(np.shares_memory(c, kept) for c in canvases) == 1
+    # of the two taken back the larger is kept
+    assert tj._arena.size == kept.size
+
+
+def test_two_threads_resizing_at_once_get_a_canvas_each(arena):
     images = {0: [np.full((300, 400, 3), 40, np.uint8)] * 3,
               1: [np.full((280, 500, 3), 200, np.uint8)] * 2}
     failures: list[str] = []
@@ -247,7 +301,42 @@ def test_two_threads_resizing_at_once_get_a_canvas_each(staging):
     finally:
         sys.setswitchinterval(interval)
     assert not failures
-    assert list(staging) == [(512, 512, 3)]
+    # the largest call's canvases, (512, 512) × 3 at pad 4
+    assert tj._arena.size == 4 * 512 * 512 * 3
+
+
+@pytest.mark.parametrize("seed", [39, 3900000011, 2147484731])
+def test_results_do_not_depend_on_what_the_arena_held(arena, seed):
+    """A mixed batch (RGB and RGBA, portrait and landscape, three
+    buckets) gives the same bytes from an empty arena, one poisoned
+    with 255 and one a larger call of another shape left."""
+    rng = np.random.default_rng(seed)
+    images = []
+    # (256, 256), (512, 512), (512, 1024): a landscape and a portrait
+    # each, the last transposed in; the channels fixed, so the programs
+    for side, lo, channels in ((256, 100, (3, 4)), (512, 260, (4, 3)),
+                               (1024, 520, (3, 4))):
+        for k in range(2):
+            a = int(rng.integers(lo, side + 1))
+            b = int(rng.integers(lo // 2 if side == 1024 else lo,
+                                 min(side, 512) + 1))
+            h, w = (b, a) if k == 0 else (a, b)
+            images.append(_photo(h, w, channels[k]))
+    images.append(_photo(330, 500, 4))
+    targets = [(max(1, round(th * s)), max(1, round(tw * s)))
+               for (th, tw), s in zip(_targets(images),
+                                      rng.uniform(0.3, 1.0, len(images)))]
+    want = tj.resize_batch(images, targets)
+    tj._arena = np.full(4 << 20, 255, np.uint8)
+    poisoned = tj.resize_batch(images, targets)
+    tj._arena = None
+    larger = [_noise((700, 1000, 4))] * 4  # (1024, 1024) and its alpha
+    tj.resize_batch(larger, _targets(larger))
+    assert tj._arena.size == 4 * 1024 * 1024 * 3
+    left = tj.resize_batch(images, targets)
+    for a, b, c, t in zip(want, poisoned, left, targets):
+        assert a.shape == (*t, a.shape[2])
+        assert np.array_equal(a, b) and np.array_equal(a, c)
 
 
 # (e) what `pack` writes: the frame and the filter's margin are enough
@@ -277,7 +366,7 @@ FILLS = {"ff": lambda shape: np.full(shape, 0xFF, np.uint8),
 ], ids=["square_landscape", "square_portrait", "half_landscape",
         "half_portrait"])
 def test_nothing_unwritten_in_a_canvas_reaches_a_result(
-        staging, bucket, shapes, channels, scale):
+        arena, monkeypatch, bucket, shapes, channels, scale):
     """Whatever the canvas held before the call (0xFF, 0x00, noise), the
     result is byte for byte what a canvas filled with replicated edges
     to its last byte gives: the margin is all the filter reads."""
@@ -289,22 +378,27 @@ def test_nothing_unwritten_in_a_canvas_reaches_a_result(
         assert tj.bucket_for(h, w) == bucket
     results = {}
     for name, fill in FILLS.items():
-        staging.clear()
-        for planes in (3, 1):
-            staging[(bh, bw, planes)] = fill((2, bh, bw, planes))
+        tj._arena = fill((2 * bh * bw * 3,))
         results[name] = tj.resize_batch(images, targets)
-    # the parent's canvas: every byte an edge of the image it holds
-    staging.clear()
+    # the canvas before ISSUE 33: every byte an edge of the image it holds
+    edges = {}
     as_packed = [img if (h <= bh and th <= tj.OUT_CANVAS_HW[0])
                  else np.transpose(img, (1, 0, 2))
                  for img, (h, _w), (th, _tw) in zip(images, shapes, targets)]
     for planes, chans in ((3, slice(0, 3)), (1, slice(3, 4))):
         if planes == 1 and channels == 3:
             continue
-        staging[(bh, bw, planes)] = np.stack([
+        edges[planes] = np.stack([
             _fill_edges(*a.shape[:2], bh, bw, a[..., chans])
             for a in as_packed])
-    results["edges"] = tj.resize_batch(images, targets)
+
+    @contextlib.contextmanager
+    def edge_canvas(bpad, _bh, _bw, planes):
+        yield edges[planes][:bpad]
+
+    with monkeypatch.context() as m:
+        m.setattr(tj, "_staging_canvas", edge_canvas)
+        results["edges"] = tj.resize_batch(images, targets)
     for name, outs in results.items():
         for out, want, t in zip(outs, results["edges"], targets):
             assert out.shape == (*t, channels)
@@ -332,15 +426,15 @@ def test_a_portrait_as_it_stands_is_the_flipped_path_within_one(
     assert (gap != 0).mean() < 0.01, int((gap != 0).sum())
 
 
-def test_a_portrait_transposes_in_only_where_it_has_to(staging):
+def test_a_portrait_transposes_in_only_where_it_has_to(arena):
     """Which canvas rows a frame filled says which way it went in."""
     clip = _photo(240, 135, 3)      # fits (256, 256) and its target fits
     photo = _photo(700, 600, 3)     # fits (1024, 1024); target 553 high
     tj.resize_batch([clip], [(128, 72)])
-    assert np.array_equal(staging[(256, 256, 3)][0, :240, :135], clip)
+    assert np.array_equal(_arena_canvas(256, 256, 3)[:240, :135], clip)
     out = tj.resize_batch([photo], _targets([photo]))[0]
     assert _targets([photo])[0][0] > tj.OUT_CANVAS_HW[0]
-    assert np.array_equal(staging[(1024, 1024, 3)][0, :600, :700],
+    assert np.array_equal(_arena_canvas(1024, 1024, 3)[:600, :700],
                           np.transpose(photo, (1, 0, 2)))
     assert out.shape == (*_targets([photo])[0], 3)
 
