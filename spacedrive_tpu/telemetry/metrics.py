@@ -172,6 +172,13 @@ THUMB_STAGING = REGISTRY.counter(
     "lent, a call over the bound CALL_CANVAS_BYTES (mapped)",
     labels=("result",),  # kept | mapped
 )
+THUMB_PACK_FRAMES = REGISTRY.counter(
+    "sd_thumbnail_pack_frames_total",
+    "frames `pack` wrote into staging canvases, by the copy each took: one "
+    "copy of contiguous rows, a blocked transpose of a portrait turned in "
+    "(transposed), or plane by plane against the grain (planes)",
+    labels=("copy",),  # rows | transposed | planes
+)
 THUMB_DEVICE_BYTES = REGISTRY.counter(
     "sd_thumbnail_device_bytes_total",
     "nbytes of the canvases put on the device and of the output canvases "

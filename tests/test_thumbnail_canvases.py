@@ -439,6 +439,115 @@ def test_a_portrait_transposes_in_only_where_it_has_to(arena):
     assert out.shape == (*_targets([photo])[0], 3)
 
 
+# (f) how a frame goes in: a portrait turned in through a blocked transpose
+
+
+def _pack_counts() -> dict:
+    return {c: tm.THUMB_PACK_FRAMES.value(copy=c)
+            for c in ("rows", "transposed", "planes")}
+
+
+def _ticked(start: dict, **ticks) -> dict:
+    return {c: n + ticks.get(c, 0) for c, n in start.items()}
+
+
+def _turned(h: int, w: int, planes: int, seed: int = 42):
+    """A decoded portrait of h × w as `resize_batch` places it: the
+    transposed view of its contiguous frame, and its scale."""
+    rng = np.random.default_rng(seed)
+    frame = np.frombuffer(rng.bytes(h * w * planes), np.uint8).reshape(
+        h, w, planes)
+    tw, th = tj.scale_dimensions(w, h)
+    return np.transpose(frame, (1, 0, 2)), (tw / w, th / h)
+
+
+def _plane_loop(monkeypatch, canvas, img, scale) -> None:
+    """`_pack_one` as the program before the blocked transpose packed."""
+    with monkeypatch.context() as m:
+        m.setattr(tj, "_transpose_in", lambda _dst, _img: False)
+        tj._pack_one(canvas, img, scale)
+
+
+@pytest.mark.parametrize("planes", [1, 3, 4])
+@pytest.mark.parametrize("h,w,bucket", [
+    (4032, 3024, (4096, 4096)),   # 12 MP, `photolib.heic` and `.hires`
+    (4031, 3021, (4096, 4096)),
+    (5712, 4284, (4608, 6144)),   # 24 MP, `photolib.hires`
+    (5709, 4283, (4608, 6144)),
+    (8064, 6048, (6144, 8192)),   # 48 MP, `photolib.hires`
+    (8063, 6045, (6144, 8192)),
+    (2532, 1170, (2048, 4096)),   # a screenshot, `photolib.cold`
+    (2531, 1169, (2048, 4096)),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_a_turned_frame_transposes_in_byte_equal_to_the_plane_loop(
+        arena, monkeypatch, h, w, bucket, planes):
+    """Frame and margin, and every byte of the canvas round them that
+    an earlier call left in the arena, are what the plane loop writes."""
+    assert tj.bucket_for(h, w) == bucket
+    img, scale = _turned(h, w, planes)
+    bh, bw = bucket
+    rng = np.random.default_rng(h * w)
+    tj._arena = np.frombuffer(bytearray(rng.bytes(bh * bw * planes)),
+                              np.uint8)
+    want = tj._arena.reshape(bh, bw, planes).copy()
+    _plane_loop(monkeypatch, want, img, scale)
+    start = _pack_counts()
+    with tj._staging_canvas(1, bh, bw, planes) as canvas:
+        tj._pack_one(canvas[0], img, scale)
+        assert np.array_equal(canvas[0], want)
+    assert _pack_counts() == _ticked(start, transposed=1)
+
+
+class _CopyingCv2:
+    """cv2 whose transpose hands back a new array and leaves `dst`."""
+
+    error = RuntimeError
+
+    @staticmethod
+    def transpose(src, dst=None):
+        return np.ascontiguousarray(np.transpose(src, (1, 0, 2)))
+
+
+@pytest.mark.parametrize("fallback", ["no_cv2", "dst_not_contiguous",
+                                      "dst_not_written"])
+def test_a_transpose_that_cannot_write_the_canvas_takes_the_plane_loop(
+        monkeypatch, fallback):
+    img, scale = _turned(700, 300, 3)
+    bh, bw = tj.bucket_for(700, 300)
+    # a canvas whose pixels lie 6 bytes apart: no cv::Mat can be it
+    wide = np.full((bh, bw, 6), 0x5A, np.uint8)
+    canvas = (wide[..., ::2] if fallback == "dst_not_contiguous"
+              else np.full((bh, bw, 3), 0x5A, np.uint8))
+    want = np.full((bh, bw, 3), 0x5A, np.uint8)
+    _plane_loop(monkeypatch, want, img, scale)
+    if fallback == "no_cv2":
+        monkeypatch.setitem(sys.modules, "cv2", None)  # import fails
+    elif fallback == "dst_not_written":
+        monkeypatch.setitem(sys.modules, "cv2", _CopyingCv2)
+    start = _pack_counts()
+    tj._pack_one(canvas, img, scale)
+    assert _pack_counts() == _ticked(start, planes=1)
+    assert np.array_equal(canvas, want)
+
+
+def test_every_frame_packed_ticks_once_by_the_copy_it_took(arena):
+    images = [np.ascontiguousarray(a) for a in (
+        _photo(400, 900, 3),   # landscape: one copy of its rows
+        _photo(240, 135, 3),   # a clip's portrait, as it stands: rows
+        _photo(900, 600, 3),   # a photo's portrait, turned in
+        _photo(700, 600, 4),   # RGBA, turned in: colour and alpha planes
+    )]
+    # turned in, the three planes of a frame whose pixels are 4 bytes
+    images.append(_photo(500, 300, 3))
+    targets = _targets(images)
+    targets[1] = (128, 72)
+    start = _pack_counts()
+    outs = tj.resize_batch(images, targets)
+    assert _pack_counts() == _ticked(start, rows=2, transposed=1, planes=3)
+    for out, img, t in zip(outs, images, targets):
+        assert out.shape == (*t, img.shape[2])
+
+
 # the warm-up contract (benchmark/warm.py:75-90, 131-135): warm.py runs ONE
 # call per (bucket, pad), a 4-channel array of zeros, and the benchmark holds
 # a timed pass to zero compile requests. So that call has to reach every
