@@ -15,8 +15,7 @@ cas_ids, PIL for thumbnails, NumPy for embeddings. It asserts that the
 pass USED the chip — Mosaic-compiled Pallas kernel, degradation ladder
 at level 0, zero fallback counters, every job COMPLETED — and, on a
 multi-chip host, that every chip hashed and resized. A second pass over
-a fresh data dir, with SD_PROCS=2 workers spawned by the process that
-owns the chip, must compile nothing.
+a fresh data dir, in the same process, must compile nothing.
 
 Stdout carries two lines, both JSON objects: first the summary (counts,
 compile counts, per-phase seconds, ending `"claim": null`), then, last,
@@ -474,7 +473,6 @@ async def index_pass(data_dir: str, corpus: str, after=None) -> dict:
     await node.start()
     try:
         summary = await cli.index_location(node, corpus, "smoke", "tpu")
-        summary["procpool_workers"] = node.procpool.worker_count()
         if after is not None:
             await after(node, summary)
         return summary
@@ -946,16 +944,13 @@ def main(argv: list[str] | None = None) -> int:
         # pass 2: what a second `sdx index` process would see with a
         # warm compile cache — fresh data dir, fresh autotuner state
         # (a new process inherits neither), every program already
-        # compiled. SD_PROCS=2: the process that owns the chip spawns
-        # CPU-pinned workers.
+        # compiled.
         from spacedrive_tpu.parallel import autotune
 
         autotune.reset()
-        os.environ["SD_PROCS"] = "2"
         compiles.phase = memory.phase = "pass_warm"
         warm = asyncio.run(index_pass(
             os.path.join(args.work_dir, "node-warm"), corpus))
-        os.environ.pop("SD_PROCS")
         phases["pass_warm_s"] = warm["seconds"]
         phases["pass_warm_job_s"] = warm["job_seconds"]
         log(f"warm pass: {warm['files']} files in {warm['seconds']} s "
@@ -964,8 +959,6 @@ def main(argv: list[str] | None = None) -> int:
         warm_compiles = compile_report.get("pass_warm", {}).get("requests", 0)
         chk.ok(warm_compiles == 0,
                f"the warm pass requested {warm_compiles} compiles, not 0")
-        chk.ok(warm["procpool_workers"] == 2,
-               f"warm pass had {warm['procpool_workers']} pool workers, not 2")
         for key in ("files", "objects", "jobs", "jobs_failed", "ladder_level",
                     "cas_backend_fallbacks", "thumbnail_cpu_fallbacks",
                     "thumbnail_errors"):

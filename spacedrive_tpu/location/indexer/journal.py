@@ -35,7 +35,6 @@ Verdict counters: `sd_index_journal_ops_total{result=...}` plus
 
 from __future__ import annotations
 
-import collections
 import itertools
 import logging
 import os
@@ -134,11 +133,7 @@ class JournalEntry:
 
 
 def entry_of_row(row: dict) -> JournalEntry | None:
-    """Strictly validated row → entry decode (None = corrupt/foreign).
-    Module-level (not a method) so the procpool worker's
-    ``journal.match`` stage runs the EXACT code path consult_many runs
-    inline — the verdict parity between pooled and single-process
-    consults is by construction, not by reimplementation."""
+    """Strictly validated row → entry decode (None = corrupt/foreign)."""
     payload = _decode_payload(row.get("payload"))
     if payload is None:
         return None
@@ -201,11 +196,11 @@ def judge_row(
 ) -> tuple[str, "JournalEntry | None"]:
     """(verdict, entry) of one fetched `index_journal` row against a
     file's identity: the ONE place a row is judged (`lookup`, a
-    window's `judge`, `consult_many` and the procpool worker's
-    ``journal.match`` stage all come here). No row is a `miss`; a row
-    that does not decode is `bypassed` (the owner drops it); `hit` only
-    when all four identity fields match and the entry is not stale; an
-    `invalidated` entry is returned too, for its chunk cache."""
+    window's `judge` and `consult_many` all come here). No row is a
+    `miss`; a row that does not decode is `bypassed` (the owner drops
+    it); `hit` only when all four identity fields match and the entry is
+    not stale; an `invalidated` entry is returned too, for its chunk
+    cache."""
     if row is None:
         return MISS, None
     entry = entry_of_row(row)
@@ -407,12 +402,6 @@ class IndexJournal:
         if not items:
             return {}
         rows_by_key = self.fetch_rows(location_id, [k for k, _i in items])
-        if rows_by_key is not None:
-            pooled = self._consult_pool(
-                location_id, items, rows_by_key, count_invalidated, count,
-            )
-            if pooled is not None:
-                return pooled
         return {
             key: self.judge(location_id, key, rows_by_key, identity,
                             count_invalidated, count)
@@ -421,122 +410,6 @@ class IndexJournal:
 
     def _entry_of(self, row: dict) -> JournalEntry | None:
         return entry_of_row(row)
-
-    #: smallest consult batch worth a pool round-trip — below this the
-    #: msgpack+frame tax exceeds the decode work being escaped
-    POOL_MIN_ITEMS = 16
-
-    def _consult_pool(
-        self,
-        location_id: int,
-        items: list[tuple[Key, Identity | None]],
-        rows_by_key: dict[Key, dict],
-        count_invalidated: bool,
-        count: bool,
-    ) -> dict[Key, tuple[str, JournalEntry | None]] | None:
-        """consult_many's match half on the process pool: the fetched
-        rows ship out as plain dicts, the per-row payload decode +
-        strict validation + identity compare (the GIL-held middle of a
-        warm consult) runs in a worker, and verdict COUNTING stays here
-        — one writer per process. Returns None (caller runs the inline
-        loop, rows already fetched) when the pool is off, the batch is
-        too small, or anything about the round-trip fails. The gate
-        counts FETCHED ROWS, not items: a cold pass (no journal rows)
-        has no payloads to decode, and shipping a batch of misses
-        would be pure IPC tax."""
-        if len(rows_by_key) < self.POOL_MIN_ITEMS:
-            return None
-        from ...parallel import procpool as _procpool
-
-        pool = _procpool.get()
-        if pool is None:
-            return None
-        wire_items: list[list] = []
-        wire_rows: list[dict | None] = []
-        for key, ident in items:
-            wire_items.append([
-                list(key),
-                [ident.inode, ident.dev, ident.mtime_ns, ident.size]
-                if ident is not None else None,
-            ])
-            wire_rows.append(rows_by_key.get(key))
-        try:
-            reply = pool.request(
-                "journal.match",
-                {"items": wire_items, "rows": wire_rows},
-                rows=len(items),
-            )
-            verdicts = reply["verdicts"]
-            if len(verdicts) != len(items):
-                raise ValueError("verdict count mismatch")
-            out: dict[Key, tuple[str, JournalEntry | None]] = {}
-            corrupt_keys: list[Key] = []
-            tallies: list[str] = []
-            for (key, _ident), (verdict, plain, corrupt) in zip(
-                items, verdicts,
-            ):
-                if corrupt:
-                    # corrupt row: dropped (below) so the next pass
-                    # starts clean — the DB write stays owner-side
-                    corrupt_keys.append(key)
-                    tallies.append("bypassed")
-                    out[key] = (BYPASSED, None)
-                    continue
-                entry = None
-                if plain is not None:
-                    chunks = None
-                    if plain.get("chunks") is not None:
-                        # worker-validated (entry_of_row) — direct
-                        # construction skips a second O(chunks) pass
-                        p = plain["chunks"]
-                        chunks = ChunkCache(
-                            p["len"], list(p["dig"]), p.get("cvs"))
-                    entry = JournalEntry(
-                        identity=Identity(*plain["identity"])
-                        if plain.get("identity") is not None else None,
-                        stale=bool(plain["stale"]),
-                        cas_id=plain.get("cas_id"),
-                        thumb=bool(plain.get("thumb")),
-                        media_digest=plain.get("media"),
-                        phash=plain.get("phash"),
-                        embed=bool(plain.get("embed")),
-                        chunks=chunks,
-                    )
-                if verdict == HIT:
-                    tallies.append("hits")
-                elif verdict == MISS:
-                    tallies.append("misses")
-                elif verdict == INVALIDATED:
-                    tallies.append(
-                        "invalidated" if count_invalidated else "")
-                else:
-                    raise ValueError(f"foreign verdict {verdict!r}")
-                out[key] = (verdict, entry)
-        except (_procpool.ProcPoolError, KeyError, TypeError, ValueError):
-            # anything torn about the round-trip: the inline loop is
-            # the fallback and the rows are already in hand. Nothing
-            # was counted or deleted yet, so the fallback cannot
-            # double-count a verdict.
-            return None
-        for key in corrupt_keys:
-            self._delete_key(location_id, key)
-        if count:
-            agg = collections.Counter(t for t in tallies if t)
-            if agg["hits"]:
-                _tm.INDEX_JOURNAL_OPS.inc(agg["hits"], result="hit")
-                self._loc_count(location_id, "hits", agg["hits"])
-            if agg["misses"]:
-                _tm.INDEX_JOURNAL_OPS.inc(agg["misses"], result="miss")
-                self._loc_count(location_id, "misses", agg["misses"])
-            if agg["invalidated"]:
-                _tm.INDEX_JOURNAL_OPS.inc(
-                    agg["invalidated"], result="invalidated")
-                self._loc_count(
-                    location_id, "invalidated", agg["invalidated"])
-            if agg["bypassed"]:
-                _tm.INDEX_JOURNAL_OPS.inc(agg["bypassed"], result="bypassed")
-                self._loc_count(location_id, "bypassed", agg["bypassed"])
-        return out
 
     # ---- record --------------------------------------------------------
 

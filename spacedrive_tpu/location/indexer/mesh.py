@@ -119,32 +119,11 @@ def make_session(library: Any, location: dict, *,
 # --- shard execution (any node) -------------------------------------------
 
 
-def _pool_for_backend(backend: str) -> Any:
-    """The running process pool when this shard's hash leg is host-side
-    (the pool never owns the accelerator — device backends keep the
-    owner's batched dispatch), else None."""
-    if backend in ("tpu", "device"):
-        return None
-    if backend == "auto" and cas._device_available():
-        return None
-    from ...parallel import procpool as _procpool
-
-    return _procpool.get()
-
-
 def _execute_shard_sync(library: Any, location: dict, entries: list[dict],
                         backend: str) -> list[dict]:
     """Worker-thread half of shard execution: journal consult → read →
     batch hash → link + vouch. Returns wire-shippable per-file results
-    ``{pub_id, cas_id, ext, identity, chunks}``.
-
-    With the multi-process plane live (``SD_PROCS`` > 0, CPU hash
-    backend), the per-entry stat/read/chunk-digest/hash middle ships to
-    pool workers in PipelinePolicy-sized quanta instead of running
-    under this thread's GIL; journal consults, the sync-write commit,
-    and the vouches stay on the owning process. Every pool failure
-    degrades that batch to the identical inline stage function — the
-    pool can slow a shard, never wrong it."""
+    ``{pub_id, cas_id, ext, identity, chunks}``."""
     journal = _journal.IndexJournal(library.db)
     loc_id = location["id"]
     loc_path = location["path"]
@@ -152,10 +131,6 @@ def _execute_shard_sync(library: Any, location: dict, entries: list[dict],
     messages: list[bytes] = []
     msg_results: list[dict] = []  # result dicts awaiting a cas
     to_record: list[tuple] = []   # journal vouches, written post-commit
-    pool = _pool_for_backend(backend)
-    # (plain entry, result, key, prior entry) per pool-shipped file
-    pool_jobs: list[tuple[dict, dict, tuple, Any]] = []
-    pool_bytes = 0  # expected message bytes riding the pool (span size)
     # stat pass first, then ONE batched journal consult for the whole
     # shard — the per-file SELECT was the GIL-bound floor ROADMAP PR 9
     # called out (128-entry shard = 128 round-trips into SQLite)
@@ -200,14 +175,6 @@ def _execute_shard_sync(library: Any, location: dict, entries: list[dict],
             journal.bytes_saved(cas.message_len(ident.size),
                                 location_id=loc_id)
             continue
-        if pool is not None:
-            pool_jobs.append((
-                {"pub_id": e["pub_id"], "mat": e["mat"],
-                 "name": e["name"], "ext": e["ext"]},
-                result, key, entry,
-            ))
-            pool_bytes += cas.message_len(ident.size)
-            continue
         try:
             msg = cas.read_message(full, ident.size)
         except OSError as exc:
@@ -231,8 +198,6 @@ def _execute_shard_sync(library: Any, location: dict, entries: list[dict],
         _tm.INDEX_BYTES_HASHED.inc(sum(len(m) for m in messages))
         for result, cas_hex in zip(msg_results, cas_ids):
             result["cas_id"] = cas_hex
-    if pool_jobs:
-        _pool_hash(pool, loc_path, pool_jobs, to_record, pool_bytes)
     _tm.IDENTIFIER_FILES.inc(len(entries))
 
     # link + sync write FIRST, then the journal vouch (truth discipline:
@@ -253,73 +218,6 @@ def _execute_shard_sync(library: Any, location: dict, entries: list[dict],
     _tm.IDENTIFIER_STAGE_SECONDS.observe(
         time.perf_counter() - t_db, stage="db")
     return results
-
-
-def _pool_hash(pool: Any, loc_path: str, jobs: list[tuple],
-               to_record: list[tuple], nbytes: int = 0) -> None:
-    """Fan the shard's hash-needing entries across the process pool in
-    PipelinePolicy-sized quanta, filling each entry's result dict and
-    vouch record from the worker's plain reply. A batch whose pool trip
-    fails (worker error past the retry budget, pool mid-shutdown) runs
-    the SAME stage function inline — output is identical by
-    construction, only the parallelism is lost."""
-    from ...parallel import autotune as _autotune
-    from ...parallel import procpool as _procpool
-    from ...parallel import procworker as _procworker
-
-    quanta = max(1, _autotune.policy("identify").procpool_batch_rows())
-    batches = [jobs[i:i + quanta] for i in range(0, len(jobs), quanta)]
-    futures = []
-    for batch in batches:
-        plain_entries = [plain for plain, _res, _key, _carry in batch]
-        payload = {"loc_path": loc_path, "entries": plain_entries}
-        try:
-            futures.append(pool.submit(
-                "identify.hash_entries", payload, rows=len(batch)))
-        except _procpool.ProcPoolError:
-            futures.append(None)  # degrade this batch inline below
-    t_hash = time.perf_counter()
-    with span("procpool.hash_entries", nbytes=nbytes):
-        for batch, fut in zip(batches, futures):
-            out = None
-            if fut is not None:
-                try:
-                    out = fut.result(_procpool.REQUEST_TIMEOUT_S)["results"]
-                except Exception as exc:  # noqa: BLE001 - degrade inline
-                    logger.warning(
-                        "procpool hash batch failed (%s); inline fallback",
-                        exc)
-            if out is None:
-                out = _procworker._stage_hash_entries({
-                    "loc_path": loc_path,
-                    "entries": [p for p, _r, _k, _c in batch],
-                })["results"]
-            for (_plain, result, key, carry), rec in zip(batch, out):
-                ident_raw = rec.get("identity")
-                result["identity"] = ident_raw
-                result["cas_id"] = rec.get("cas_id")
-                result["chunks"] = rec.get("chunks")
-                if ident_raw is None or rec.get("cas_id") is None:
-                    continue  # unreadable/vanished: no vouch
-                # the worker already built + validated this cache
-                # (build_chunk_cache output shipped verbatim) — direct
-                # construction skips a second O(chunks) validation
-                cache = None
-                if rec.get("chunks") is not None:
-                    p = rec["chunks"]
-                    cache = cas.ChunkCache(
-                        p["len"], list(p["dig"]), p.get("cvs"))
-                to_record.append((
-                    key, _journal.Identity(*(int(x) for x in ident_raw)),
-                    rec["cas_id"], cache, carry,
-                ))
-    # the hash leg's WALL, observed once owner-side (the worker stage
-    # deliberately does not observe this series: concurrent workers'
-    # per-batch times would merge to CPU-seconds and make
-    # autotune.observed_files_per_s — the lease-sizing throughput
-    # self-report — read a pool-accelerated node as unaccelerated)
-    _tm.IDENTIFIER_STAGE_SECONDS.observe(
-        time.perf_counter() - t_hash, stage="hash")
 
 
 async def resolve_location(library: Any, location_pub: str | None) -> dict:
@@ -536,35 +434,14 @@ async def _drive_session(
     by_stage: dict[str, int] = {}
     for sh in session.shards.values():
         by_stage[sh.stage] = by_stage.get(sh.stage, 0) + 1
-    # with the multi-process plane live, the coordinator keeps several
-    # shards in flight at once: one shard's owner-side SQL commit
-    # overlaps another's worker-side hashing. SD_PROCS=0 keeps today's
-    # strictly sequential self-steal (the golden path).
-    from ...parallel import procpool as _procpool
-
-    width = _procpool.procs() if _procpool.get() is not None else 1
     if plane is None:
         # no P2P runtime: run every shard inline (still shard-shaped so
         # the journal/link/vouch path is identical)
-        if width > 1:
-            sem = asyncio.Semaphore(width)
-
-            async def _one_inline(shard: Any) -> None:
-                async with sem:
-                    await execute_stage_shard(
-                        node, library, session.location_pub,
-                        shard.stage, shard.entries, backend,
-                    )
-
-            await asyncio.gather(*(
-                _one_inline(s) for s in session.shards.values()
-            ))
-        else:
-            for shard in session.shards.values():
-                await execute_stage_shard(
-                    node, library, session.location_pub, shard.stage,
-                    shard.entries, backend,
-                )
+        for shard in session.shards.values():
+            await execute_stage_shard(
+                node, library, session.location_pub, shard.stage,
+                shard.entries, backend,
+            )
         return {
             "session": session.id, "shards": len(session.shards),
             "files": total_files, "local_shards": len(session.shards),
@@ -587,18 +464,17 @@ async def _drive_session(
                     f"{deadline_s}s ({session.pending()} shards pending)"
                 )
             _session, grant, _lease = plane.board.claim(
-                session.id, "local", max_shards=width, local=True,
+                session.id, "local", max_shards=1, local=True,
             )
             if not grant:
                 # everything is leased out (or done): wait for completes
                 # / lease expiries; expire_leases runs inside claim()
                 await asyncio.sleep(0.05)
                 continue
-            # normally one shard (`width` with the process pool live —
-            # the execute leg keeps every pool worker fed); an injected
-            # claim race can append a duplicate-leased one — execute
-            # everything granted so a shard re-leased to "local"
-            # (exempt from expiry) can never strand
+            # normally one shard; an injected claim race can append a
+            # duplicate-leased one — execute everything granted so a
+            # shard re-leased to "local" (exempt from expiry) can never
+            # strand
             handles = [
                 (shard, node.task_system.dispatch(ShardTask(
                     node, library, session.location_pub, shard.entries,

@@ -2,7 +2,7 @@
 execution continuum (``parallel/scheduler.py``).
 
 ``location/indexer/mesh.py`` proved the shape for identify: journal-
-keyed entries, executor-side journal consult, procpool CPU leg,
+keyed entries, executor-side journal consult, CPU leg,
 idempotent results shipping back in ``complete``. This module
 generalizes it to the remaining pipeline stages — thumbnails, media
 extraction, duplicates pHash, semantic embeddings — so a WORK shard of
@@ -11,10 +11,9 @@ ANY stage executes identically on every node:
 1. **journal first**: every executor consults its OWN index journal
    before touching a byte (a warm peer's vouched thumb/phash/embed is
    served from its local store/DB — warm-peer hits count);
-2. **procpool middle**: the stage's CPU-bound leg (webp encode, gray
-   decode, embed decode) ships to the executor's local process pool in
-   PipelinePolicy-sized quanta, inline-degrading on any pool failure —
-   the pool can slow a shard, never wrong it (PR 15 contract);
+2. **CPU middle**: the stage's CPU-bound leg (webp encode, gray
+   decode, embed decode) runs on the executor, the same code a local
+   pass runs;
 3. **idempotent results**: per-file results ship back in ``complete``
    and merge through :func:`apply_stage_results` — deterministic
    content (same webp encoder, same derived embed params, same DCT
@@ -35,6 +34,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import os
 import time
 import uuid
 from typing import Any, Callable
@@ -220,7 +220,7 @@ def _read_webp(store: Any, lib_id: str, cas_id: str) -> bytes | None:
 def _execute_thumb_sync(node: Any, library: Any, location: dict,
                         entries: list[dict]) -> list[dict]:
     """The thumbnail stage leg: journal/store consult → webp generate
-    (procpool ``thumb.cpu``, inline fallback) → store write → vouch →
+    (``process.generate_one_cpu``) → store write → vouch →
     ship the webp bytes so the coordinator's store converges
     bit-identical without re-decoding anything."""
     journal = _journal.IndexJournal(library.db)
@@ -246,43 +246,18 @@ def _execute_thumb_sync(node: Any, library: Any, location: dict,
                 continue
         pending.append((e, result, key, full))
     if pending:
-        pool = _scheduler.pool_for(_scheduler.STAGE_THUMB)
-        futures: list[Any] = []
-        if pool is not None:
-            from ...parallel import procpool as _procpool
-
-            for _e, _r, _k, full in pending:
-                ext = _e["ext"]
-                try:
-                    futures.append(pool.submit(
-                        "thumb.cpu", {"path": full, "ext": ext}, rows=1))
-                except _procpool.ProcPoolError:
-                    futures.append(None)
-        else:
-            futures = [None] * len(pending)
         from ...object.media.thumbnail.process import (
             ThumbError,
             generate_one_cpu,
         )
 
         with span("continuum.thumb", nbytes=0):
-            for (e, result, key, full), fut in zip(pending, futures):
+            for e, result, key, full in pending:
                 webp = err = None
-                if fut is not None:
-                    try:
-                        from ...parallel import procpool as _procpool
-
-                        out = fut.result(_procpool.REQUEST_TIMEOUT_S)
-                        webp, err = out.get("webp"), out.get("error")
-                    except Exception as exc:  # noqa: BLE001 - degrade inline
-                        logger.debug("thumb pool leg failed (%s); inline",
-                                     exc)
-                        fut = None
-                if fut is None and err is None and webp is None:
-                    try:
-                        webp = generate_one_cpu(full, e["ext"])
-                    except (ThumbError, OSError) as exc:
-                        err = f"{type(exc).__name__}: {exc}"
+                try:
+                    webp = generate_one_cpu(full, e["ext"])
+                except (ThumbError, OSError) as exc:
+                    err = f"{type(exc).__name__}: {exc}"
                 if webp is None:
                     result["error"] = err or "undecodable"
                     continue
@@ -418,21 +393,27 @@ def _apply_media(node: Any, library: Any, location: dict,
 # --- phash ------------------------------------------------------------------
 
 
-def _inline_gray(full: str | None, thumb_path: str | None) -> Any:
-    """Inline fallback: the EXACT decode the pool stage runs
-    (procworker._stage_phash_gray is pure), so pooled and inline grays
-    are bit-identical."""
+def _gray_plane(full: str | None, thumb_path: str | None) -> Any:
+    """The 32×32 grayscale pHash plane: original-first JPEG draft
+    decode, the stored thumbnail as fallback
+    (object/duplicates.py:_decode_gray, minus the DB lookups). None
+    when neither source decodes."""
     import numpy as np
+    from PIL import Image
 
     from ...ops import phash_jax
-    from ...parallel.procworker import _stage_phash_gray
 
-    blob = _stage_phash_gray(
-        {"path": full, "thumb_path": thumb_path})["gray"]
-    if blob is None:
-        return None
-    return np.frombuffer(blob, np.float32).reshape(
-        phash_jax.DCT_SIZE, phash_jax.DCT_SIZE).copy()
+    for path, draft in ((full, True), (thumb_path, False)):
+        if not path or not os.path.exists(path):
+            continue
+        try:
+            with Image.open(path) as img:
+                if draft and img.format == "JPEG":
+                    img.draft("RGB", (phash_jax.DCT_SIZE, phash_jax.DCT_SIZE))
+                return phash_jax.to_gray32(np.asarray(img.convert("RGBA")))
+        except Exception:  # noqa: BLE001 - undecodable → next source
+            continue
+    return None
 
 
 def _commit_phash(library: Any, journal: Any, loc_id: int, key: tuple,
@@ -452,7 +433,7 @@ def _commit_phash(library: Any, journal: Any, loc_id: int, key: tuple,
 def _execute_phash_sync(node: Any, library: Any, location: dict,
                         entries: list[dict]) -> list[dict]:
     """The duplicates-pHash leg: journal-vouched reuse → gray decode
-    (procpool ``phash.gray``, inline fallback) → ONE device DCT batch →
+    (:func:`_gray_plane`) → ONE device DCT batch →
     local object.phash update + vouch → ship the 8-byte hashes."""
     import numpy as np
 
@@ -464,8 +445,6 @@ def _execute_phash_sync(node: Any, library: Any, location: dict,
     store = _store_of(node)
     results: list[dict] = []
     to_hash: list[tuple[dict, dict, tuple, Any]] = []
-    pool = _scheduler.pool_for(_scheduler.STAGE_PHASH)
-    futures: list[Any] = []
     pend: list[tuple[dict, dict, tuple, str, str | None]] = []
     for e in entries:
         verdict, jentry, full = _consult(journal, loc_id, loc_path, e)
@@ -485,32 +464,8 @@ def _execute_phash_sync(node: Any, library: Any, location: dict,
             store.path_for(lib_id, e["cas_id"]) if store is not None else None
         )
         pend.append((e, result, key, full, thumb_path))
-    if pool is not None:
-        from ...parallel import procpool as _procpool
-
-        for _e, _r, _k, full, thumb_path in pend:
-            try:
-                futures.append(pool.submit(
-                    "phash.gray", {"path": full, "thumb_path": thumb_path},
-                    rows=1))
-            except _procpool.ProcPoolError:
-                futures.append(None)
-    else:
-        futures = [None] * len(pend)
-    for (e, result, key, full, thumb_path), fut in zip(pend, futures):
-        gray = None
-        if fut is not None:
-            try:
-                from ...parallel import procpool as _procpool
-
-                blob = fut.result(_procpool.REQUEST_TIMEOUT_S)["gray"]
-                if blob is not None:
-                    gray = np.frombuffer(blob, np.float32).reshape(
-                        phash_jax.DCT_SIZE, phash_jax.DCT_SIZE).copy()
-            except Exception:  # noqa: BLE001 - degrade inline
-                gray = _inline_gray(full, thumb_path)
-        else:
-            gray = _inline_gray(full, thumb_path)
+    for e, result, key, full, thumb_path in pend:
+        gray = _gray_plane(full, thumb_path)
         if gray is not None:
             to_hash.append((e, result, key, gray))
     if to_hash:
@@ -597,11 +552,10 @@ def _commit_embed(library: Any, journal: Any, loc_id: int, key: tuple,
 def _execute_embed_sync(node: Any, library: Any, location: dict,
                         entries: list[dict]) -> list[dict]:
     """The semantic-embedding leg: journal-vouched reuse → decode
-    (procpool ``embed.decode``, inline fallback — same decode_image
-    body) → ONE padded device forward → object_embedding rows + CRDT
-    ops in one transaction → vouch → ship the vector blobs (derived
-    model params are seed-deterministic, so every executor's forward is
-    bit-identical)."""
+    (``embedder.decode_image``) → ONE padded device forward →
+    object_embedding rows + CRDT ops in one transaction → vouch → ship
+    the vector blobs (derived model params are seed-deterministic, so
+    every executor's forward is bit-identical)."""
     import numpy as np
 
     from ...models import embedder as _embedder
@@ -631,32 +585,7 @@ def _execute_embed_sync(node: Any, library: Any, location: dict,
         pend.append((e, result, key, full))
     if not pend:
         return results
-    # decode leg: pooled in one quantum-shaped batch, inline fallback
-    paths = [full for _e, _r, _k, full in pend]
-    planes: list[Any] = []
-    pool = _scheduler.pool_for(_scheduler.STAGE_EMBED)
-    if pool is not None and len(paths) > 1:
-        try:
-            from ...parallel import procpool as _procpool
-
-            reply = pool.request(
-                "embed.decode", {"paths": list(paths)}, rows=len(paths))
-            raw_planes = reply["planes"]
-            if len(raw_planes) != len(paths):
-                raise ValueError("plane count mismatch")
-            shape = (_embedder.IMAGE_SIZE, _embedder.IMAGE_SIZE, 3)
-            for raw in raw_planes:
-                if raw is None:
-                    planes.append(None)
-                    continue
-                arr = np.frombuffer(raw, np.float32)
-                if arr.size != int(np.prod(shape)):
-                    raise ValueError("plane size mismatch")
-                planes.append(arr.reshape(shape))
-        except Exception:  # noqa: BLE001 - degrade inline
-            planes = []
-    if not planes:
-        planes = [_embedder.decode_image(p) for p in paths]
+    planes = [_embedder.decode_image(full) for _e, _r, _k, full in pend]
     batch: list[tuple[dict, dict, tuple]] = []
     imgs: list[Any] = []
     for (e, result, key, _full), img in zip(pend, planes):

@@ -170,8 +170,8 @@ def decode_image(path: str) -> np.ndarray | None:
     the DCT scale `images.draft_jpeg` asks for (the thumbnailer's own
     request, so a 12 MP photo decodes at 1/4), everything else PIL
     opens as it is, then `plane_from_frame`. HEIF, SVG and PDF do not
-    come from PIL and ride `format_image`. Module-level so the procpool
-    `embed.decode` stage, the inline leg and query-by-image run the
+    come from PIL and ride `format_image`. Module-level so the media
+    job's embed leg, the mesh embed stage and query-by-image run the
     EXACT same code path, and the media job's planes from the
     thumbnailer's frames are the same bits; None = undecodable or over
     `MAXIMUM_FILE_SIZE`."""

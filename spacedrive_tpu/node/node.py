@@ -118,14 +118,6 @@ class Node:
 
         self.profiler = _sampler.SAMPLER
         self._profiler_started = False
-        # the multi-process execution plane (parallel/procpool.py):
-        # spawn-started with the node, refcounted like the sampler so
-        # two in-process nodes share one worker set. SD_PROCS=0 (the
-        # default) starts nothing — the golden single-process path.
-        from ..parallel import procpool as _procpool
-
-        self.procpool = _procpool.POOL
-        self._procpool_started = False
         # the process-wide resource-growth sampler (telemetry/resources):
         # refcounted like the profiler; SD_RESOURCES=0 starts nothing
         # (true no-op). Inventory providers that need node state
@@ -182,9 +174,6 @@ class Node:
             # refcounted hold on the process sampler
             self.profiler.register_loop_thread()
             self._profiler_started = self.profiler.start()
-            # worker processes up before any job runs, so the first shard's
-            # pool batches never pay spawn latency inside a measured pass
-            self._procpool_started = self.procpool.start()
             # resource growth surfaces: node-state inventories registered
             # before the sampler's hold so the first tick reads them all
             from ..telemetry import resources as _resources
@@ -321,9 +310,6 @@ class Node:
             if self._profiler_started:
                 self.profiler.stop()
                 self._profiler_started = False
-            if self._procpool_started:
-                self.procpool.stop()
-                self._procpool_started = False
             if self._resources_started:
                 self.resources.stop()
                 self._resources_started = False

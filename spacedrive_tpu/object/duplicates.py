@@ -105,56 +105,6 @@ class DuplicateDetectorJob(StatefulJob):
                     pass
         return None
 
-    def _pool_decode(self, ctx: JobContext, pool: Any,
-                     pending: list[tuple[int, dict]],
-                     grays: list) -> None:
-        """Ship the undecoded rows' gray-plane decode (original-first
-        JPEG draft, thumbnail fallback — the CPU-bound leg of a pHash
-        step) to the process pool; the device phash_batch and the DB
-        update stay on the owning process. Any pool failure degrades
-        that row to the inline decoder — identical output either way
-        (the worker runs the same PIL → to_gray32 path)."""
-        import numpy as np
-
-        from ..files.isolated_path import full_path_from_db_row
-        from ..parallel import procpool as _procpool
-
-        futs = []
-        for _idx, r in pending:
-            loc = self._location(ctx, r["location_id"])
-            path = (
-                full_path_from_db_row(loc["path"], r)
-                if loc is not None else None
-            )
-            node = getattr(ctx.library, "node", None)
-            thumb = (
-                node.thumbnailer.store.path_for(
-                    str(ctx.library.id), r["cas_id"])
-                if node is not None else None
-            )
-            try:
-                futs.append(pool.submit(
-                    "phash.gray", {"path": path, "thumb_path": thumb},
-                    rows=1,
-                ))
-            except _procpool.ProcPoolError:
-                futs.append(None)
-        for (idx, r), fut in zip(pending, futs):
-            gray = None
-            if fut is not None:
-                try:
-                    blob = fut.result(
-                        _procpool.REQUEST_TIMEOUT_S)["gray"]
-                    if blob is not None:
-                        gray = np.frombuffer(blob, np.float32).reshape(
-                            phash_jax.DCT_SIZE, phash_jax.DCT_SIZE
-                        ).copy()
-                except Exception:  # noqa: BLE001 - degrade inline
-                    gray = self._decode_gray(ctx, r)
-            else:
-                gray = self._decode_gray(ctx, r)
-            grays[idx] = gray
-
     async def execute_step(self, ctx: JobContext, step: dict, step_number: int) -> StepResult:
         import asyncio
 
@@ -188,23 +138,13 @@ class DuplicateDetectorJob(StatefulJob):
             return None
 
         def decode_all():
-            from ..parallel import procpool as _procpool
-
-            pool = _procpool.get()
             cached, grays = [], []
-            pending: list[tuple[int, dict]] = []  # undecoded (idx, row)
             for r in rows:
                 ph = consult(r)
                 cached.append(ph)
-                if ph is not None or pool is None:
-                    grays.append(
-                        None if ph is not None else self._decode_gray(ctx, r)
-                    )
-                else:
-                    grays.append(None)
-                    pending.append((len(grays) - 1, r))
-            if pending and pool is not None:
-                self._pool_decode(ctx, pool, pending, grays)
+                grays.append(
+                    None if ph is not None else self._decode_gray(ctx, r)
+                )
             return cached, grays
 
         cached, grays = await asyncio.to_thread(decode_all)
@@ -309,7 +249,7 @@ async def distribute_phash(
 ) -> dict[str, Any]:
     """Distribute one location's duplicates-pHash pass as stage-typed
     WORK shards (parallel/scheduler.py STAGE_PHASH): executors reuse
-    journal-vouched hashes, gray-decode through their own procpool, DCT
+    journal-vouched hashes, gray-decode the rest, DCT
     in one device batch, and ship the 8-byte hashes back — the
     local-only ``object.phash`` column converges via the shipped
     results. With no P2P runtime this IS a local pass in shard

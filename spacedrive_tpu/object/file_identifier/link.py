@@ -58,43 +58,10 @@ def object_pub_for(library_id: Any, cas_id: str) -> bytes:
 #: SQLite's default 999-variable limit
 _LINK_CHUNK = 400
 
-#: smallest result batch worth a pool round-trip for the prep leg
-_PREP_POOL_MIN = 32
-
-
 def _prep_results(lib_id: Any, results: list[dict]) \
         -> list[tuple[dict, bytes, str, bytes]]:
     """``(result, fp_pub, cas, deterministic obj_pub)`` per linkable
-    result — apply_cas_results' pure prep. Ships to the process pool
-    (stage ``link.prep``) when it is live and the batch is big enough;
-    the inline loop is both the small-batch path and the fallback, so
-    pooled and single-process prep are identical by construction."""
-    if len(results) >= _PREP_POOL_MIN:
-        from ...parallel import procpool as _procpool
-
-        pool = _procpool.get()
-        if pool is not None:
-            # None pub_id stays None: the worker's fromhex(str(None))
-            # rejects it exactly like the inline KeyError skip
-            wire = [
-                {"pub_id": res.get("pub_id"),
-                 "cas_id": res.get("cas_id"),
-                 "ext": res.get("ext")}
-                for res in results
-            ]
-            try:
-                reply = pool.request(
-                    "link.prep",
-                    {"library_id": str(lib_id), "results": wire},
-                    rows=len(results),
-                )
-                return [
-                    (results[i], bytes(fp_pub), cas, bytes(obj_pub))
-                    for i, fp_pub, cas, obj_pub in reply["usable"]
-                ]
-            except (_procpool.ProcPoolError, KeyError, TypeError,
-                    ValueError, IndexError):
-                pass  # fall through to the inline prep
+    result — apply_cas_results' pure prep."""
     usable: list[tuple[dict, bytes, str, bytes]] = []
     for res in results:
         cas = res.get("cas_id")

@@ -392,8 +392,7 @@ class MediaProcessorJob(StatefulJob):
     def _embed_files(self, ctx: JobContext, step: dict) -> StepResult:
         """One embedding chunk: the planes (the thumbnailer's frames
         gave them where this job's batch decoded the file; what is left
-        is decoded here, procpool leg when the pool is up, inline
-        otherwise — the same plane bit for bit whichever made it) →
+        is decoded here) →
         one padded device forward (ops/embed_jax, DeviceLadder
         demotion inside) → object_embedding rows + their CRDT ops in
         ONE transaction via sync.write_ops, so the vectors replicate
@@ -534,40 +533,11 @@ class MediaProcessorJob(StatefulJob):
         return planes
 
     def _decode_for_embed(self, paths: list[str]) -> list:
-        """The embedding decode leg: pooled when the multi-process
-        plane is up (stage `embed.decode` — SD022 keeps the payload
-        msgpack-plain), inline fallback otherwise; both run
-        models/embedder.decode_image (a JPEG at `images.draft_jpeg`'s
-        DCT scale, everything else as PIL opens it) so the planes are
-        bit-identical. `sd_embed_decode_total` counts in the process
-        that decodes: a pooled decode counts in its worker."""
-        import numpy as np
-
+        """The embedding decode leg: models/embedder.decode_image (a
+        JPEG at `images.draft_jpeg`'s DCT scale, everything else as PIL
+        opens it), counted in `sd_embed_decode_total`."""
         from ...models import embedder as _embedder
-        from ...parallel import procpool as _procpool
 
-        pool = _procpool.get()
-        if pool is not None and len(paths) > 1:
-            try:
-                reply = pool.request(
-                    "embed.decode", {"paths": list(paths)}, rows=len(paths),
-                )
-                planes = reply["planes"]
-                if len(planes) != len(paths):
-                    raise ValueError("plane count mismatch")
-                shape = (_embedder.IMAGE_SIZE, _embedder.IMAGE_SIZE, 3)
-                out = []
-                for raw in planes:
-                    if raw is None:
-                        out.append(None)
-                        continue
-                    arr = np.frombuffer(raw, np.float32)
-                    if arr.size != int(np.prod(shape)):
-                        raise ValueError("plane size mismatch")
-                    out.append(arr.reshape(shape))
-                return out
-            except (_procpool.ProcPoolError, KeyError, TypeError, ValueError):
-                pass  # torn round-trip → the inline leg decodes
         return [_embedder.decode_image(p) for p in paths]
 
     async def _wait_thumbnails(self, ctx: JobContext, step: dict) -> StepResult:
@@ -626,7 +596,7 @@ async def distribute_embeddings(
 ) -> dict[str, Any]:
     """Distribute one location's semantic-embedding pass as stage-typed
     WORK shards (parallel/scheduler.py STAGE_EMBED): executors decode
-    through their own procpool, run the seed-deterministic forward in
+    the planes, run the seed-deterministic forward in
     one device batch, mint the same CRDT ops a local pass would, and
     ship the vector blobs back for direct apply. No-op session when
     SD_EMBED is disabled."""

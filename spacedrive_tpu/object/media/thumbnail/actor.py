@@ -28,7 +28,6 @@ import time
 from typing import Any, Callable, Sequence
 
 from ....parallel import autotune as _autotune
-from ....parallel import procpool as _procpool
 from ....telemetry import metrics as _tm
 from ....telemetry import span
 from ....telemetry import trace as _trace
@@ -41,7 +40,6 @@ from .process import (
     decode,
     decodes_full_size,
     finish,
-    generate_one_cpu,
     host_resize_reason,
     resize_cpu,
     resize_decoded,
@@ -367,99 +365,7 @@ class Thumbnailer:
 
     async def _process_batch(self, batch: Batch) -> None:
         with _trace.use(_trace.TraceContext.from_wire(batch.trace)):
-            pool = self._pool()
-            if pool is not None:
-                await self._process_batch_pool(batch, pool)
-            else:
-                await self._process_batch_traced(batch)
-
-    def _pool(self) -> Any:
-        """The running process pool, but ONLY for the software path:
-        device actors keep the batched device resize (the pool never
-        owns the accelerator) and their rare extreme-aspect stragglers
-        stay inline. ``SD_PROCS=0`` always lands here as None — the
-        golden single-process pipeline below."""
-        if self.use_device:
-            return None
-        return _procpool.get()
-
-    async def _process_batch_pool(self, batch: Batch, pool: Any) -> None:
-        """Software-path batches ride the multi-process plane: decode →
-        CPU resize → orientation/overlay → webp encode run in pool
-        workers (``thumb.cpu`` = ``process.generate_one_cpu``, the
-        exact inline host path, so the stored webp bytes are
-        bit-identical either way). Store, events, and accounting stay
-        on this process; entries are consumed strictly in order, the
-        same crash-resume contract as the inline pipeline. Jobs ship
-        per image — decode dominates the IPC tax by orders of
-        magnitude, and variable image sizes would skew any multi-image
-        quantum — with in-flight bounded by the worker count."""
-        entries = list(batch.entries)
-        done = 0
-        chunk_rows = self._device_chunk()
-        # keep workers fed (2× pool width) but honor the background
-        # throttle: a background batch may not saturate the pool any
-        # more than it may saturate the host thread budget
-        width = _procpool.procs() * 2
-        if batch.background:
-            width = min(width, max(1, self._bg_parallelism))
-        sem = asyncio.Semaphore(max(1, width))
-
-        async def _one(entry: tuple[str, str, str]) -> bytes | None:
-            _cas_id, path, ext = entry
-            async with sem:
-                try:
-                    reply = await asyncio.wait_for(
-                        pool.run("thumb.cpu", {"path": path, "ext": ext}),
-                        timeout=GENERATION_TIMEOUT_S,
-                    )
-                    webp = reply.get("webp")
-                    if webp is None:
-                        # typed image failure from the worker — a
-                        # retry would decode the same bad bytes again
-                        logger.debug("thumb failed %s: %s", path,
-                                     reply.get("error"))
-                    return webp
-                except (_procpool.ProcPoolError, asyncio.TimeoutError):
-                    # pool-side INFRASTRUCTURE failure is not evidence
-                    # the image is bad: one inline retry before erroring
-                    try:
-                        return await asyncio.wait_for(
-                            asyncio.to_thread(generate_one_cpu, path, ext),
-                            timeout=GENERATION_TIMEOUT_S,
-                        )
-                    except (ThumbError, asyncio.TimeoutError, OSError) as e:
-                        logger.debug("thumb failed %s: %s", path, e)
-                        return None
-
-        pos = 0
-        while pos < len(entries) and not self._stopped:
-            chunk = entries[pos:pos + chunk_rows]
-            pos += len(chunk)
-            _tm.THUMB_BATCH_FILL.observe(len(chunk) / chunk_rows)
-            # workers account the per-image stage time (shipped back in
-            # their telemetry deltas); this span is the owner-side wall
-            # the attribution engine files under host_cpu
-            async with span("procpool.thumb_cpu") as pool_span:
-                webps = await asyncio.gather(*(_one(e) for e in chunk))
-            _tm.PIPELINE_HOST_SECONDS.observe(
-                pool_span.duration, pipeline="thumbnail")
-            for (cas_id, _path, _ext), webp in zip(chunk, webps):
-                if webp is None:
-                    self.errors += 1
-                    _tm.THUMB_FILES.inc(result="error")
-                else:
-                    self._store_one(batch.library_id, cas_id, webp)
-            if _faults.hit("thumbnail.persist") is not None:
-                # same crash window as the inline pipeline: chunk
-                # stored, journal/accounting not yet — resume must
-                # skip exactly the stored prefix
-                raise _faults.InjectedCrash(
-                    "injected crash between chunk store and journal write"
-                )
-            done += len(chunk)
-            batch.entries = entries[done:]
-            await self._account(batch, len(chunk))
+            await self._process_batch_traced(batch)
 
     def _device_chunk(self) -> int:
         """Images per device dispatch: the live "thumbnail"
@@ -738,8 +644,8 @@ async def distribute_thumbnails(
 ) -> dict[str, Any]:
     """Distribute one location's thumbnail pass across library peers as
     stage-typed WORK shards (parallel/scheduler.py STAGE_THUMB): every
-    executor consults its own journal + store first, encodes through
-    its own procpool, and ships the webp bytes back so the
+    executor consults its own journal + store first, encodes the rest,
+    and ships the webp bytes back so the
     coordinator's store converges bit-identical. With no P2P runtime
     this IS a local pass in shard clothing."""
     from ....location.indexer.mesh import distribute_location_stages

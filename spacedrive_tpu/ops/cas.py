@@ -32,10 +32,8 @@ from .blake3_ref import (
     root_digest_from_pair,
 )
 # blake3_jax (and with it jax) loads lazily inside the device dispatch
-# paths: the procpool worker runtime imports this module for its CPU
-# halves (read_message / chunk caches / cas_ids "cpu") and must stay
-# jax-free — a spawned worker paying a jax import to hash on host would
-# defeat the slim-runtime contract (parallel/procworker.py).
+# paths: the CPU halves (read_message / chunk caches / cas_ids "cpu")
+# stay importable without jax, so hashing on host pays no jax import.
 
 SAMPLE_COUNT = 4
 SAMPLE_SIZE = 10 * 1024

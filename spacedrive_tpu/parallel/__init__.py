@@ -5,7 +5,7 @@ maps to batch-parallel device meshes here; §7 hard part #2 — the
 host-side read pipeline that keeps the device fed.
 """
 
-from . import autotune, procpool
+from . import autotune
 from .feeder import PipelineStats, WindowPipeline, pipeline_depth
 from .mesh import (
     AXES,
@@ -24,7 +24,6 @@ __all__ = [
     "AXES",
     "PipelineStats",
     "autotune",
-    "procpool",
     "WindowPipeline",
     "accelerator_count",
     "batch_sharding",

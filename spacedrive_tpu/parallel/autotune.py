@@ -88,12 +88,6 @@ EMBED_DEVICE_BATCH = 32
 FEEDER_BASE_DEPTH = 3
 FEEDER_DEPTH_CAP = 8
 
-#: rows per multi-process-pool batch (parallel/procpool.py): one
-#: round-trip's serialize+frame tax amortized over this many entries.
-#: Small enough that a 128-entry shard still fans out across workers,
-#: large enough that msgpack+pipe overhead stays a rounding error.
-PROCPOOL_BATCH_ROWS = 32
-
 #: window-scale bounds: the static base is the floor (shrinking the
 #: host window below it just multiplies per-window overhead — how
 #: much padding rides the link is the dispatch RUNG's job); ≥8× static
@@ -118,17 +112,6 @@ OCC_HIGH = 0.9
 #: on the direction; ticks with no new samples hold the streak (an
 #: idle pipeline is not evidence of anything)
 STEP_STREAK = 2
-
-#: pool-quantum control bands: when dispatch (serialize + queue put)
-#: eats this share of the batch roundtrip, the IPC tax dominates and a
-#: wider quantum amortizes it; a roundtrip past POOL_RT_SLOW_S says the
-#: quantum is hurting latency (and lease margins) and should shrink
-POOL_DISPATCH_SHARE = 0.15
-POOL_RT_SLOW_S = 2.0
-#: pool-scale bounds mirror the window-scale rationale: the static
-#: quantum is the floor, ≥8× stops amortizing anything real
-POOL_SCALE_MIN = 1.0
-POOL_SCALE_MAX = 8.0
 
 #: per-stage lease-target hysteresis: the Controller republishes a
 #: stage's lease target only when it moved ≥25% — lease sizing is a
@@ -168,15 +151,11 @@ class PipelinePolicy:
     window_scale: float = 1.0
     #: additive adjustment to the feeder read-ahead depth
     depth_extra: int = 0
-    #: multiplier on the static procpool batch quantum (its own knob:
-    #: the pool's IPC tax and the host window amortize different costs)
-    pool_scale: float = 1.0
 
     def reset(self) -> None:
         self.rung = len(BATCH_LADDER) - 1
         self.window_scale = 1.0
         self.depth_extra = 0
-        self.pool_scale = 1.0
 
     # ---- derived sizes (the seam every consumer reads) ---------------
 
@@ -212,23 +191,6 @@ class PipelinePolicy:
             return base
         return max(1, int(base * self.window_scale))
 
-    def procpool_batch_rows(self) -> int:
-        """Entries per multi-process-pool round-trip (the execute leg's
-        per-stage shipping quantum — parallel/procpool.py). An explicit
-        ``SD_PROCS_BATCH`` pins it; otherwise the controller's
-        ``pool_scale`` knob sizes it from observed per-batch dispatch /
-        roundtrip deltas (``_tick_pool``) — growing when the IPC tax
-        dominates, shrinking on slow or underfilled batches."""
-        explicit = os.environ.get("SD_PROCS_BATCH")
-        if explicit:
-            try:
-                return max(1, int(explicit))
-            except ValueError:
-                pass
-        if not enabled():
-            return PROCPOOL_BATCH_ROWS
-        return max(8, int(PROCPOOL_BATCH_ROWS * self.pool_scale))
-
     def feeder_depth(self, n_devices: int = 1) -> int:
         """In-flight feeder windows (read live by WindowPipeline, so a
         mid-job adjustment takes effect on the next fetch)."""
@@ -247,8 +209,6 @@ class PipelinePolicy:
             "rows_per_device": self.dispatch_rows_per_device(),
             "window_scale": round(self.window_scale, 3),
             "depth_extra": self.depth_extra,
-            "pool_scale": round(self.pool_scale, 3),
-            "pool_quantum": self.procpool_batch_rows(),
         }
 
 
@@ -266,11 +226,6 @@ class Sample:
     occ_n: dict[str, int] = field(default_factory=dict)
     loop_lag_s: float = 0.0
     demotion_level: int = 0
-    # procpool per-batch deltas this tick (owner-side series)
-    pool_batches: int = 0
-    pool_dispatch_s: float = 0.0
-    pool_roundtrip_s: float = 0.0
-    pool_rows: float = 0.0
 
 
 #: which occupancy `op` label feeds each workload's rung control
@@ -405,9 +360,6 @@ class Controller:
             "h2d": _tm.FEEDER_H2D_BYTES.value(),
             "occ": occ,
             "lag": gauge_value("sd_event_loop_lag_seconds"),
-            "pool_dispatch": _tm.PROCPOOL_DISPATCH_SECONDS.stats(),
-            "pool_rt": _tm.PROCPOOL_ROUNDTRIP_SECONDS.stats(),
-            "pool_rows": _tm.PROCPOOL_BATCH_ROWS.stats(),
         }
 
     def sample(self) -> Sample:
@@ -437,12 +389,6 @@ class Controller:
             ds = cur["occ"][op]["sum"] - prev["occ"][op]["sum"]
             s.occ_n[op] = dn
             s.occ_mean[op] = (ds / dn) if dn > 0 else None
-        s.pool_batches = int(
-            cur["pool_rt"]["count"] - prev["pool_rt"]["count"])
-        s.pool_dispatch_s = (
-            cur["pool_dispatch"]["sum"] - prev["pool_dispatch"]["sum"])
-        s.pool_roundtrip_s = cur["pool_rt"]["sum"] - prev["pool_rt"]["sum"]
-        s.pool_rows = cur["pool_rows"]["sum"] - prev["pool_rows"]["sum"]
         return s
 
     # ---- the control law ---------------------------------------------
@@ -577,54 +523,7 @@ class Controller:
                         "pad-waste" if want < 0 else "saturate",
                     ))
                     pol.rung = new_rung
-
-        out.extend(self._tick_pool(workload, pol, s))
         return out
-
-    def _tick_pool(
-        self, workload: str, pol: PipelinePolicy, s: Sample
-    ) -> list[dict[str, Any]]:
-        """Procpool batch-quantum control (the execution continuum's
-        IPC leg). Evidence is the owner-side per-batch deltas — shared
-        across workloads because the pool is, so each workload's knob
-        sees the same signal but keeps its own damped streak:
-
-        - **slow roundtrips** (mean submit→result past
-          ``POOL_RT_SLOW_S``): the quantum is hurting latency — and a
-          stolen shard's lease margin — so shrink toward static;
-        - **underfilled** (mean rows under half the current quantum
-          while scaled up): call sites aren't producing batches that
-          size, so the scale buys nothing — decay;
-        - **IPC tax** (dispatch time ≥ ``POOL_DISPATCH_SHARE`` of the
-          roundtrip while roundtrips are fast): serialization + queue
-          overhead dominates — widen the quantum to amortize it."""
-        if s.pool_batches <= 0:
-            want: int | None = None  # idle pool: silence, not evidence
-            reason = ""
-        else:
-            rt_mean = s.pool_roundtrip_s / s.pool_batches
-            rows_mean = s.pool_rows / s.pool_batches
-            share = (s.pool_dispatch_s / s.pool_roundtrip_s
-                     if s.pool_roundtrip_s > 0 else 0.0)
-            if rt_mean >= POOL_RT_SLOW_S and pol.pool_scale > POOL_SCALE_MIN:
-                want, reason = -1, "slow-roundtrip"
-            elif (rows_mean < 0.5 * pol.procpool_batch_rows()
-                    and pol.pool_scale > POOL_SCALE_MIN):
-                want, reason = -1, "underfilled"
-            elif share >= POOL_DISPATCH_SHARE and rt_mean < POOL_RT_SLOW_S:
-                want, reason = +1, "ipc-tax"
-            else:
-                want, reason = 0, ""
-        if not self._step(workload, "pool", want):
-            return []
-        new = pol.pool_scale * (2.0 if want > 0 else 0.5)
-        new = min(POOL_SCALE_MAX, max(POOL_SCALE_MIN, new))
-        if new == pol.pool_scale:
-            return []
-        decision = self._apply(
-            workload, pol, "pool_scale", pol.pool_scale, new, s, reason)
-        pol.pool_scale = new
-        return [decision]
 
     def _tick_stages(self, s: Sample) -> list[dict[str, Any]]:
         """Per-stage lease targets (the continuum's WORK-board output):
@@ -761,7 +660,6 @@ class Controller:
         scale = new if knob == "window_scale" else pol.window_scale
         rung = new if knob == "rung" else pol.rung
         extra = new if knob == "depth_extra" else pol.depth_extra
-        pscale = new if knob == "pool_scale" else pol.pool_scale
         # inline bounded conditionals pin the label domain at each
         # emit site (SD007): WORKLOADS is the entire vocabulary
         _tm.AUTOTUNE_WINDOW_SCALE.set(
@@ -774,10 +672,6 @@ class Controller:
             else ("thumbnail" if workload == "thumbnail" else "embed"))
         _tm.AUTOTUNE_DEPTH_EXTRA.set(
             float(extra),
-            workload="identify" if workload == "identify"
-            else ("thumbnail" if workload == "thumbnail" else "embed"))
-        _tm.AUTOTUNE_POOL_SCALE.set(
-            float(pscale),
             workload="identify" if workload == "identify"
             else ("thumbnail" if workload == "thumbnail" else "embed"))
 

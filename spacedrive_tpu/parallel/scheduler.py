@@ -1,9 +1,7 @@
 """Unified stage-typed execution continuum — ONE scheduler vocabulary
-for the three planes that used to schedule work separately:
+for the two queues that used to schedule work separately:
 
 - the task system's local threads (``tasks/system.py``),
-- the multi-process execution plane (``parallel/procpool.py``, whose
-  stage functions are the per-stage CPU legs),
 - the mesh WORK shard plane (``p2p/work.py``), previously identify-only.
 
 This module is the registry that fuses them: every distributable unit
@@ -11,11 +9,10 @@ of pipeline work is a **stage** with a stable id, and a mesh
 :class:`~spacedrive_tpu.p2p.work.WorkShard` now carries its stage id so
 any executor — local self-steal, remote peer — can route the shard to
 the right execution leg (``location/indexer/stages.py``), consult its
-own index journal first, and push the CPU-bound middle through its own
-local procpool. The registry also owns the **per-stage throughput
-EWMAs** the control loop runs on: executors report
-``(files, seconds)`` per shard here, the PR 8 ``Controller`` folds the
-rates into per-stage lease targets every tick
+own index journal first, and run the CPU-bound middle itself. The
+registry also owns the **per-stage throughput EWMAs** the control loop
+runs on: executors report ``(files, seconds)`` per shard here, the PR 8
+``Controller`` folds the rates into per-stage lease targets every tick
 (``parallel/autotune.py:_tick_stages``), and the WORK board sizes
 leases per stage from the claimer's self-reported per-stage rates with
 the Controller targets as the fallback — heterogeneous-fleet
@@ -28,8 +25,7 @@ shared by in-process test nodes), ``RATES`` is process-wide;
 
 sdlint scope: this module and the stage executors it routes to are
 fully inside SD014 (P2P requests must ride a ResiliencePolicy — the
-scheduler is NOT a defining module) and SD022 (pool payloads must be
-msgpack-plain; ``pool_for`` is a recognized pool-handle accessor).
+scheduler is NOT a defining module).
 """
 
 from __future__ import annotations
@@ -57,26 +53,21 @@ class StageSpec:
     """One distributable pipeline stage.
 
     ``workload`` names the autotune :class:`PipelinePolicy` whose
-    quanta size this stage's legs; ``pool_stage`` is the procpool
-    stage function that is its CPU-bound middle (None = the stage has
-    no pool leg and always runs inline on the executor);
-    ``journal_field`` documents which index-journal vouch the executor
-    consults before touching a byte."""
+    quanta size this stage's legs; ``journal_field`` documents which
+    index-journal vouch the executor consults before touching a byte."""
 
     id: str
     workload: str
-    pool_stage: str | None
     journal_field: str
 
 
 #: the stage registry — insertion order is the grant tie-break order
 STAGES: dict[str, StageSpec] = {
-    STAGE_IDENTIFY: StageSpec(
-        STAGE_IDENTIFY, "identify", "identify.hash_entries", "cas_id"),
-    STAGE_THUMB: StageSpec(STAGE_THUMB, "thumbnail", "thumb.cpu", "thumb"),
-    STAGE_MEDIA: StageSpec(STAGE_MEDIA, "identify", None, "media_digest"),
-    STAGE_PHASH: StageSpec(STAGE_PHASH, "thumbnail", "phash.gray", "phash"),
-    STAGE_EMBED: StageSpec(STAGE_EMBED, "embed", "embed.decode", "embed"),
+    STAGE_IDENTIFY: StageSpec(STAGE_IDENTIFY, "identify", "cas_id"),
+    STAGE_THUMB: StageSpec(STAGE_THUMB, "thumbnail", "thumb"),
+    STAGE_MEDIA: StageSpec(STAGE_MEDIA, "identify", "media_digest"),
+    STAGE_PHASH: StageSpec(STAGE_PHASH, "thumbnail", "phash"),
+    STAGE_EMBED: StageSpec(STAGE_EMBED, "embed", "embed"),
 }
 
 
@@ -84,18 +75,6 @@ def spec(stage_id: str) -> StageSpec:
     """The registry entry for a stage id — unknown stages fail loudly
     (a typo'd wire shard must not execute as the wrong stage)."""
     return STAGES[stage_id]
-
-
-def pool_for(stage_id: str) -> Any:
-    """The running process pool for a stage's CPU leg — None when the
-    pool is down, SD_PROCS=0, or the stage has no pool leg. sdlint
-    SD022 recognizes locals bound from this accessor as pool handles,
-    so payloads shipped through them stay review-time checked."""
-    if STAGES[stage_id].pool_stage is None:
-        return None
-    from . import procpool as _procpool
-
-    return _procpool.get()
 
 
 # --- per-stage throughput EWMAs (the control loop's input) -----------------
@@ -230,7 +209,6 @@ __all__ = [
     "StageSpec",
     "lease_seconds_for",
     "observed_files_per_s",
-    "pool_for",
     "reset",
     "snapshot",
     "spec",

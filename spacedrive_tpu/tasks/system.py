@@ -203,7 +203,6 @@ class TaskSystem:
         self._shutdown_leftover: list[Task] = []
         self._shutting_down = False
         self._started = False
-        self._procpool_held = False
 
     # -- lifecycle --
 
@@ -211,17 +210,6 @@ class TaskSystem:
         if self._started:
             return
         self._started = True
-        # the execute leg's escape hatch from the GIL: task bodies
-        # dispatch CPU-bound stages onto the multi-process plane
-        # (parallel/procpool.py — mesh shard hashing, journal match,
-        # link prep, thumb software path), so the pool's lifecycle
-        # rides this system's. Refcounted like the host profiler: a
-        # bare TaskSystem (tests, tools) gets workers under SD_PROCS>0
-        # without a Node, and a Node's own hold stacks harmlessly.
-        # SD_PROCS=0: start() returns False and spawns nothing.
-        from ..parallel import procpool as _procpool
-
-        self._procpool_held = _procpool.POOL.start()
         for w in self.workers:
             w.runner = asyncio.ensure_future(w.run_loop())
 
@@ -229,11 +217,6 @@ class TaskSystem:
         """Stop workers; returns queued/paused/suspended tasks
         (ref:system.rs:224-258)."""
         self._shutting_down = True
-        if self._procpool_held:
-            from ..parallel import procpool as _procpool
-
-            _procpool.POOL.stop()
-            self._procpool_held = False
         for w in self.workers:
             if w.current_interrupter is not None:
                 w.current_interrupter.interrupt(InterruptionKind.PAUSE)

@@ -81,9 +81,6 @@ _HEAD_BUCKETS = {
     "relay": LINK,
     "cloud": LINK,
     "feeder": LINK,  # H2D staging: producer fetch AND consumer wait
-    # batches riding the multi-process execution plane: the pass is
-    # waiting on host CPU burned in pool workers (their GIL, not ours)
-    "procpool": HOST_CPU,
 }
 
 #: last dotted segment → bucket for the pipeline stages

@@ -275,7 +275,7 @@ EMBED_FILES = REGISTRY.counter(
 )
 EMBED_STAGE_SECONDS = REGISTRY.histogram(
     "sd_embed_stage_seconds",
-    "per-chunk time split across the embedding stages: host/pool "
+    "per-chunk time split across the embedding stages: host "
     "decode, device forward, DB+sync write",
     labels=("stage",),  # decode | forward | write
 )
@@ -292,7 +292,7 @@ EMBED_PLANES = REGISTRY.counter(
     "planes the media job's embed step consumed, by where they came "
     "from: shared = made from the frame the thumbnailer decoded, own = "
     "the step decoded the file itself (thumbnail already stored, "
-    "resumed job, restarted actor, pooled software path)",
+    "resumed job, restarted actor)",
     labels=("source",),  # shared | own
 )
 
@@ -574,44 +574,6 @@ FEEDER_RESTARTS = REGISTRY.counter(
     "window-pipeline producer threads restarted after a crash",
 )
 
-# --- multi-process execution plane (parallel/procpool.py) -------------------
-# Counters/histograms here are OWNER-side series. Workers accumulate
-# into their own per-process registry and ship additive deltas back
-# with each batch result (registry.merge_delta) — gauges never merge.
-
-PROCPOOL_WORKERS = REGISTRY.gauge(
-    "sd_procpool_workers",
-    "worker processes currently alive in the multi-process execution "
-    "plane (0 = SD_PROCS disabled or pool stopped)",
-)
-PROCPOOL_JOBS = REGISTRY.counter(
-    "sd_procpool_jobs_total",
-    "pool batches by outcome: ok (result + telemetry delta merged), "
-    "error (worker raised — the call site falls back to its inline "
-    "path), retried (re-dispatched after a worker died mid-batch)",
-    labels=("result",),  # ok | error | retried
-)
-PROCPOOL_DISPATCH_SECONDS = REGISTRY.histogram(
-    "sd_procpool_dispatch_seconds",
-    "owner-side submit cost per batch (msgpack serialization + queue "
-    "put — the IPC tax the PipelinePolicy batch quantum amortizes)",
-)
-PROCPOOL_ROUNDTRIP_SECONDS = REGISTRY.histogram(
-    "sd_procpool_roundtrip_seconds",
-    "submit-to-result wall time per pool batch",
-)
-PROCPOOL_BATCH_ROWS = REGISTRY.histogram(
-    "sd_procpool_batch_rows",
-    "rows per shipped pool batch (sized by the per-workload "
-    "PipelinePolicy procpool quantum)",
-    buckets=ROW_BUCKETS,
-)
-PROCPOOL_RESTARTS = REGISTRY.counter(
-    "sd_procpool_restarts_total",
-    "worker processes restarted after dying mid-batch (each dead "
-    "worker's in-flight batches are re-dispatched exactly once)",
-)
-
 # --- closed-loop autotuner (parallel/autotune.py) ---------------------------
 
 AUTOTUNE_DECISIONS = REGISTRY.counter(
@@ -635,15 +597,6 @@ AUTOTUNE_DEPTH_EXTRA = REGISTRY.gauge(
     "additive adjustment the autotuner applies to the feeder depth",
     labels=("workload",),
 )
-AUTOTUNE_POOL_SCALE = REGISTRY.gauge(
-    "sd_autotune_pool_scale",
-    "current multiplier on the static procpool batch quantum (the "
-    "Controller grows it when the per-batch dispatch share says the "
-    "IPC tax dominates, shrinks it on long roundtrips or underfilled "
-    "batches)",
-    labels=("workload",),
-)
-
 # --- serve layer: admission gate + read cache (spacedrive_tpu/serve/) -------
 
 GATE_REQUESTS = REGISTRY.counter(
@@ -801,11 +754,6 @@ RESOURCE_FDS = REGISTRY.gauge(
 RESOURCE_THREADS = REGISTRY.gauge(
     "sd_resource_threads",
     "OS threads in this process (/proc/self/status Threads:)",
-)
-RESOURCE_PROCPOOL_RSS = REGISTRY.gauge(
-    "sd_resource_procpool_rss_bytes",
-    "summed resident set size of live procpool workers "
-    "(/proc/<pid>/statm over the multi-process plane; 0 with SD_PROCS=0)",
 )
 RESOURCE_INVENTORY = REGISTRY.gauge(
     "sd_resource_inventory",

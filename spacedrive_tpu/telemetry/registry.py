@@ -299,111 +299,6 @@ class MetricsRegistry:
                         out.append(f"{name}{base} {_fmt(s.value)}")
         return "\n".join(out) + "\n"
 
-    # --- cross-process deltas (parallel/procpool.py) --------------------
-    #
-    # The multi-process execution plane keeps this registry single-
-    # writer per process: pool workers accumulate into their OWN
-    # registry (same families — both sides import telemetry.metrics)
-    # and ship a msgpack-plain delta blob back with each batch result;
-    # the owner merges it here. Counters and histograms merge by
-    # addition (monotonic / mergeable by construction); gauges are
-    # deliberately excluded — they are point-in-time statements only
-    # the owning process may make.
-
-    def delta_capture(self) -> dict[str, Any]:
-        """Compact additive state: {family: {label-key-tuple-as-list:
-        …}} rendered as parallel lists so the blob stays msgpack-plain."""
-        with self._lock:
-            counters: dict[str, list] = {}
-            hists: dict[str, list] = {}
-            for name, fam in self._families.items():
-                if isinstance(fam, Counter):
-                    rows = [
-                        [list(key), s.value]
-                        for key, s in fam._series.items() if s.value
-                    ]
-                    if rows:
-                        counters[name] = rows
-                elif isinstance(fam, Histogram):
-                    rows = [
-                        [list(key), s.sum, s.count,
-                         list(s.bucket_counts), list(s.recent)]
-                        for key, s in fam._series.items() if s.count
-                    ]
-                    if rows:
-                        hists[name] = rows
-            return {"c": counters, "h": hists}
-
-    @staticmethod
-    def delta_diff(before: dict[str, Any],
-                   after: dict[str, Any]) -> dict[str, Any]:
-        """after − before, per series. New observations in a histogram
-        ring are its trailing ``count_after − count_before`` samples
-        (the ring may have dropped older ones — then the whole ring is
-        the best available tail)."""
-        out: dict[str, Any] = {"c": {}, "h": {}}
-        prev_c = {
-            (name, tuple(key)): value
-            for name, rows in before.get("c", {}).items()
-            for key, value in rows
-        }
-        for name, rows in after.get("c", {}).items():
-            kept = []
-            for key, value in rows:
-                d = value - prev_c.get((name, tuple(key)), 0.0)
-                if d > 0:
-                    kept.append([key, d])
-            if kept:
-                out["c"][name] = kept
-        prev_h = {
-            (name, tuple(key)): (s, n, bc)
-            for name, rows in before.get("h", {}).items()
-            for key, s, n, bc, _recent in rows
-        }
-        for name, rows in after.get("h", {}).items():
-            kept = []
-            for key, s, n, bc, recent in rows:
-                ps, pn, pbc = prev_h.get((name, tuple(key)), (0.0, 0, None))
-                dn = n - pn
-                if dn <= 0:
-                    continue
-                dbc = (
-                    [b - p for b, p in zip(bc, pbc)] if pbc is not None
-                    else list(bc)
-                )
-                kept.append([key, s - ps, dn, dbc, recent[-dn:]])
-            if kept:
-                out["h"][name] = kept
-        return out
-
-    def merge_delta(self, delta: dict[str, Any]) -> None:
-        """Fold a worker-shipped delta into this registry. Unknown
-        families/label shapes are skipped (version drift between owner
-        and worker must never corrupt owner series)."""
-        with self._lock:
-            for name, rows in (delta.get("c") or {}).items():
-                fam = self._families.get(name)
-                if not isinstance(fam, Counter):
-                    continue
-                for key, value in rows:
-                    if len(key) != len(fam.label_names) or value <= 0:
-                        continue
-                    fam._resolve(dict(zip(fam.label_names, key))).value += value
-            for name, rows in (delta.get("h") or {}).items():
-                fam = self._families.get(name)
-                if not isinstance(fam, Histogram):
-                    continue
-                for key, s, n, bc, recent in rows:
-                    if len(key) != len(fam.label_names) or n <= 0 \
-                            or len(bc) != len(fam.buckets) + 1:
-                        continue
-                    series = fam._resolve(dict(zip(fam.label_names, key)))
-                    series.sum += s
-                    series.count += n
-                    for i, b in enumerate(bc):
-                        series.bucket_counts[i] += b
-                    series.recent.extend(recent)
-
     # --- snapshot (rspc + bench read path) ------------------------------
 
     def snapshot(self) -> dict[str, Any]:

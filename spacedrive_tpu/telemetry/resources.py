@@ -11,8 +11,6 @@ and publishing them as ``sd_resource_*`` gauges:
 
 - ``/proc/self`` facts: RSS bytes, open-fd count, OS thread count
   (portable fallbacks where /proc is absent);
-- procpool worker RSS summed over the multi-process execution plane's
-  live workers (``/proc/<pid>/statm``);
 - in-process inventories over a **fixed kind vocabulary**
   (:data:`INVENTORY_KINDS`): index-journal and op-log row counts,
   serve-cache entries/bytes, history-store bytes — registered by the
@@ -106,29 +104,6 @@ def fd_count() -> float:
         return float(len(os.listdir("/proc/self/fd")))
     except OSError:
         return 0.0
-
-
-def _pid_rss_bytes(pid: int) -> float:
-    try:
-        with open(f"/proc/{pid}/statm", encoding="ascii") as f:
-            pages = int(f.read().split()[1])
-        return float(pages * os.sysconf("SC_PAGE_SIZE"))
-    except (OSError, IndexError, ValueError):
-        return 0.0
-
-
-def _procpool_rss() -> float:
-    """Summed RSS of the multi-process plane's live workers (0 with
-    SD_PROCS=0 — the pool spawned nothing)."""
-    from ..parallel import procpool as _procpool
-
-    total = 0.0
-    for w in list(getattr(_procpool.POOL, "_workers", ())):
-        proc = getattr(w, "proc", None)
-        pid = getattr(proc, "pid", None)
-        if pid is not None and proc.poll() is None:
-            total += _pid_rss_bytes(pid)
-    return total
 
 
 def _ring_drops() -> float:
@@ -239,7 +214,6 @@ class ResourceSampler:
 
         rss, threads = _proc_status()
         fds = fd_count()
-        pool_rss = _procpool_rss()
         with self._lock:
             providers = dict(self._providers)
         # every kind always present: absent providers read an explicit
@@ -255,7 +229,6 @@ class ResourceSampler:
         _tm.RESOURCE_RSS.set(rss)
         _tm.RESOURCE_FDS.set(fds)
         _tm.RESOURCE_THREADS.set(threads)
-        _tm.RESOURCE_PROCPOOL_RSS.set(pool_rss)
         # one literal call site per kind: the label domain is fixed by
         # construction (SD007) and absent providers read an explicit 0
         _tm.RESOURCE_INVENTORY.set(inv.get("journal_rows", 0.0),
@@ -274,7 +247,6 @@ class ResourceSampler:
             "rss_bytes": rss,
             "fds": fds,
             "threads": threads,
-            "procpool_rss_bytes": pool_rss,
             **inv,
         }
         with self._lock:

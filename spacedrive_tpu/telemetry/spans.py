@@ -25,8 +25,9 @@ lands on a host line of the same ``.xplane.pb`` as the device's ops, on
 the profiler's clock, so an idle gap of the chip can be laid against the
 span that was open. With no session the annotation is one small object
 and a flag read. It is bound on first use and only in a process that has
-imported ``jax`` already: this module never imports it
-(``parallel/procworker.py`` stays import-light).
+imported ``jax`` already: this module never imports it, so code that
+never touches the device (the CLI, host-only tools) can import the
+telemetry package without paying for ``jax``.
 
 Every span also carries distributed-trace identity (``trace_id``/
 ``span_id``/``parent_id``, see ``telemetry.trace``): a nested span

@@ -104,14 +104,6 @@ FAULT_POINTS: dict[str, tuple[str, tuple[str, ...]]] = {
         "(claim race; the twice-executed shard must merge idempotently)",
         ("vanish", "race"),
     ),
-    "procpool.worker": (
-        "multi-process execution plane (parallel/procpool.py): `crash` "
-        "kills the chosen worker process right after its batch ships "
-        "(death mid-batch; the pool must restart the worker once and "
-        "re-dispatch, and the pass must converge bit-identical); "
-        "`stall` delays the batch inside the worker by delay_s",
-        ("crash", "stall"),
-    ),
     "relay.http": (
         "cloud relay HTTP surface (cloud/relay middleware)",
         ("500", "timeout", "truncate"),

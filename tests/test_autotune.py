@@ -78,7 +78,6 @@ def test_cold_start_holds_static_defaults():
     assert pol.snapshot() == {
         "rung": 2, "rows_per_device": 1024,
         "window_scale": 1.0, "depth_extra": 0,
-        "pool_scale": 1.0, "pool_quantum": 32,
     }
 
 

@@ -1,6 +1,6 @@
 """The unified stage-typed execution continuum (ISSUE 19): stage-typed
 WORK shards for thumbnails / media / pHash / embeddings, the per-stage
-lease law, the procpool batch-quantum autotune knob, and the two-node
+lease law, the per-stage lease targets, and the two-node
 chaos proof that a distributed thumbnail+embed pass converges
 BIT-IDENTICAL (webp bytes, embedding vectors, journal vouches) to a
 single-node pass — including under mid-lease peer death and claim
@@ -288,77 +288,7 @@ def test_sessionless_claim_not_masked_by_newer_leased_session():
     telemetry.reset()
 
 
-# --- autotune: pool quantum knob + per-stage lease targets ------------------
-
-
-def test_pool_scale_widens_on_ipc_tax_and_shrinks_on_slow_roundtrips():
-    from spacedrive_tpu.parallel.autotune import (
-        POOL_SCALE_MIN,
-        PROCPOOL_BATCH_ROWS,
-        Controller,
-        Sample,
-    )
-
-    telemetry.reset()
-    c = Controller(interval=999)
-    pol = c.policies["identify"]
-    assert pol.procpool_batch_rows() == PROCPOOL_BATCH_ROWS
-    # dispatch eats 30% of fast roundtrips → IPC tax → widen (after
-    # the STEP_STREAK damping: two consecutive wishes)
-    taxed = Sample(pool_batches=10, pool_dispatch_s=3.0,
-                   pool_roundtrip_s=10.0, pool_rows=10 * 64.0)
-    c.tick(taxed)
-    decisions = c.tick(taxed)
-    assert any(d.get("knob") == "pool_scale" and d["to"] == 2.0
-               for d in decisions), decisions
-    assert pol.procpool_batch_rows() == 2 * PROCPOOL_BATCH_ROWS
-    assert gauge_value("sd_autotune_pool_scale",
-                       workload="identify") == 2.0
-    # slow roundtrips: the quantum is hurting lease margins → shrink
-    slow = Sample(pool_batches=4, pool_dispatch_s=0.1,
-                  pool_roundtrip_s=16.0, pool_rows=4 * 64.0)
-    c.tick(slow)
-    decisions = c.tick(slow)
-    assert any(d.get("knob") == "pool_scale" and d["to"] == POOL_SCALE_MIN
-               for d in decisions), decisions
-    assert pol.procpool_batch_rows() == PROCPOOL_BATCH_ROWS
-    # an idle pool is silence, not evidence: no further movement
-    assert not [d for d in c.tick(Sample())
-                if d.get("knob") == "pool_scale"]
-    telemetry.reset()
-
-
-def test_pool_scale_decays_when_underfilled():
-    from spacedrive_tpu.parallel.autotune import Controller, Sample
-
-    telemetry.reset()
-    c = Controller(interval=999)
-    pol = c.policies["thumbnail"]
-    pol.pool_scale = 4.0
-    # call sites only ever produce ~8-row batches: the scale buys
-    # nothing — decay toward static
-    under = Sample(pool_batches=10, pool_dispatch_s=0.01,
-                   pool_roundtrip_s=1.0, pool_rows=10 * 8.0)
-    c.tick(under)
-    decisions = c.tick(under)
-    assert any(d.get("knob") == "pool_scale" and d["to"] == 2.0
-               for d in decisions), decisions
-    telemetry.reset()
-
-
-def test_pool_quantum_disabled_env_is_static(monkeypatch):
-    from spacedrive_tpu.parallel.autotune import (
-        PROCPOOL_BATCH_ROWS,
-        PipelinePolicy,
-    )
-
-    pol = PipelinePolicy("identify")
-    pol.pool_scale = 8.0
-    monkeypatch.setenv("SD_AUTOTUNE", "0")
-    assert pol.procpool_batch_rows() == PROCPOOL_BATCH_ROWS
-    monkeypatch.delenv("SD_AUTOTUNE")
-    monkeypatch.setenv("SD_PROCS_BATCH", "17")
-    assert pol.procpool_batch_rows() == 17
+# --- autotune: per-stage lease targets ----------------------------------------
 
 
 def test_stage_lease_targets_follow_rates_with_hysteresis():

@@ -381,32 +381,6 @@ async def test_no_plane_offered_every_vector_through_own(tmp_path, arrange):
         await node.thumbnailer.shutdown()
 
 
-async def test_pooled_software_path_offers_nothing(tmp_path, monkeypatch):
-    from spacedrive_tpu.parallel import procpool
-
-    cases = ("jpeg_2016x1512", "jpeg_400x300", "png_rgba")
-    corpus = _location(tmp_path, cases)
-    monkeypatch.setenv("SD_PROCS", "2")
-    assert procpool.POOL.start()
-    try:
-        procpool.POOL.warm()
-        node, library, mgr = await _pipeline(tmp_path, use_device=False)
-        try:
-            loc = await _identify(library, mgr, corpus)
-            shared0, own0 = _planes("shared"), _planes("own")
-            job, (holder,) = await _media(library, mgr, loc)
-            assert _job_status(library, job) == COMPLETED
-            assert _planes("shared") == shared0
-            assert _planes("own") == own0 + len(cases)
-            assert len(_vectors(library)) == len(cases)
-            assert len(holder) == 0
-        finally:
-            await node.thumbnailer.shutdown()
-    finally:
-        while procpool.POOL.running():
-            procpool.POOL.stop()
-
-
 # --- (d) the holder ---------------------------------------------------------
 
 

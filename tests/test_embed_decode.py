@@ -248,12 +248,13 @@ def test_handler_formats_keep_format_image(tmp_path, monkeypatch, mode):
     assert len(seen) == 3
 
 
-# --- (d) the procpool stage and the thumbnailer's tap give the same plane ---
+# --- (d) the media job's leg, the mesh stage's leg and the thumbnailer's tap
+# give the same plane ---------------------------------------------------------
 
 
 def test_stage_embed_decode_bytes_equal_inline_planes(tmp_path):
+    from spacedrive_tpu.object.media.job import MediaProcessorJob
     from spacedrive_tpu.object.media.thumbnail import process
-    from spacedrive_tpu.parallel.procworker import _stage_embed_decode
 
     paths = []
     for name, (w, h), fmt in [("a.jpg", (4032, 3024), "JPEG"),
@@ -263,12 +264,16 @@ def test_stage_embed_decode_bytes_equal_inline_planes(tmp_path):
         _field(31, w, h).save(paths[-1], fmt)
     paths.append(str(tmp_path / "d.jpg"))
     _text_file(paths[-1])
-    reply = _stage_embed_decode({"paths": paths})
-    inline = [embedder.decode_image(p) for p in paths]
-    assert [None if p is None else p.tobytes() for p in inline] \
-        == reply["planes"]
-    assert reply["planes"][-1] is None
-    assert all(len(b) == 32 * 32 * 3 * 4 for b in reply["planes"][:-1])
+    # the media job's embed step decodes what the holder lacks; the
+    # mesh embed stage (location/indexer/stages.py) decodes each pending
+    # path with `embedder.decode_image`
+    job_planes = MediaProcessorJob._decode_for_embed(None, paths)
+    stage_planes = [embedder.decode_image(p) for p in paths]
+    planes = [None if p is None else p.tobytes() for p in job_planes]
+    assert [None if p is None else p.tobytes() for p in stage_planes] \
+        == planes
+    assert planes[-1] is None
+    assert all(len(b) == 32 * 32 * 3 * 4 for b in planes[:-1])
 
     # the third side: the plane made from the frame the thumbnailer
     # decoded (what the media job's holder keeps, as uint8)
@@ -278,7 +283,7 @@ def test_stage_embed_decode_bytes_equal_inline_planes(tmp_path):
             path, lambda frame, scale: tapped.append(
                 embedder.plane_from_frame(frame, scale)))
     assert [embedder.input_plane(p).tobytes() for p in tapped] \
-        == reply["planes"][:-1]
+        == planes[:-1]
     with pytest.raises(Exception):
         process.decode_image(paths[-1], lambda frame, scale: tapped.append(0))
     assert len(tapped) == 3

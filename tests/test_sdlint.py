@@ -2333,100 +2333,6 @@ def test_sd021_tree_reading_no_knobs_needs_no_catalog(tmp_path, monkeypatch):
     assert findings == []
 
 
-# --- SD022 process-boundary-purity -----------------------------------------
-
-
-def test_sd022_flags_rich_objects_in_pool_payloads(tmp_path):
-    findings = run_on(
-        tmp_path,
-        """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
-        def ship(self, library, entries):
-            pool = _procpool.get()
-            pool.submit("identify.hash_entries",
-                        {"db": self.db, "entries": entries})
-            pool.request("link.prep", {"library": library})
-            _procpool.POOL.run("thumb.cpu", {"cb": lambda p: p})
-        """,
-        ["SD022"],
-    )
-    assert len(findings) == 3
-    assert rules_of(findings) == ["SD022"]
-    assert any("`db`" in f.message for f in findings)
-    assert any("`library`" in f.message for f in findings)
-    assert any("`lambda`" in f.message for f in findings)
-
-
-def test_sd022_follows_payload_dict_assignment(tmp_path):
-    findings = run_on(
-        tmp_path,
-        """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
-        def ship(self, loc_path, entries):
-            payload = {"loc_path": loc_path, "conn": self._conn}
-            pool = _procpool.get()
-            pool.submit("identify.hash_entries", payload, rows=len(entries))
-        """,
-        ["SD022"],
-    )
-    assert len(findings) == 1
-    assert "_conn" in findings[0].message
-
-
-def test_sd022_silent_on_plain_payloads_and_foreign_submits(tmp_path):
-    findings = run_on(
-        tmp_path,
-        """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
-        def ship(loc_path, wire_items, wire_rows, executor, inode):
-            pool = _procpool.get()
-            payload = {"loc_path": loc_path, "items": wire_items}
-            pool.submit("journal.match", payload, rows=len(wire_items))
-            pool.request("identify.hash_entries",
-                         {"rows": wire_rows, "inode": inode})
-            # a NON-pool submit (thread executor) is out of scope
-            executor.submit(lambda: None)
-        """,
-        ["SD022"],
-    )
-    assert findings == []
-
-
-def test_sd022_covers_embed_decode_leg(tmp_path):
-    # ISSUE 16: the embed stage ships decode work to the pool exactly
-    # like identify/thumb — the same purity bar applies to its payload
-    findings = run_on(
-        tmp_path,
-        """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
-        def decode(self, paths):
-            pool = _procpool.get()
-            pool.request("embed.decode",
-                         {"paths": paths, "lib": self.library})
-        """,
-        ["SD022"],
-    )
-    assert len(findings) == 1
-    assert "library" in findings[0].message
-    # the real leg's plain payload ({"paths": [...]}) stays silent
-    assert run_on(
-        tmp_path,
-        """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
-        def decode(paths):
-            pool = _procpool.get()
-            pool.request("embed.decode", {"paths": list(paths)},
-                         rows=len(paths))
-        """,
-        ["SD022"],
-    ) == []
-
-
 # --- SD023 cross-context-race ----------------------------------------------
 
 
@@ -2641,13 +2547,10 @@ def test_sd025_flags_mutation_after_pool_submit_and_queue_put(tmp_path):
     findings = run_on(
         tmp_path,
         """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
         def ship(rows, q):
             payload = {"rows": rows}
-            pool = _procpool.get()
-            pool.submit("identify.hash", payload, rows=len(rows))
-            payload["rows"] = []          # races the worker's view
+            q.put_nowait(payload)
+            payload["rows"] = []          # races the consumer's view
 
             batch = [1, 2]
             q.put(batch)
@@ -2664,13 +2567,10 @@ def test_sd025_silent_on_rebind_and_pre_submit_mutation(tmp_path):
     findings = run_on(
         tmp_path,
         """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
         def ship(rows, q):
             payload = {"rows": rows}
             payload["extra"] = 1          # before the hand-off: fine
-            pool = _procpool.get()
-            pool.submit("identify.hash", payload, rows=len(rows))
+            q.put_nowait(payload)
             payload = {"rows": []}        # rebind severs the alias
             payload["rows"] = rows
 

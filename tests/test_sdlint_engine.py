@@ -562,7 +562,8 @@ def test_context_seeding_at_each_spawn_seam(tmp_path):
     assert _ctxs(project, "m.py", "feeder_loop") == {"feeder"}
     assert _ctxs(project, "m.py", "plain_loop") == {"thread"}
     assert _ctxs(project, "m.py", "worker_main") == {"proc"}
-    assert _ctxs(project, "m.py", "stage_handler") == {"proc"}
+    # a dispatch-table dict is data, not a spawn seam: unknown
+    assert _ctxs(project, "m.py", "stage_handler") == set()
     # no seam reaches spawn itself: unknown, not safe
     assert _ctxs(project, "m.py", "spawn") == set()
 

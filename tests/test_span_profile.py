@@ -114,8 +114,8 @@ def test_span_without_a_session_records_as_before():
 
 
 def test_annotation_is_not_bound_before_jax_is_imported(monkeypatch):
-    """`procworker.py` imports the telemetry package without jax: a span
-    there must neither import it nor fail."""
+    """Code that never touches the device imports the telemetry package
+    without jax: a span there must neither import it nor fail."""
     import sys
 
     monkeypatch.setattr(spans, "_annotation_cls", None)

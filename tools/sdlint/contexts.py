@@ -1,9 +1,10 @@
 """Execution-context inference: which contexts can each function run in?
 
-The production tree runs five execution contexts at once — the asyncio
+The production tree runs four execution contexts at once — the asyncio
 event loop, the feeder ("sd-window-pipeline") producer thread, the
-~19 Hz sampler ("sd-profiler") thread, `asyncio.to_thread` /
-`run_in_executor` helper threads, and `SD_PROCS` worker processes.
+~19 Hz sampler ("sd-profiler") thread, and `asyncio.to_thread` /
+`run_in_executor` helper threads — and a fifth, worker processes, is
+modeled for ``multiprocessing.Process`` targets.
 The concurrency rules (SD023-SD026) need to know, for every function,
 the set of contexts it can execute in; this module infers that set in
 two steps:
@@ -21,9 +22,8 @@ two steps:
      ...)`` callables run on executor threads (``thread``);
    - ``loop.call_soon(f)`` / ``call_soon_threadsafe`` / ``call_later``
      / ``call_at`` callbacks run on the loop;
-   - functions registered in a module-level ``STAGES = {...}`` dispatch
-     table (the procworker idiom) and ``multiprocessing.Process``
-     targets run in worker processes (``proc``).
+   - ``multiprocessing.Process`` targets run in worker processes
+     (``proc``).
 
 2. **Propagation over the call graph.** A function called from a
    context runs in that context, so seed contexts flow caller→callee
@@ -153,23 +153,6 @@ class ContextMap:
                 self._seed(ctx.path, info.qualname, CTX_LOOP, "async def")
 
         for node in ast.walk(ctx.tree):
-            # STAGES = {"name": handler, ...} — the procworker dispatch
-            # table; handlers execute in the worker process
-            if isinstance(node, (ast.Assign, ast.AnnAssign)):
-                targets = node.targets if isinstance(node, ast.Assign) \
-                    else [node.target]
-                value = node.value
-                if (
-                    isinstance(value, ast.Dict)
-                    and any(isinstance(t, ast.Name) and t.id == "STAGES"
-                            for t in targets)
-                ):
-                    for v in value.values:
-                        self._seed_callable(
-                            ctx, v, node, CTX_PROC,
-                            "registered in STAGES dispatch table",
-                        )
-                continue
             if not isinstance(node, ast.Call):
                 continue
             name = call_name(node)

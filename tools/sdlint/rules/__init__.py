@@ -14,5 +14,4 @@ from . import commitorder  # noqa: F401  SD017
 from . import frozenrules  # noqa: F401  SD018
 from . import breakerrules  # noqa: F401  SD019
 from . import envrules  # noqa: F401  SD021
-from . import procrules  # noqa: F401  SD022
 from . import concurrency  # noqa: F401  SD023-SD026

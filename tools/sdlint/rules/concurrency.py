@@ -12,8 +12,8 @@ class this repo has actually shipped (or nearly shipped):
   from a thread; asyncio's loop machinery is not thread-safe and the
   failure mode is a silently lost callback.
 - **SD025** post-submit payload aliasing — mutating a batch after it
-  was handed to the worker pool or a queue; the shared-nothing
-  contract SD022 checks for purity, this checks for aliasing.
+  was handed to a queue; the consumer's view of the batch races the
+  mutation.
 - **SD026** hot-thread blocking — an unbounded wait on the sampler or
   feeder thread; a stall there corrupts profiling cadence or starves
   the device of windows.
@@ -36,7 +36,7 @@ from ..core import (
 from ..effects import WRITE, effect_summaries
 
 #: contexts that share the host address space (proc is a separate
-#: process behind the msgpack boundary — the sanctioned seam)
+#: process with its own address space)
 _HOST = lambda ctxs: frozenset(ctxs) - {CTX_PROC}  # noqa: E731
 
 
@@ -237,19 +237,17 @@ def _mutation_root(stmt: ast.AST) -> Iterator[tuple[str, ast.AST]]:
 @rule(
     "SD025",
     "post-submit-aliasing",
-    "a payload mutated after it was handed to the worker pool or a "
-    "queue — the consumer sees the mutation race",
+    "a payload mutated after it was handed to a queue — the consumer "
+    "sees the mutation race",
 )
 def check_post_submit_aliasing(ctx: FileContext) -> Iterator[Finding]:
     from ..cfg import STMT, solve_forward
     from .flowrules import walk_shallow_stmt
-    from .procrules import _SHIP_METHODS, _is_pool_handle, _pool_handle_names
 
     for info in ctx.functions:
         fn = info.node
         if not any(isinstance(n, ast.Call) for n in walk_shallow(fn)):
             continue
-        safe = _pool_handle_names(ctx, fn)
 
         def ships_in(stmt: ast.AST) -> Iterator[tuple[str, int, str]]:
             for sub in walk_shallow_stmt(stmt):
@@ -258,21 +256,12 @@ def check_post_submit_aliasing(ctx: FileContext) -> Iterator[Finding]:
                     and isinstance(sub.func, ast.Attribute)
                 ):
                     continue
-                payload: ast.AST | None = None
-                dest = None
-                if sub.func.attr in _SHIP_METHODS and _is_pool_handle(
-                    sub.func.value, safe
+                if (
+                    sub.func.attr in _HANDOFF_QUEUE_METHODS and sub.args
+                    and isinstance(sub.args[0], ast.Name)
                 ):
-                    dest = "the worker pool"
-                    payload = sub.args[1] if len(sub.args) >= 2 else None
-                    for kw in sub.keywords:
-                        if kw.arg == "payload":
-                            payload = kw.value
-                elif sub.func.attr in _HANDOFF_QUEUE_METHODS and sub.args:
-                    dest = f"`{sub.func.attr}(...)`"
-                    payload = sub.args[0]
-                if dest is not None and isinstance(payload, ast.Name):
-                    yield payload.id, sub.lineno, dest
+                    yield (sub.args[0].id, sub.lineno,
+                           f"`{sub.func.attr}(...)`")
 
         def rebinds_in(stmt: ast.AST) -> set[str]:
             out: set[str] = set()

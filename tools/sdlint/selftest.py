@@ -190,21 +190,6 @@ CORPUS: dict[str, dict] = {
         """},
         "env": {"SDLINT_KNOB_CATALOG": "nonexistent.md"},
     },
-    "SD022": {"files": {"pkg/mod.py": """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
-        def ship(self, entries):
-            pool = _procpool.get()
-            pool.submit("identify.hash_entries",
-                        {"db": self.db, "entries": entries})
-    """, "pkg/stage.py": """
-        from spacedrive_tpu.parallel import scheduler as _scheduler
-
-        def ship_stage(self, entries):
-            pool = _scheduler.pool_for("thumb")
-            pool.submit("thumb.cpu",
-                        {"library": self.library, "entries": entries})
-    """}},
     "SD023": {"files": {"pkg/mod.py": """
         import threading
         from collections import deque
@@ -241,12 +226,9 @@ CORPUS: dict[str, dict] = {
                 self.loop.call_soon(print)
     """}},
     "SD025": {"files": {"pkg/mod.py": """
-        from spacedrive_tpu.parallel import procpool as _procpool
-
-        def ship(rows):
+        def ship(rows, q):
             payload = {"rows": rows}
-            pool = _procpool.get()
-            pool.submit("identify.hash", payload, rows=len(rows))
+            q.put(payload)
             payload["rows"] = []
     """}},
     "SD026": {"files": {"pkg/mod.py": """
